@@ -107,6 +107,8 @@ def main(argv=None) -> None:
     names = select_suites(args.only, args.skip)
     if not names:
         raise SystemExit("no suites selected (--only/--skip removed all)")
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     print("name,us_per_call,derived")
     failed = []
     report = {"git_sha": git_sha(),

@@ -114,7 +114,6 @@ class Engine:
         self.head_importance = head_importance  # headkv per-head weights
         self.mesh = mesh
         self.pa = PlanArrays.from_plan(plan)
-        self.sp = _serve.slotify_params(params, plan, cfg.model)
         # observability (DESIGN.md §12): one registry + trace per engine,
         # threaded through the executor, backend, and (lazily) the scheduler
         self.obs = Obs.build(cfg.obs)
@@ -125,6 +124,8 @@ class Engine:
                                       cfg.compression,
                                       exec_cfg=cfg.executor_cfg, mesh=mesh,
                                       paging=cfg.paging, obs=self.obs)
+        self.sp = self.executor.shard_params(
+            _serve.slotify_params(params, plan, cfg.model))
         # cache storage backend (DESIGN.md §9): "slot" | "paged" | plugin
         self.backend = make_cache_backend(
             cfg.cache_backend, cfg.model, cfg.compression,
@@ -201,7 +202,8 @@ class Engine:
         StepFn takes both as arguments, so nothing recompiles (the shapes
         are replan-invariant — slot grid and capacity are fixed)."""
         self.pa = PlanArrays.from_plan(self.plan)
-        self.sp = _serve.slotify_params(self.params, self.plan, self.cfg.model)
+        self.sp = self.executor.shard_params(
+            _serve.slotify_params(self.params, self.plan, self.cfg.model))
 
     # ---- one-shot serving --------------------------------------------------
 

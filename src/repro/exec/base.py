@@ -177,14 +177,25 @@ class Executor:
         batch sharding; the mesh executor returns its data size)."""
         return 1
 
-    def shard_state(self, state):
-        """Lay a freshly initialized ServeState out for this executor.
+    def shard_params(self, sp: dict) -> dict:
+        """Lay slot-layout weights out for this executor, once per plan.
 
-        The continuous scheduler's empty state is created by the cache
-        backend with no layout information; the mesh executor places it
-        under its decode in_specs here so the cache is sharded before the
-        first step instead of living replicated on one device until the
-        first call reshards it.  Identity on single-device executors."""
+        The mesh executor places them under its in_specs (slot-dim leaves
+        split over ``model``, the rest replicated), so a StepFn call does
+        not re-transfer the weights.  Identity on single-device
+        executors."""
+        return sp
+
+    def shard_state(self, state):
+        """Lay a ServeState out for this executor.
+
+        Cache backends build state with no layout information — the empty
+        continuous state, and the block table each time the paged backend
+        re-uploads its host mirror.  The mesh executor places such state
+        under its decode in_specs here (its decode / propose / verify entry
+        points do so on every call), so the cache is sharded before the
+        first step and a re-uploaded table never retraces a StepFn.
+        Identity on single-device executors."""
         return state
 
     # ---- StepFns -----------------------------------------------------------
@@ -300,6 +311,11 @@ class Executor:
         return tokens, jnp.asarray(active), jnp.asarray(rows, jnp.int32)
 
     # ---- audit -------------------------------------------------------------
+
+    def prefill_hlo(self, sp: dict, batch: dict, pa) -> str:
+        """Compiled HLO of the prefill StepFn for ``batch`` (rows
+        arange(B), no head importance) — the audit twin of `decode_hlo`."""
+        raise NotImplementedError
 
     def decode_hlo(self, sp: dict, state, pa, tokens: jnp.ndarray) -> str:
         """Compiled (post-SPMD) HLO of the decode StepFn for the given

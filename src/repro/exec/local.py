@@ -165,6 +165,13 @@ class LocalExecutor(Executor):
         return self._observe_step("verify", self._verify_jits[draft_layers],
                                   args)
 
+    def prefill_hlo(self, sp, batch, pa):
+        if self._prefill_jit is None:
+            self._prefill_jit = self._build_prefill()
+        rows = jnp.arange(batch["tokens"].shape[0], dtype=jnp.int32)
+        lowered = self._prefill_jit.lower(sp, batch, pa, rows, None)
+        return lowered.compile().as_text()
+
     def decode_hlo(self, sp, state, pa, tokens):
         if self._decode_jit is None:
             self._decode_jit = self._build_decode()

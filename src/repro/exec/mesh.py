@@ -17,7 +17,8 @@ are all-gathered over ``model`` per layer (cheap next to prompt attention),
 while the compression selection and per-slot cache fill stay local.
 Prefill's non-cache outputs are replicated over ``model`` by construction
 (identical math from identical gathered inputs), which shard_map's static
-replication checker cannot prove — hence ``check_rep=False`` there.
+varying-manual-axes checker cannot prove — hence ``check_vma=False``
+there.
 
 Paged backend: the pool shards over ``model`` into per-shard partitions;
 the partition-aware allocator (`repro.paging.block_pool.BlockPool` with
@@ -39,7 +40,6 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.api.registry import register_executor
@@ -181,15 +181,15 @@ class MeshExecutor(Executor):
                                   model_axis=ec.model_axis)
 
         d = ec.data_axis
-        fn = shard_map(
+        fn = jax.shard_map(
             inner, mesh=self.mesh,
             in_specs=(sp_specs, {"tokens": P(d)}, self._pa_specs(), P(d),
                       P() if has_hi else None),
             out_specs=(state_specs, P(d), P(None, None, d)),
             # non-cache outputs are replicated over model by construction
             # (identical math from all-gathered weights); not statically
-            # provable, so the rep checker is off here (module docstring)
-            check_rep=False)
+            # provable, so the vma checker is off here (module docstring)
+            check_vma=False)
         return jax.jit(fn)
 
     def prefill(self, sp, batch, pa, rows=None, head_importance=None):
@@ -250,14 +250,14 @@ class MeshExecutor(Executor):
                                         model_axis=ec.model_axis)
 
         d = ec.data_axis
-        fn = shard_map(
+        fn = jax.shard_map(
             inner, mesh=self.mesh,
             in_specs=(sp_specs, P(d), self._pa_specs(), state_specs, P(d),
                       P(d), P(d), P(), P() if has_hi else None),
             out_specs=(state_specs, P(d), P(None, None, d)),
             # chunk attention all-gathers the cache over model; non-cache
             # outputs are replicated by construction (same as prefill)
-            check_rep=False)
+            check_vma=False)
         donate = (3,) if ec.donate_state else ()
         return jax.jit(fn, donate_argnums=donate)
 
@@ -323,17 +323,17 @@ class MeshExecutor(Executor):
                                       paged_impl=impl, kv_kinds=kinds)
 
         d = ec.data_axis
-        # the static replication checker stays on for XLA-only decode; a
+        # the static varying-axes checker stays on for XLA-only decode; a
         # Pallas kernel in the trace (TPU, impl="pallas", or forced
-        # interpret) has no replication rule, so the check is dropped there
+        # interpret) has no rule for it, so the check is dropped there
         # (semantics unchanged — ops.pallas_in_decode)
         from repro.kernels.ops import pallas_in_decode
-        fn = shard_map(
+        fn = jax.shard_map(
             inner, mesh=self.mesh,
             in_specs=(sp_specs, state_specs, self._pa_specs(), P(d), P(d),
                       P(d)),
             out_specs=(state_specs, P(d)),
-            check_rep=not pallas_in_decode(self.paged_impl))
+            check_vma=not pallas_in_decode(self.paged_impl))
         donate = (1,) if ec.donate_state else ()
         return jax.jit(fn, donate_argnums=donate)
 
@@ -356,7 +356,7 @@ class MeshExecutor(Executor):
                 f"{self.data_size}; size the batch (scheduler max_rows / "
                 f"generate batch) as a multiple of the data-axis width")
         jit = self._decode_jit_for(sp, state)
-        args = (sp, state, pa, tokens, active, rows)
+        args = (sp, self.shard_state(state), pa, tokens, active, rows)
         if not self.obs.enabled:
             return jit(*args)
         return self._observe_step("decode", jit, args)
@@ -379,12 +379,12 @@ class MeshExecutor(Executor):
 
         d = ec.data_axis
         from repro.kernels.ops import pallas_in_decode
-        fn = shard_map(
+        fn = jax.shard_map(
             inner, mesh=self.mesh,
             in_specs=(sp_specs, state_specs, self._pa_specs(), P(d), P(d),
                       P(d)),
             out_specs=(state_specs, P(d, None)),
-            check_rep=not pallas_in_decode(self.paged_impl))
+            check_vma=not pallas_in_decode(self.paged_impl))
         donate = (1,) if ec.donate_state else ()
         return jax.jit(fn, donate_argnums=donate)
 
@@ -404,12 +404,12 @@ class MeshExecutor(Executor):
 
         d = ec.data_axis
         from repro.kernels.ops import pallas_in_decode
-        fn = shard_map(
+        fn = jax.shard_map(
             inner, mesh=self.mesh,
             in_specs=(sp_specs, state_specs, self._pa_specs(), P(d, None),
                       P(d), P(d), P(d)),
             out_specs=(state_specs, P(d, None), P(d), P(d, None, None)),
-            check_rep=not pallas_in_decode(self.paged_impl))
+            check_vma=not pallas_in_decode(self.paged_impl))
         donate = (1,) if ec.donate_state else ()
         return jax.jit(fn, donate_argnums=donate)
 
@@ -434,7 +434,8 @@ class MeshExecutor(Executor):
         if key not in self._propose_jits:
             self._propose_jits[key] = self._build_propose(
                 sp_specs, self._state_specs(state), draft_layers, max_k)
-        args = (sp, state, pa, jnp.asarray(depths, jnp.int32), active, rows)
+        args = (sp, self.shard_state(state), pa,
+                jnp.asarray(depths, jnp.int32), active, rows)
         if not self.obs.enabled:
             return self._propose_jits[key](*args)
         return self._observe_step("propose", self._propose_jits[key], args)
@@ -453,13 +454,27 @@ class MeshExecutor(Executor):
         if key not in self._verify_jits:
             self._verify_jits[key] = self._build_verify(
                 sp_specs, self._state_specs(state), draft_layers)
-        args = (sp, state, pa, tokens, jnp.asarray(q_lens, jnp.int32),
-                active, rows)
+        args = (sp, self.shard_state(state), pa, tokens,
+                jnp.asarray(q_lens, jnp.int32), active, rows)
         if not self.obs.enabled:
             return self._verify_jits[key](*args)
         return self._observe_step("verify", self._verify_jits[key], args)
 
+    def shard_params(self, sp):
+        from jax.sharding import NamedSharding
+        return jax.tree.map(
+            lambda x, s: jax.device_put(x, NamedSharding(self.mesh, s)),
+            sp, self._sp_specs(sp))
+
     def shard_state(self, state):
+        """Place every state leaf under the decode in_specs.
+
+        The decode, propose and verify entry points pass their state
+        through here: host-built leaves — the paged backend re-uploads its
+        block-table mirror whenever blocks are allocated or trimmed — arrive
+        unplaced, and jit keys its trace cache on argument shardings, so an
+        unplaced table would retrace the StepFn.  Leaves already placed
+        pass through untouched (``device_put`` returns them as they are)."""
         from jax.sharding import NamedSharding
         specs = self._state_specs(state)
         return jax.tree.map(

@@ -13,7 +13,9 @@ Design (TPU-adapted flash-decoding):
 - online softmax (m, l, acc) in VMEM scratch, fp32; the final block writes
   ``acc / l`` (zeros for rows the slot does not own, i.e. len == 0).
 - optional sliding-window masking via per-entry absolute positions
-  (gemma2 local layers / hymba), and gemma2's attention softcap.
+  (gemma2 local layers / hymba), and gemma2's attention softcap.  The
+  positions travel as ``(S, B, 1, C)`` so their block's last two dims are
+  ``(1, block_c)`` — legal for the TPU lowering at any batch width.
 
 Validated in interpret mode against ``ref.fairkv_decode_ref`` over
 shape/dtype sweeps (tests/test_kernels.py).
@@ -29,8 +31,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import compiler_params
-
 NEG_INF = -1e30
 
 
@@ -42,7 +42,7 @@ def _kernel(
     q_ref,  # (1, 1, G, Dh)
     k_ref,  # (1, 1, block_c, Dh)
     v_ref,  # (1, 1, block_c, Dh)
-    kpos_ref,  # (1, 1, block_c) int32
+    kpos_ref,  # (1, 1, 1, block_c) int32
     # output
     o_ref,  # (1, 1, G, Dh)
     # scratch
@@ -78,9 +78,8 @@ def _kernel(
         offs = c * block_c + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
         valid = offs < ln
         if window > 0:
-            kp = kpos_ref[0, 0]  # (blk,) int32
-            qp = q_pos_ref[b]
-            valid &= kp[None, :] > (qp - window)
+            kp = kpos_ref[0, 0]  # (1, blk) int32
+            valid &= kp > (q_pos_ref[b] - window)
         scores = jnp.where(valid, scores, NEG_INF)
         m_prev = m_ref[...]  # (G, 1)
         m_new = jnp.maximum(m_prev, scores.max(axis=1, keepdims=True))
@@ -135,15 +134,15 @@ def fairkv_decode_pallas(
     def q_map(s, b, c, lens, qp):
         return (b, s, 0, 0)
 
-    def kv_map(s, b, c, lens, qp):
+    def last_valid(s, b, c, lens):
         ln = lens[s, b]
-        last_valid = jnp.maximum((ln + block_c - 1) // block_c - 1, 0)
-        return (s, b, jnp.minimum(c, last_valid), 0)
+        return jnp.minimum(c, jnp.maximum((ln + block_c - 1) // block_c - 1, 0))
+
+    def kv_map(s, b, c, lens, qp):
+        return (s, b, last_valid(s, b, c, lens), 0)
 
     def kpos_map(s, b, c, lens, qp):
-        ln = lens[s, b]
-        last_valid = jnp.maximum((ln + block_c - 1) // block_c - 1, 0)
-        return (s, b, jnp.minimum(c, last_valid))
+        return (s, b, 0, last_valid(s, b, c, lens))
 
     def o_map(s, b, c, lens, qp):
         return (b, s, 0, 0)
@@ -155,7 +154,7 @@ def fairkv_decode_pallas(
             pl.BlockSpec((1, 1, G, Dh), q_map),
             pl.BlockSpec((1, 1, block_c, Dh), kv_map),
             pl.BlockSpec((1, 1, block_c, Dh), kv_map),
-            pl.BlockSpec((1, 1, block_c), kpos_map),
+            pl.BlockSpec((1, 1, 1, block_c), kpos_map),
         ],
         out_specs=pl.BlockSpec((1, 1, G, Dh), o_map),
         scratch_shapes=[
@@ -172,7 +171,7 @@ def fairkv_decode_pallas(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, S, G, Dh), q.dtype),
         interpret=interpret,
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-    )(lengths, q_pos, q, k, v, k_pos)
+    )(lengths, q_pos, q, k, v, k_pos.reshape(S, B, 1, k_pos.shape[2]))
     return out

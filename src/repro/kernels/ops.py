@@ -16,9 +16,10 @@
 - "auto"      pallas on TPU, jnp elsewhere
 
 ``REPRO_PALLAS_INTERPRET=1`` forces every "auto" dispatch onto the Pallas
-kernels in interpret mode even off-TPU — the CI ``kernels-interpret`` gate
-uses it so kernel regressions fail in a named job instead of hiding behind
-the jnp fallback.
+kernels in interpret mode off-TPU — the CI ``kernels-interpret`` gate uses
+it so kernel regressions fail in a named job instead of hiding behind the
+jnp fallback.  On a TPU it is an error: interpret mode there would run the
+serving path without its compiled kernels.
 """
 from __future__ import annotations
 
@@ -39,24 +40,31 @@ def _on_tpu() -> bool:
 
 
 def _force_interpret() -> bool:
-    """True when REPRO_PALLAS_INTERPRET forces Pallas-interpret off-TPU."""
-    return os.environ.get("REPRO_PALLAS_INTERPRET", "") not in ("", "0")
+    """True when REPRO_PALLAS_INTERPRET forces Pallas-interpret off-TPU;
+    raises when it is set on a TPU backend."""
+    if os.environ.get("REPRO_PALLAS_INTERPRET", "") in ("", "0"):
+        return False
+    if _on_tpu():
+        raise RuntimeError(
+            "REPRO_PALLAS_INTERPRET is set but the backend is a TPU; unset "
+            "it — interpret mode would bypass the compiled kernels")
+    return True
 
 
 def _use_pallas(backend: str) -> bool:
     if backend == "jnp":
         return False
     if backend == "auto":
-        return _on_tpu() or _force_interpret()
+        return _force_interpret() or _on_tpu()
     return True
 
 
 def pallas_in_decode(paged_impl: str = "auto") -> bool:
     """True when the decode step's attention resolves to a Pallas kernel
     under the current backend/env — the mesh executor must then build its
-    decode ``shard_map`` with ``check_rep=False`` (``pallas_call`` has no
-    replication rule for the static checker; the psum-reassembly contract
-    is unchanged, only its static verification is skipped)."""
+    decode ``shard_map`` with ``check_vma=False`` (``pallas_call`` has no
+    varying-manual-axes rule for the static checker; the psum-reassembly
+    contract is unchanged, only its static verification is skipped)."""
     # slot kernel and "auto"/"gather" paged dispatch all hit pallas then
     return _use_pallas("auto") or paged_impl == "pallas"
 

@@ -11,12 +11,18 @@ realized retained lengths FairKV balances across shards (DESIGN.md §11).
 
 Design (TPU-adapted flash-decoding over block tables):
 - grid = (S, B, M); one program attends one (slot, row) over one pool
-  block of ``bs`` positions (logical columns ``[j·bs, (j+1)·bs)``).
-- the block table and ``lengths`` ride in scalar prefetch; the K/V
-  BlockSpec index maps resolve ``table[s, b, j]`` per grid step.  Steps
+  block of ``bs`` positions (logical columns ``[j·bs, (j+1)·bs)``).  The
+  query axes fold into one ``(Q·G, Dh)`` block: Q = 1 for single-token
+  decode, Q = k + 1 for the speculative-verify window (DESIGN.md §16).
+- the block table, ``lengths``, ``q_pos`` and ``q_lens`` ride in scalar
+  prefetch; the K/V BlockSpec index maps resolve ``table[s, b, j]`` per
+  grid step.  Steps
   past ``ceil(len/bs)`` clamp to the *last valid* block's pool index, so
   consecutive grid steps map to the same block and the Pallas TPU pipeline
   skips the redundant copy — null and past-length blocks cost no bandwidth.
+- every block's last two dims equal the array's (the TPU lowering's rule
+  for blocks narrower than an (8, 128) tile): positions travel as
+  ``(N, 1, bs)`` and scales as ``(N, 1, 1)``.
 - rows with no valid blocks resolve to the table's first entry (the null
   block); its garbage never reaches the output because the in-kernel
   length mask zeroes every score past ``lengths[s, b]``.
@@ -27,15 +33,15 @@ Design (TPU-adapted flash-decoding over block tables):
   before masking, matching the slot kernel bit-for-bit on the same math.
 
 Quantized pools (DESIGN.md §15): when the backend stores int8 codes the
-kernel takes two extra ``(N, 1)`` fp32 scale operands whose BlockSpecs ride
+kernel takes two extra ``(N, 1, 1)`` fp32 scale operands whose BlockSpecs ride
 the *same* block-id index map as K/V — each grid step's HBM→VMEM copy is
 then ``2·bs·Dh`` bytes of codes plus 8 bytes of scale instead of
 ``2·bs·Dh·itemsize`` bytes of floats, and the dequant
 (``codes → fp32 · scale``) happens in-register inside the online-softmax
-loop.  A fourth scalar-prefetch operand carries the (S,) per-slot kind
+loop.  A fifth scalar-prefetch operand carries the (S,) per-slot kind
 codes (0 = int8, 1 = fp8-bitcast) selecting the dequant interpretation per
-program.  The fp32 path takes the original operand list — the quantized
-knob off compiles a byte-identical kernel.
+program.  The unquantized path takes neither — the quantized knob off
+compiles the same kernel as before it existed.
 
 Validated in interpret mode against ``ref.paged_fairkv_decode_ref``
 (tests/test_paged_kernel.py); dispatched via ``ops.paged_fairkv_decode``.
@@ -50,8 +56,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-from repro.kernels.pallas_compat import compiler_params
 
 NEG_INF = -1e30
 
@@ -77,82 +81,17 @@ def _dequant(codes, scale, kind):
     return f * scale
 
 
+def _query_index(rows: int, n_q: int, group: int):
+    """(rows, 1) int32 query index of each folded ``(Q·G)`` score row
+    (``row // group``), built from compares — no vector integer division."""
+    r = jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
+    qi = jnp.zeros_like(r)
+    for i in range(1, n_q):
+        qi = jnp.where(r >= i * group, i, qi)
+    return qi
+
+
 def _kernel(
-    *refs,
-    bs: int,
-    n_blocks: int,
-    scale: float,
-    attn_cap: float,
-    window: int,
-    quantized: bool,
-):
-    # operand order mirrors the two pallas_call signatures below: scalar
-    # prefetch (table, lengths, q_pos[, kinds]), then inputs
-    # (q, k, v, kpos[, k_scale, v_scale]), output, scratch
-    if quantized:
-        (table_ref, lengths_ref, q_pos_ref, kinds_ref,
-         q_ref, k_ref, v_ref, kpos_ref, ksc_ref, vsc_ref,
-         o_ref, acc_ref, m_ref, l_ref) = refs
-    else:
-        (table_ref, lengths_ref, q_pos_ref,
-         q_ref, k_ref, v_ref, kpos_ref,
-         o_ref, acc_ref, m_ref, l_ref) = refs
-    s, b, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
-    ln = lengths_ref[s, b]
-    n_valid = (ln + bs - 1) // bs
-
-    @pl.when(j == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-
-    @pl.when(j < n_valid)
-    def _compute():
-        q = q_ref[0, 0].astype(jnp.float32)  # (G, Dh)
-        if quantized:
-            kind = kinds_ref[s]
-            k = _dequant(k_ref[0], ksc_ref[0, 0], kind)  # (bs, Dh)
-        else:
-            k = k_ref[0].astype(jnp.float32)  # (bs, Dh)
-        scores = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale  # (G, bs)
-        if attn_cap > 0:
-            scores = attn_cap * jnp.tanh(scores / attn_cap)
-        offs = j * bs + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
-        valid = offs < ln  # masks the last block's partial fill too
-        if window > 0:
-            kp = kpos_ref[0]  # (bs,) int32 absolute entry positions
-            qp = q_pos_ref[b]
-            valid &= kp[None, :] > (qp - window)
-        scores = jnp.where(valid, scores, NEG_INF)
-        m_prev = m_ref[...]  # (G, 1)
-        m_new = jnp.maximum(m_prev, scores.max(axis=1, keepdims=True))
-        # explicit mask: when every entry is masked, m_new stays NEG_INF and
-        # exp(NEG_INF - NEG_INF) would be 1 — the mask zeroes it instead
-        p = jnp.where(valid, jnp.exp(scores - m_new), 0.0)
-        corr = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * corr + p.sum(axis=1, keepdims=True)
-        m_ref[...] = m_new
-        if quantized:
-            v = _dequant(v_ref[0], vsc_ref[0, 0], kinds_ref[s])  # (bs, Dh)
-        else:
-            v = v_ref[0].astype(jnp.float32)  # (bs, Dh)
-        pv = jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        acc_ref[...] = acc_ref[...] * corr + pv
-
-    @pl.when(j == n_blocks - 1)
-    def _finalize():
-        l = l_ref[...]
-        out = acc_ref[...] / jnp.where(l > 0, l, 1.0)
-        out = jnp.where(l > 0, out, 0.0)
-        o_ref[0, 0] = out.astype(o_ref.dtype)
-
-
-def _mq_kernel(
     *refs,
     bs: int,
     n_blocks: int,
@@ -163,13 +102,15 @@ def _mq_kernel(
     window: int,
     quantized: bool,
 ):
-    """Multi-query (speculative-verify) variant: one program attends the
-    full (Q, G) query block of one (slot, row) over one pool block.  The
-    query axis folds into the sublane dim — scores and scratch are
-    ``(Q·G, ·)`` — and the causal mask within the speculative window is a
-    per-query length limit: query ``i`` of a row with ``qn`` valid queries
-    sees the first ``len − (qn − 1 − i)`` entries (own token included,
-    later speculative tokens excluded)."""
+    """One program attends the folded ``(Q·G, Dh)`` query block of one
+    (slot, row) over one pool block.  With ``n_q > 1`` (speculative verify)
+    the causal mask within the window is a per-query length limit: query
+    ``i`` of a row with ``qn`` valid queries sees the first
+    ``len − (qn − 1 − i)`` entries (own token included, later speculative
+    tokens excluded)."""
+    # operand order mirrors the pallas_call below: scalar prefetch (table,
+    # lengths, q_pos, q_lens[, kinds]), then inputs (q, k, v, kpos[,
+    # k_scale, v_scale]), output, scratch
     if quantized:
         (table_ref, lengths_ref, q_pos_ref, q_lens_ref, kinds_ref,
          q_ref, k_ref, v_ref, kpos_ref, ksc_ref, vsc_ref,
@@ -190,10 +131,9 @@ def _mq_kernel(
 
     @pl.when(j < n_valid)
     def _compute():
-        q = q_ref[0, 0].astype(jnp.float32).reshape(n_q * group, -1)
+        q = q_ref[0, 0].astype(jnp.float32)  # (Q·G, Dh)
         if quantized:
-            kind = kinds_ref[s]
-            k = _dequant(k_ref[0], ksc_ref[0, 0], kind)  # (bs, Dh)
+            k = _dequant(k_ref[0], ksc_ref[0], kinds_ref[s])  # (bs, Dh)
         else:
             k = k_ref[0].astype(jnp.float32)  # (bs, Dh)
         scores = jax.lax.dot_general(
@@ -202,24 +142,29 @@ def _mq_kernel(
         if attn_cap > 0:
             scores = attn_cap * jnp.tanh(scores / attn_cap)
         offs = j * bs + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
-        qi = jax.lax.broadcasted_iota(jnp.int32, scores.shape, 0) // group
-        qn = q_lens_ref[b]
-        # per-query causal limit; garbage lanes (qi >= qn) clamp to ln
-        limit = jnp.minimum(ln - (qn - 1 - qi), ln)
-        valid = offs < limit
+        qp = q_pos_ref[b]
+        if n_q == 1:
+            valid = offs < ln  # masks the last block's partial fill too
+        else:
+            qi = _query_index(scores.shape[0], n_q, group)  # (Q·G, 1)
+            qn = q_lens_ref[b]
+            # per-query causal limit; garbage lanes (qi >= qn) clamp to ln
+            valid = offs < jnp.minimum(ln - (qn - 1 - qi), ln)
+            qp = qp + qi  # query i sits at q_pos + i
         if window > 0:
-            kp = kpos_ref[0]  # (bs,) int32 absolute entry positions
-            qp = q_pos_ref[b] + qi  # query i sits at q_pos + i
-            valid &= kp[None, :] > (qp - window)
+            # (1, bs) int32 absolute entry positions of this block
+            valid &= kpos_ref[0] > (qp - window)
         scores = jnp.where(valid, scores, NEG_INF)
         m_prev = m_ref[...]  # (Q·G, 1)
         m_new = jnp.maximum(m_prev, scores.max(axis=1, keepdims=True))
+        # explicit mask: when every entry is masked, m_new stays NEG_INF and
+        # exp(NEG_INF - NEG_INF) would be 1 — the mask zeroes it instead
         p = jnp.where(valid, jnp.exp(scores - m_new), 0.0)
         corr = jnp.exp(m_prev - m_new)
         l_ref[...] = l_ref[...] * corr + p.sum(axis=1, keepdims=True)
         m_ref[...] = m_new
         if quantized:
-            v = _dequant(v_ref[0], vsc_ref[0, 0], kinds_ref[s])  # (bs, Dh)
+            v = _dequant(v_ref[0], vsc_ref[0], kinds_ref[s])  # (bs, Dh)
         else:
             v = v_ref[0].astype(jnp.float32)  # (bs, Dh)
         pv = jax.lax.dot_general(
@@ -232,95 +177,7 @@ def _mq_kernel(
         l = l_ref[...]
         out = acc_ref[...] / jnp.where(l > 0, l, 1.0)
         out = jnp.where(l > 0, out, 0.0)
-        o_ref[0, 0] = out.reshape(n_q, group, -1).astype(o_ref.dtype)
-
-
-def _paged_decode_pallas_mq(
-    q, k_pool, v_pool, pos_pool, block_table, lengths, capacity,
-    attn_cap, q_pos, q_lens, window, interpret, k_scale, v_scale, kinds,
-):
-    """Multi-query pallas_call assembly — same grid/index maps as the
-    single-query path with ``q_lens`` riding as an extra scalar-prefetch
-    operand and (Q, G)-blocked query/output BlockSpecs."""
-    B, S, Q, G, Dh = q.shape
-    N, bs, _ = k_pool.shape
-    M = block_table.shape[2]
-    if M * bs < capacity:
-        raise ValueError(
-            f"block table spans {M}x{bs} tokens < capacity {capacity}")
-    table = jnp.asarray(block_table, jnp.int32)
-    lengths = jnp.asarray(lengths, jnp.int32)
-    if q_pos is None:
-        q_pos = jnp.zeros((B,), jnp.int32)
-    if q_lens is None:
-        q_lens = jnp.full((B,), Q, jnp.int32)
-    q_lens = jnp.asarray(q_lens, jnp.int32)
-    quantized = k_scale is not None
-
-    def q_map(s, b, j, tbl, lens, *rest):
-        return (b, s, 0, 0, 0)
-
-    def block_id(s, b, j, tbl, lens):
-        ln = lens[s, b]
-        last_valid = jnp.maximum((ln + bs - 1) // bs - 1, 0)
-        jj = jnp.minimum(j, last_valid)
-        return jnp.maximum(tbl[s, b, jj], 0)
-
-    def kv_map(s, b, j, tbl, lens, *rest):
-        return (block_id(s, b, j, tbl, lens), 0, 0)
-
-    def kpos_map(s, b, j, tbl, lens, *rest):
-        return (block_id(s, b, j, tbl, lens), 0)
-
-    def scale_map(s, b, j, tbl, lens, *rest):
-        return (block_id(s, b, j, tbl, lens), 0)
-
-    def o_map(s, b, j, tbl, lens, *rest):
-        return (b, s, 0, 0, 0)
-
-    in_specs = [
-        pl.BlockSpec((1, 1, Q, G, Dh), q_map),
-        pl.BlockSpec((1, bs, Dh), kv_map),
-        pl.BlockSpec((1, bs, Dh), kv_map),
-        pl.BlockSpec((1, bs), kpos_map),
-    ]
-    num_prefetch = 4
-    args = [table, lengths, q_pos, q_lens, q, k_pool, v_pool, pos_pool]
-    if quantized:
-        kind = (jnp.zeros((S,), jnp.int32) if kinds is None
-                else jnp.asarray(kinds, jnp.int32))
-        num_prefetch = 5
-        args = [table, lengths, q_pos, q_lens, kind, q, k_pool, v_pool,
-                pos_pool,
-                jnp.asarray(k_scale, jnp.float32).reshape(N, 1),
-                jnp.asarray(v_scale, jnp.float32).reshape(N, 1)]
-        in_specs = in_specs + [
-            pl.BlockSpec((1, 1), scale_map),
-            pl.BlockSpec((1, 1), scale_map),
-        ]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=num_prefetch,
-        grid=(S, B, M),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, Q, G, Dh), o_map),
-        scratch_shapes=[
-            pltpu.VMEM((Q * G, Dh), jnp.float32),
-            pltpu.VMEM((Q * G, 1), jnp.float32),
-            pltpu.VMEM((Q * G, 1), jnp.float32),
-        ],
-    )
-    kernel = functools.partial(
-        _mq_kernel, bs=bs, n_blocks=M, n_q=Q, group=G,
-        scale=1.0 / math.sqrt(Dh), attn_cap=attn_cap, window=window,
-        quantized=quantized)
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, S, Q, G, Dh), q.dtype),
-        interpret=interpret,
-        compiler_params=compiler_params(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-    )(*args)
+        o_ref[0, 0] = out.astype(o_ref.dtype)
 
 
 def paged_fairkv_decode_pallas(
@@ -343,16 +200,17 @@ def paged_fairkv_decode_pallas(
     """Decode attention over one paged layer — same contract as
     ``ref.paged_fairkv_decode_ref``, consuming pools + table directly.
 
-    A 5-D ``q`` selects the multi-query speculative-verify path
-    (`_mq_kernel`); the 4-D single-query path below is byte-identical to
-    its pre-speculation form, so single-token decode traces are unchanged.
+    A 5-D ``q`` selects the multi-query speculative-verify semantics; both
+    forms fold the query axes into one ``(Q·G, Dh)`` block per program
+    (Q = 1 for single-token decode).  Operands are laid out so every
+    block's last two dims equal the array's — ``pos_pool`` as
+    ``(N, 1, bs)``, scales as ``(N, 1, 1)`` — which is what the TPU
+    lowering requires of blocks narrower than an (8, 128) tile.
     """
     if q.ndim == 5:
-        return _paged_decode_pallas_mq(
-            q, k_pool, v_pool, pos_pool, block_table, lengths, capacity,
-            attn_cap, q_pos, q_lens, window, interpret, k_scale, v_scale,
-            kinds)
-    B, S, G, Dh = q.shape
+        B, S, Q, G, Dh = q.shape
+    else:
+        (B, S, G, Dh), Q = q.shape, 1
     N, bs, _ = k_pool.shape
     M = block_table.shape[2]
     if M * bs < capacity:
@@ -360,75 +218,61 @@ def paged_fairkv_decode_pallas(
             f"block table spans {M}x{bs} tokens < capacity {capacity}")
     table = jnp.asarray(block_table, jnp.int32)
     lengths = jnp.asarray(lengths, jnp.int32)
-    if q_pos is None:
-        q_pos = jnp.zeros((B,), jnp.int32)
+    q_pos = (jnp.zeros((B,), jnp.int32) if q_pos is None
+             else jnp.asarray(q_pos, jnp.int32))
+    q_lens = (jnp.full((B,), Q, jnp.int32) if q_lens is None
+              else jnp.asarray(q_lens, jnp.int32))
     quantized = k_scale is not None
 
-    # *rest absorbs the extra (kinds) scalar-prefetch ref on the quantized
-    # path so one set of index maps serves both operand lists
+    # *rest absorbs the scalar-prefetch refs past (table, lengths)
     def q_map(s, b, j, tbl, lens, *rest):
         return (b, s, 0, 0)
 
-    def block_id(s, b, j, tbl, lens):
+    def pool_map(s, b, j, tbl, lens, *rest):
         # clamp past-length grid steps to the last valid block so
         # consecutive steps map to equal indices (pipeline skips the copy);
         # rows with no valid blocks resolve to entry 0 (the null block)
         ln = lens[s, b]
         last_valid = jnp.maximum((ln + bs - 1) // bs - 1, 0)
         jj = jnp.minimum(j, last_valid)
-        return jnp.maximum(tbl[s, b, jj], 0)
-
-    def kv_map(s, b, j, tbl, lens, *rest):
-        return (block_id(s, b, j, tbl, lens), 0, 0)
-
-    def kpos_map(s, b, j, tbl, lens, *rest):
-        return (block_id(s, b, j, tbl, lens), 0)
-
-    def scale_map(s, b, j, tbl, lens, *rest):
-        return (block_id(s, b, j, tbl, lens), 0)
-
-    def o_map(s, b, j, tbl, lens, *rest):
-        return (b, s, 0, 0)
+        return (jnp.maximum(tbl[s, b, jj], 0), 0, 0)
 
     in_specs = [
-        pl.BlockSpec((1, 1, G, Dh), q_map),
-        pl.BlockSpec((1, bs, Dh), kv_map),
-        pl.BlockSpec((1, bs, Dh), kv_map),
-        pl.BlockSpec((1, bs), kpos_map),
+        pl.BlockSpec((1, 1, Q * G, Dh), q_map),
+        pl.BlockSpec((1, bs, Dh), pool_map),
+        pl.BlockSpec((1, bs, Dh), pool_map),
+        pl.BlockSpec((1, 1, bs), pool_map),
     ]
-    num_prefetch = 3
-    args = [table, lengths, q_pos, q, k_pool, v_pool, pos_pool]
+    prefetch = [table, lengths, q_pos, q_lens]
+    inputs = [q.reshape(B, S, Q * G, Dh), k_pool, v_pool,
+              jnp.asarray(pos_pool, jnp.int32).reshape(N, 1, bs)]
     if quantized:
-        kind = (jnp.zeros((S,), jnp.int32) if kinds is None
-                else jnp.asarray(kinds, jnp.int32))
-        num_prefetch = 4
-        args = [table, lengths, q_pos, kind, q, k_pool, v_pool, pos_pool,
-                jnp.asarray(k_scale, jnp.float32).reshape(N, 1),
-                jnp.asarray(v_scale, jnp.float32).reshape(N, 1)]
-        in_specs = in_specs + [
-            pl.BlockSpec((1, 1), scale_map),
-            pl.BlockSpec((1, 1), scale_map),
-        ]
+        prefetch.append(jnp.zeros((S,), jnp.int32) if kinds is None
+                        else jnp.asarray(kinds, jnp.int32))
+        inputs += [jnp.asarray(k_scale, jnp.float32).reshape(N, 1, 1),
+                   jnp.asarray(v_scale, jnp.float32).reshape(N, 1, 1)]
+        in_specs += [pl.BlockSpec((1, 1, 1), pool_map)] * 2
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=num_prefetch,
+        num_scalar_prefetch=len(prefetch),
         grid=(S, B, M),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, G, Dh), o_map),
+        out_specs=pl.BlockSpec((1, 1, Q * G, Dh), q_map),
         scratch_shapes=[
-            pltpu.VMEM((G, Dh), jnp.float32),
-            pltpu.VMEM((G, 1), jnp.float32),
-            pltpu.VMEM((G, 1), jnp.float32),
+            pltpu.VMEM((Q * G, Dh), jnp.float32),
+            pltpu.VMEM((Q * G, 1), jnp.float32),
+            pltpu.VMEM((Q * G, 1), jnp.float32),
         ],
     )
     kernel = functools.partial(
-        _kernel, bs=bs, n_blocks=M, scale=1.0 / math.sqrt(Dh),
-        attn_cap=attn_cap, window=window, quantized=quantized)
+        _kernel, bs=bs, n_blocks=M, n_q=Q, group=G,
+        scale=1.0 / math.sqrt(Dh), attn_cap=attn_cap, window=window,
+        quantized=quantized)
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, S, G, Dh), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, S, Q * G, Dh), q.dtype),
         interpret=interpret,
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-    )(*args)
-    return out
+    )(*prefetch, *inputs)
+    return out.reshape(q.shape)

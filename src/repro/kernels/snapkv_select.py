@@ -12,8 +12,10 @@ T·budget for selection).  Two-phase grid over T blocks:
   phase 1 (c >= nT): emit Σ_{w,g} exp(s - m)/l for block c - nT
 
 Both phases stream the same K blocks; the q tile (W·G, Dh) stays VMEM-
-resident across the whole (b, h) program.  Validated in interpret mode
-against ``ref.snapkv_scores_ref``.
+resident across the whole (b, h) program.  Key positions travel as
+``(B, 1, T)`` and scores as ``(B, Hkv, 1, T)`` so each block's last two
+dims are ``(1, block_t)`` — legal for the TPU lowering at any batch.
+Validated in interpret mode against ``ref.snapkv_scores_ref``.
 """
 from __future__ import annotations
 
@@ -25,8 +27,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import compiler_params
-
 NEG_INF = -1e30
 
 
@@ -34,8 +34,8 @@ def _kernel(
     obs_pos_ref,  # (B, W) int32 scalar prefetch
     q_ref,  # (1, W*G, Dh)
     k_ref,  # (1, 1, block_t, Dh)
-    kpos_ref,  # (1, block_t) int32
-    o_ref,  # (1, 1, block_t) f32
+    kpos_ref,  # (1, 1, block_t) int32
+    o_ref,  # (1, 1, 1, block_t) f32
     m_ref,  # (W*G, 1) f32
     l_ref,  # (W*G, 1) f32
     *,
@@ -52,7 +52,7 @@ def _kernel(
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    def scores_and_mask(blk_idx):
+    def scores_and_mask():
         q = q_ref[0].astype(jnp.float32)  # (W*G, Dh)
         k = k_ref[0, 0].astype(jnp.float32)  # (blk, Dh)
         s = jax.lax.dot_general(
@@ -60,16 +60,18 @@ def _kernel(
             preferred_element_type=jnp.float32) * scale  # (W*G, blk)
         if attn_cap > 0:
             s = attn_cap * jnp.tanh(s / attn_cap)
-        kp = kpos_ref[0]  # (blk,)
-        wg = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) // g  # query idx
-        qp = obs_pos_ref[b]  # (W,) — gather per row
-        qp_row = qp[wg[:, 0]][:, None] if False else jnp.take(qp, wg[:, 0])[:, None]
-        causal = kp[None, :] <= qp_row
+        # (W*G, 1) position of each row's query (rows are w-major, g-minor),
+        # read as W scalars — no in-kernel gather
+        r = jax.lax.broadcasted_iota(jnp.int32, (s.shape[0], 1), 0)
+        qp_row = jnp.zeros_like(r)
+        for w in range(s.shape[0] // g):
+            qp_row = jnp.where(r >= w * g, obs_pos_ref[b, w], qp_row)
+        causal = kpos_ref[0] <= qp_row  # (1, blk) vs (W*G, 1)
         return jnp.where(causal, s, NEG_INF), causal
 
     @pl.when(c < n_blocks)
     def _phase_lse():
-        s, causal = scores_and_mask(c)
+        s, causal = scores_and_mask()
         m_prev = m_ref[...]
         m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
         p = jnp.where(causal, jnp.exp(s - m_new), 0.0)
@@ -79,11 +81,11 @@ def _kernel(
 
     @pl.when(c >= n_blocks)
     def _phase_emit():
-        s, causal = scores_and_mask(c - n_blocks)
+        s, causal = scores_and_mask()
         m = m_ref[...]
         l = l_ref[...]
         p = jnp.where(causal, jnp.exp(s - m), 0.0) / jnp.where(l > 0, l, 1.0)
-        o_ref[0, 0] = p.sum(axis=0).astype(o_ref.dtype)
+        o_ref[0, 0] = p.sum(axis=0, keepdims=True).astype(o_ref.dtype)
 
 
 def snapkv_scores_pallas(
@@ -118,11 +120,11 @@ def snapkv_scores_pallas(
 
     def kpos_map(b, h, c, opos):
         cc = jnp.where(c < n_blocks, c, c - n_blocks)
-        return (b, cc)
+        return (b, 0, cc)
 
     def o_map(b, h, c, opos):
         cc = jnp.maximum(c - n_blocks, 0)
-        return (b, h, cc)
+        return (b, h, 0, cc)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
@@ -130,9 +132,9 @@ def snapkv_scores_pallas(
         in_specs=[
             pl.BlockSpec((1, W * G, Dh), q_map),
             pl.BlockSpec((1, 1, block_t, Dh), k_map),
-            pl.BlockSpec((1, block_t), kpos_map),
+            pl.BlockSpec((1, 1, block_t), kpos_map),
         ],
-        out_specs=pl.BlockSpec((1, 1, block_t), o_map),
+        out_specs=pl.BlockSpec((1, 1, 1, block_t), o_map),
         scratch_shapes=[
             pltpu.VMEM((W * G, 1), jnp.float32),
             pltpu.VMEM((W * G, 1), jnp.float32),
@@ -144,11 +146,11 @@ def snapkv_scores_pallas(
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, Hkv, n_blocks * block_t),
+        out_shape=jax.ShapeDtypeStruct((B, Hkv, 1, n_blocks * block_t),
                                        jnp.float32),
         interpret=interpret,
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
     )(obs_positions, qt.reshape(B * Hkv, W * G, Dh),
-      k.transpose(0, 2, 1, 3), k_positions)
-    return out[:, :, :T]
+      k.transpose(0, 2, 1, 3), k_positions.reshape(B, 1, -1))
+    return out[:, :, 0, :T]

@@ -10,20 +10,14 @@ any jax import (see launch/dryrun.py lines 1-2).
 from __future__ import annotations
 
 import jax
-from jax.sharding import Mesh
-
-
-def _axis_type_kwargs(n_axes: int) -> dict:
-    """``axis_types`` only where the installed jax has it (added after
-    0.4.x; older versions default every axis to Auto anyway)."""
-    at = getattr(jax.sharding, "AxisType", None)
-    return {"axis_types": (at.Auto,) * n_axes} if at is not None else {}
+from jax.sharding import AxisType, Mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_axis_type_kwargs(len(axes)))
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(model: int = 1, data: int = 1) -> Mesh:
@@ -42,4 +36,4 @@ def make_host_mesh(model: int = 1, data: int = 1) -> Mesh:
             f"XLA_FLAGS=--xla_force_host_platform_device_count=N before "
             f"the first jax import to fake host devices")
     return jax.make_mesh((data, model), ("data", "model"),
-                         **_axis_type_kwargs(2))
+                         axis_types=(AxisType.Auto,) * 2)

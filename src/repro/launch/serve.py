@@ -79,7 +79,8 @@ def _engine_config(args, max_seq_len: int, batch_cap: int,
             obs_window=8, sink=2,
             decode_margin=max(8, getattr(args, "gen", 8))),
         planner=PlannerConfig(mode=args.planner, engine=args.engine,
-                              extra_copies=args.copies, batch_cap=batch_cap),
+                              extra_copies=args.copies, batch_cap=batch_cap,
+                              slots_per_shard=args.slots_per_shard or None),
         scheduler=scheduler,
         # --prefix-cache needs block refcounts, --kv-dtype needs block
         # storage, and --speculate needs provisional-block rollback — all
@@ -122,6 +123,7 @@ _CLI_FIELD_MAP = {
     "planner": ("planner", "mode"),
     "engine": ("planner", "engine"),
     "copies": ("planner", "extra_copies"),
+    "slots_per_shard": ("planner", "slots_per_shard"),
     "cache_backend": ("cache_backend",),
     "block_size": ("paging", "block_size"),
     "pool_blocks": ("paging", "n_blocks"),
@@ -173,13 +175,15 @@ def _engine_config_from_file(args, max_seq_len: int, batch_cap: int,
     return cfg
 
 
-def _build_engine(args, ecfg: EngineConfig) -> Engine:
-    """Engine on the configured executor (mesh: a (data, model) host mesh)."""
+def _build_engine(args, ecfg: EngineConfig, params=None) -> Engine:
+    """Engine on the configured executor (mesh: a (data, model) host mesh).
+    ``params`` (original layout) skips the seeded init — engines of one
+    model can then share a single weight copy."""
     mesh = None
     if ecfg.executor == "mesh":
         from repro.launch.mesh import make_host_mesh
         mesh = make_host_mesh(model=args.shards, data=args.data)
-    return Engine.build(ecfg, mesh=mesh)
+    return Engine.build(ecfg, mesh=mesh, params=params)
 
 
 def _collective_audit(eng: Engine) -> None:
@@ -426,7 +430,7 @@ def run_oneshot(args) -> None:
         print(f"row {b}: {res.tokens[b].tolist()}")
 
 
-def main() -> None:
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="",
                     help="architecture id (required unless --config "
@@ -451,6 +455,9 @@ def main() -> None:
     ap.add_argument("--shards", type=int, default=4,
                     help="logical model shards for the plan")
     ap.add_argument("--copies", type=int, default=4, help="CH")
+    ap.add_argument("--slots-per-shard", type=int, default=0,
+                    help="head slots per shard (0 = ceil(kv heads / "
+                         "shards), which leaves no spare slot for --copies)")
     # --- cache backend (DESIGN.md §9) ----------------------------------------
     ap.add_argument("--cache-backend", default="slot",
                     help=f"cache storage backend; registered: "
@@ -563,6 +570,11 @@ def main() -> None:
     ap.add_argument("--trace-out", default="",
                     help="write Chrome trace-event JSON here on exit "
                          "(Perfetto-loadable)")
+    return ap
+
+
+def main() -> None:
+    ap = build_parser()
     args = ap.parse_args()
     if not args.arch and not args.config:
         ap.error("one of --arch or --config is required")
@@ -575,6 +587,8 @@ def main() -> None:
         if any(tok == opt or tok.startswith(opt + "=")
                for opt in a.option_strings for tok in argv)}
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.http:
         run_http(args)
     elif args.continuous:

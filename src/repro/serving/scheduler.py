@@ -209,8 +209,6 @@ class Scheduler:
         self.dtype = dtype
         # serve_params: pre-slotified weights for *this plan* (the Engine
         # facade passes its own copy so the permutation isn't paid twice)
-        self.sp = (serve_params if serve_params is not None
-                   else slotify_params(params, plan, cfg))
         # cache backend: storage layout + admission accounting (DESIGN.md §9)
         self.backend = backend if backend is not None else make_cache_backend(
             "slot", cfg, ccfg, max_live_tokens=scfg.max_live_tokens,
@@ -222,6 +220,9 @@ class Scheduler:
         self.executor = (executor if executor is not None
                          else make_executor("local", cfg, ccfg,
                                             paging=self.backend.paging))
+        self.sp = (serve_params if serve_params is not None
+                   else self.executor.shard_params(
+                       slotify_params(params, plan, cfg)))
         # per-head weights for importance-driven policies (headkv): admission
         # prefills must compress with the same budgets the profile was
         # measured under, or realized loads drift from the plan
@@ -304,6 +305,9 @@ class Scheduler:
         # but live rows keep decoding to completion — set via drain()
         self.draining = False
         self.replan_log: List[dict] = []  # {step, imbalance_before/after}
+        # per-shard realized load at the tick holding the most KV (the
+        # balance a run reached; the final tick is empty once rows retire)
+        self.peak_shard_load = np.zeros(plan.n_shards)
         self.finished: List[Request] = []
         if self.obs.enabled:
             # pre-register outcome series so exports show explicit zeros
@@ -1091,7 +1095,8 @@ class Scheduler:
             return event
         self.state = dataclasses.replace(self.state, cache=commit())
         self.plan, self.pa = new_plan, new_pa
-        self.sp = slotify_params(self.params, new_plan, self.cfg)
+        self.sp = self.executor.shard_params(
+            slotify_params(self.params, new_plan, self.cfg))
         if self.prefix is not None:
             # the backend rebuilt its pool from live tables only (shared
             # rows were deep-copied private): the index's references died
@@ -1184,6 +1189,8 @@ class Scheduler:
         # load accounting + replan trigger (hysteresis inside the trigger);
         # the load vector feeds the trigger and the gauges from one compute
         load = self.per_shard_load()
+        if load.sum() > self.peak_shard_load.sum():
+            self.peak_shard_load = load
         imb = self._imbalance_from(load)
         self.trigger.observe(imb)
         if self.obs.enabled:
@@ -1242,6 +1249,7 @@ class Scheduler:
             "replans": self.n_replans,
             "replan_log": list(self.replan_log),
             "preemptions": self.n_preemptions,
+            "peak_shard_load": self.peak_shard_load.tolist(),
             "cancelled": sum(1 for r in self.finished if r.cancelled),
             "drained": self.draining,
             "latency": latency_percentiles(

@@ -80,10 +80,11 @@ import json
 import jax, jax.numpy as jnp
 from repro.configs import get_smoke_config, SHAPES
 from repro.configs.base import InputShape
-from repro.launch.mesh import _axis_type_kwargs
+from jax.sharding import AxisType
 from repro.launch.specs import build_cell
 
-mesh = jax.make_mesh((2, 4), ("data", "model"), **_axis_type_kwargs(2))
+mesh = jax.make_mesh((2, 4), ("data", "model"),
+                     axis_types=(AxisType.Auto,) * 2)
 cfg = get_smoke_config({arch!r})
 shape = InputShape("mini_{kind}", 64, 4, {kind!r})
 cell = build_cell(cfg, shape, mesh, quantize=False)

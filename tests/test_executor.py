@@ -142,6 +142,20 @@ def test_oneshot_replan_does_not_retrace():
     assert eng.executor.decode_traces == 1
 
 
+def test_local_step_hlo_audit():
+    """prefill_hlo / decode_hlo hand back the compiled modules the engine's
+    own arguments produce (the chip smoke scans them for its kernels)."""
+    cfg = _ecfg(max_seq_len=40)
+    eng = Engine.build(cfg)
+    prompts = {"tokens": np.random.default_rng(0).integers(
+        0, cfg.model.vocab_size, (2, 16)).astype(np.int32)}
+    eng.generate(prompts["tokens"], 2)
+    ex = eng.executor
+    assert "HloModule" in ex.prefill_hlo(eng.sp, prompts, eng.pa)
+    assert "HloModule" in ex.decode_hlo(eng.sp, eng.state, eng.pa,
+                                        eng.state.last_tokens)
+
+
 # ---------------------------------------------------------------------------
 # per-model-shard admission (slot backend)
 # ---------------------------------------------------------------------------

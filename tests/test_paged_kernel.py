@@ -407,6 +407,17 @@ def test_force_interpret_env_routes_auto_to_pallas(monkeypatch):
     assert not K._force_interpret()
 
 
+def test_force_interpret_env_is_an_error_on_tpu(monkeypatch):
+    """On a TPU the env knob must not silently swap the compiled kernels
+    for interpret mode: "auto" dispatch raises instead."""
+    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "1")
+    monkeypatch.setattr(K, "_on_tpu", lambda: True)
+    with pytest.raises(RuntimeError, match="REPRO_PALLAS_INTERPRET"):
+        K._use_pallas("auto")
+    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "0")
+    assert K._use_pallas("auto")
+
+
 def test_paging_config_validates_decode_impl():
     from repro.api import EngineConfig, PagingConfig
     with pytest.raises(ValueError, match="pallas"):

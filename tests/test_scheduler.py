@@ -268,6 +268,10 @@ def test_stream_all_finish_with_mid_stream_admissions():
     assert all(r.n_generated == r.max_new_tokens for r in reqs)
     # the batch never held more rows than configured
     assert sched.freelist.n_rows == 2
+    # the run ends empty; the summary keeps the fullest tick's shard loads
+    peak = np.asarray(out["peak_shard_load"])
+    assert sched.live_tokens() == 0
+    assert peak.shape == (sched.plan.n_shards,) and peak.sum() > 0
 
 
 def test_co_scheduled_logits_match_solo_run():
