@@ -1,0 +1,10 @@
+"""The benchmark's own CPU tests: its modules import by their bare names
+(``run``, ``counts``, ...), as ``python bench/run.py`` sees them, and the
+program under test from ``src``."""
+import sys
+from pathlib import Path
+
+BENCH = Path(__file__).resolve().parents[1]
+for p in (str(BENCH.parent / "src"), str(BENCH)):
+    if p not in sys.path:
+        sys.path.insert(0, p)
