@@ -238,8 +238,10 @@ class Engine:
         prefill_s = time.perf_counter() - t0
         # one-shot TTFT is the prefill wall (no queue to wait in)
         self.obs.metrics.histogram(
-            "ttft_s", help="time to first token (queue wait + prefill "
-                           "wall time)").observe(prefill_s)
+            "ttft_s", help="time to first token, seconds: submit to the "
+                           "first token on the host (queue wait + "
+                           "admission; the prefill alone in one-shot "
+                           "mode)").observe(prefill_s)
         # re-house the prefilled cache in the configured backend's layout
         # (identity for "slot"; "paged" allocates blocks proportional to the
         # realized retained lengths).  One-shot mode has no request queue to
@@ -277,8 +279,9 @@ class Engine:
             jax.block_until_ready(lg)
             step_s.append(time.perf_counter() - t0)
             self.obs.metrics.histogram(
-                "itl_s", help="inter-token latency (per-request mean in "
-                              "continuous mode; per-step in one-shot mode)"
+                "itl_s", help="inter-token gap, seconds: one sample per "
+                              "pair of consecutive tokens of a request (per "
+                              "decode step in one-shot mode)"
                 ).observe(step_s[-1])
             tokens.append(np.asarray(state.last_tokens))
             if collect_logits:

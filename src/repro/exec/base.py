@@ -27,7 +27,10 @@ in place across the hot loop.
 
 StepFns come in the named kinds of the ``STEP_KINDS`` table — prefill,
 prefill_chunk, decode, propose, verify (the last two are the speculative-
-decoding pair, DESIGN.md §16).  ``step_traces[kind]`` counts actual
+decoding pair, DESIGN.md §16).  Each executor jits the StepFn of kind
+``<kind>`` as a function named ``<kind>_step``, so a device trace's
+``XLA Modules`` line and the compiled HLO (``jit_decode_step``) say which
+step ran.  ``step_traces[kind]`` counts actual
 (re)traces per kind — the regression observable for "replans must not
 recompile" — and the ``stepfn_compiles_total{kind=}`` metric keys off the
 same table; the legacy ``decode_traces`` / ``prefill_traces`` /
@@ -50,8 +53,9 @@ from repro.obs import NULL_OBS
 
 # the StepFn kind table: every compiled step an executor owns is one of
 # these, and everything keyed per-kind — trace counters, the
-# `stepfn_compiles_total{kind=}` / `stepfn_wall_s{kind=}` metrics, trace
-# spans — derives from this tuple rather than hand-written attribute pairs.
+# `stepfn_compiles_total{kind=}` / `stepfn_wall_s{kind=}` metrics, the
+# `exec.<kind>` trace spans — derives from this tuple rather than
+# hand-written attribute pairs.
 STEP_KINDS = ("prefill", "prefill_chunk", "decode", "propose", "verify")
 
 
@@ -259,12 +263,14 @@ class Executor:
     def _observe_step(self, kind: str, fn, args) -> Tuple:
         """Run one jitted StepFn call under observation (DESIGN.md §12).
 
-        Records a wall-time histogram sample and a trace span per call, and
-        a compile instant event + counter whenever the call actually
-        (re)traced — turning the §10 zero-recompile invariant into an
-        asserted metric (``stepfn_compiles_total{kind="decode"}`` must stay
-        at its warm value).  Blocks on the result so the sample is real
-        device time, not dispatch time; the host consumes the result
+        Records a wall-time histogram sample per call inside an
+        ``exec.<kind>`` span, whose child ``exec.<kind>.wait`` covers the
+        ``block_until_ready`` (so dispatch and device wait separate in a
+        profiler trace), and a compile instant event + counter whenever the
+        call actually (re)traced — turning the §10 zero-recompile invariant
+        into an asserted metric (``stepfn_compiles_total{kind="decode"}``
+        must stay at its warm value).  Blocks on the result so the sample is
+        real device time, not dispatch time; the host consumes the result
         synchronously right after in every caller, so no pipelining is
         lost.  Collection is host-side only — nothing here runs inside the
         trace.  Callers skip this entirely when obs is disabled.
@@ -273,13 +279,14 @@ class Executor:
             raise ValueError(
                 f"unknown StepFn kind {kind!r}; known: {list(STEP_KINDS)}")
         before = self.step_traces[kind]
-        t0 = time.perf_counter()
-        out = fn(*args)
-        jax.block_until_ready(out)
-        dt = time.perf_counter() - t0
         obs = self.obs
+        with obs.trace.span(f"exec.{kind}", executor=self.name):
+            t0 = time.perf_counter()
+            out = fn(*args)
+            with obs.trace.span(f"exec.{kind}.wait"):
+                jax.block_until_ready(out)
+            dt = time.perf_counter() - t0
         m = obs.metrics
-        obs.trace.complete(f"stepfn_{kind}", t0, dt, executor=self.name)
         if self.step_traces[kind] > before:
             m.counter(
                 "stepfn_compiles_total",

@@ -42,44 +42,44 @@ class LocalExecutor(Executor):
     def _build_prefill(self):
         cfg, ccfg = self.cfg, self.ccfg
 
-        def fn(sp, batch, pa, rows, head_importance):
+        def prefill_step(sp, batch, pa, rows, head_importance):
             self.prefill_traces += 1  # runs at trace time only
             return _serve.prefill(sp, batch, cfg, pa, ccfg,
                                   head_importance=head_importance, rows=rows)
 
-        return jax.jit(fn)
+        return jax.jit(prefill_step)
 
     def _build_prefill_chunk(self):
         cfg, ccfg = self.cfg, self.ccfg
 
-        def fn(sp, tokens, pa, state, rows, start, valid, quota,
-               head_importance):
+        def prefill_chunk_step(sp, tokens, pa, state, rows, start, valid,
+                               quota, head_importance):
             self.prefill_chunk_traces += 1  # runs at trace time only
             return _serve.prefill_chunk(sp, tokens, cfg, pa, ccfg, state,
                                         rows, start, valid, quota,
                                         head_importance=head_importance)
 
         donate = (3,) if self.exec_cfg.donate_state else ()
-        return jax.jit(fn, donate_argnums=donate)
+        return jax.jit(prefill_chunk_step, donate_argnums=donate)
 
     def _build_decode(self):
         cfg, ccfg, impl = self.cfg, self.ccfg, self.paged_impl
         kinds = self.kv_kinds
 
-        def fn(sp, state, pa, tokens, active, rows):
+        def decode_step(sp, state, pa, tokens, active, rows):
             self.decode_traces += 1  # runs at trace time only
             return _serve.decode_step(sp, state, cfg, pa, ccfg,
                                       tokens=tokens, active=active, rows=rows,
                                       paged_impl=impl, kv_kinds=kinds)
 
         donate = (1,) if self.exec_cfg.donate_state else ()
-        return jax.jit(fn, donate_argnums=donate)
+        return jax.jit(decode_step, donate_argnums=donate)
 
     def _build_propose(self, draft_layers, max_k):
         cfg, ccfg, impl = self.cfg, self.ccfg, self.paged_impl
         kinds = self.kv_kinds
 
-        def fn(sp, state, pa, depths, active, rows):
+        def propose_step(sp, state, pa, depths, active, rows):
             self.propose_traces += 1  # runs at trace time only
             return _serve.propose_step(sp, state, cfg, pa, ccfg, depths,
                                        active=active, rows=rows,
@@ -87,13 +87,13 @@ class LocalExecutor(Executor):
                                        draft_layers=draft_layers, max_k=max_k)
 
         donate = (1,) if self.exec_cfg.donate_state else ()
-        return jax.jit(fn, donate_argnums=donate)
+        return jax.jit(propose_step, donate_argnums=donate)
 
     def _build_verify(self, draft_layers):
         cfg, ccfg, impl = self.cfg, self.ccfg, self.paged_impl
         kinds = self.kv_kinds
 
-        def fn(sp, state, pa, tokens, q_lens, active, rows):
+        def verify_step(sp, state, pa, tokens, q_lens, active, rows):
             self.verify_traces += 1  # runs at trace time only
             return _serve.verify_step(sp, state, cfg, pa, ccfg, tokens,
                                       q_lens, active=active, rows=rows,
@@ -101,7 +101,7 @@ class LocalExecutor(Executor):
                                       draft_layers=draft_layers)
 
         donate = (1,) if self.exec_cfg.donate_state else ()
-        return jax.jit(fn, donate_argnums=donate)
+        return jax.jit(verify_step, donate_argnums=donate)
 
     # ---- entry points ------------------------------------------------------
 

@@ -174,7 +174,7 @@ class MeshExecutor(Executor):
         cfg, ccfg = self.cfg, self.ccfg
         ec = self.exec_cfg
 
-        def inner(sp, batch, pa, rows, head_importance):
+        def prefill_step(sp, batch, pa, rows, head_importance):
             self.prefill_traces += 1  # runs at trace time only
             return _serve.prefill(sp, batch, cfg, pa, ccfg,
                                   head_importance=head_importance, rows=rows,
@@ -182,7 +182,7 @@ class MeshExecutor(Executor):
 
         d = ec.data_axis
         fn = jax.shard_map(
-            inner, mesh=self.mesh,
+            prefill_step, mesh=self.mesh,
             in_specs=(sp_specs, {"tokens": P(d)}, self._pa_specs(), P(d),
                       P() if has_hi else None),
             out_specs=(state_specs, P(d), P(None, None, d)),
@@ -241,8 +241,8 @@ class MeshExecutor(Executor):
         cfg, ccfg = self.cfg, self.ccfg
         ec = self.exec_cfg
 
-        def inner(sp, tokens, pa, state, rows, start, valid, quota,
-                  head_importance):
+        def prefill_chunk_step(sp, tokens, pa, state, rows, start, valid,
+                               quota, head_importance):
             self.prefill_chunk_traces += 1  # runs at trace time only
             return _serve.prefill_chunk(sp, tokens, cfg, pa, ccfg, state,
                                         rows, start, valid, quota,
@@ -251,7 +251,7 @@ class MeshExecutor(Executor):
 
         d = ec.data_axis
         fn = jax.shard_map(
-            inner, mesh=self.mesh,
+            prefill_chunk_step, mesh=self.mesh,
             in_specs=(sp_specs, P(d), self._pa_specs(), state_specs, P(d),
                       P(d), P(d), P(), P() if has_hi else None),
             out_specs=(state_specs, P(d), P(None, None, d)),
@@ -314,7 +314,7 @@ class MeshExecutor(Executor):
         ec = self.exec_cfg
         kinds = self.kv_kinds
 
-        def inner(sp, state, pa, tokens, active, rows):
+        def decode_step(sp, state, pa, tokens, active, rows):
             self.decode_traces += 1  # runs at trace time only
             return _serve.decode_step(sp, state, cfg, pa, ccfg,
                                       tokens=tokens, active=active, rows=rows,
@@ -329,7 +329,7 @@ class MeshExecutor(Executor):
         # (semantics unchanged — ops.pallas_in_decode)
         from repro.kernels.ops import pallas_in_decode
         fn = jax.shard_map(
-            inner, mesh=self.mesh,
+            decode_step, mesh=self.mesh,
             in_specs=(sp_specs, state_specs, self._pa_specs(), P(d), P(d),
                       P(d)),
             out_specs=(state_specs, P(d)),
@@ -368,7 +368,7 @@ class MeshExecutor(Executor):
         ec = self.exec_cfg
         kinds = self.kv_kinds
 
-        def inner(sp, state, pa, depths, active, rows):
+        def propose_step(sp, state, pa, depths, active, rows):
             self.propose_traces += 1  # runs at trace time only
             return _serve.propose_step(sp, state, cfg, pa, ccfg, depths,
                                        active=active, rows=rows,
@@ -380,7 +380,7 @@ class MeshExecutor(Executor):
         d = ec.data_axis
         from repro.kernels.ops import pallas_in_decode
         fn = jax.shard_map(
-            inner, mesh=self.mesh,
+            propose_step, mesh=self.mesh,
             in_specs=(sp_specs, state_specs, self._pa_specs(), P(d), P(d),
                       P(d)),
             out_specs=(state_specs, P(d, None)),
@@ -393,7 +393,7 @@ class MeshExecutor(Executor):
         ec = self.exec_cfg
         kinds = self.kv_kinds
 
-        def inner(sp, state, pa, tokens, q_lens, active, rows):
+        def verify_step(sp, state, pa, tokens, q_lens, active, rows):
             self.verify_traces += 1  # runs at trace time only
             return _serve.verify_step(sp, state, cfg, pa, ccfg, tokens,
                                       q_lens, active=active, rows=rows,
@@ -405,7 +405,7 @@ class MeshExecutor(Executor):
         d = ec.data_axis
         from repro.kernels.ops import pallas_in_decode
         fn = jax.shard_map(
-            inner, mesh=self.mesh,
+            verify_step, mesh=self.mesh,
             in_specs=(sp_specs, state_specs, self._pa_specs(), P(d, None),
                       P(d), P(d), P(d)),
             out_specs=(state_specs, P(d, None), P(d), P(d, None, None)),

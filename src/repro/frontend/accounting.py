@@ -17,7 +17,8 @@ zero new export machinery:
   ``frontend_itl_seconds{tenant=}`` — latency histograms;
 - ``frontend_ttft_p50_steps{tenant=}`` (and p99 / seconds / itl variants)
   — rolling-window percentile gauges over the last ``latency_window``
-  finished requests, the live dashboard numbers;
+  finished requests (ITL: over their last ``latency_window`` inter-token
+  gaps), the live dashboard numbers;
 - ``slo_attained_total{tenant=}`` / ``slo_missed_total{tenant=}`` — SLO
   attainment counters (per finished request);
 - ``goodput_tokens_total{tenant=}`` vs ``tokens_total{tenant=}`` —
@@ -80,11 +81,12 @@ class TenantAccounting:
             "frontend_ttft_steps", "TTFT in scheduler steps, per tenant",
             buckets=STEP_BUCKETS)
         self.ttft_seconds = metrics.histogram(
-            "frontend_ttft_seconds", "TTFT wall clock, per tenant")
+            "frontend_ttft_seconds",
+            "TTFT seconds (submission to first token), per tenant")
         self.itl_seconds = metrics.histogram(
             "frontend_itl_seconds",
-            "mean inter-token latency per finished request, per tenant",
-            buckets=ITL_BUCKETS)
+            "inter-token gap of finished requests, one sample per gap, "
+            "per tenant", buckets=ITL_BUCKETS)
         self.attained = metrics.counter(
             "slo_attained_total",
             "finished requests that met every configured SLO target")
@@ -123,8 +125,10 @@ class TenantAccounting:
                     continue
                 name = (f"frontend_ttft_p{q}_{unit}" if unit != "itl_seconds"
                         else f"frontend_itl_p{q}_seconds")
+                over = ("inter-token gaps of finished requests"
+                        if key == "itl_s" else "finished requests")
                 self.m.gauge(name, f"rolling p{q} over the last "
-                             f"{self.cfg.latency_window} finished requests"
+                             f"{self.cfg.latency_window} {over}"
                              ).set(v, tenant=tenant)
 
     # ---- event hooks -------------------------------------------------------
@@ -163,10 +167,9 @@ class TenantAccounting:
         if tsec is not None:
             w["ttft_s"].push(tsec)
             self.ttft_seconds.observe(tsec, tenant=tenant)
-        itl = req.itl_seconds()
-        if itl is not None:
-            w["itl_s"].push(itl)
-            self.itl_seconds.observe(itl, tenant=tenant)
+        for gap in req.itl_gaps:
+            w["itl_s"].push(gap)
+            self.itl_seconds.observe(gap, tenant=tenant)
         self._publish_percentiles(tenant)
         if self.attained_for(req):
             self.attained.inc(1, tenant=tenant)
@@ -186,7 +189,7 @@ class TenantAccounting:
             if tsec is None or tsec > cls.ttft_slo_s:
                 return False
         if cls.itl_slo_s is not None:
-            itl = req.itl_seconds()
+            itl = req.mean_itl_seconds()
             if itl is not None and itl > cls.itl_slo_s:
                 return False
         return True

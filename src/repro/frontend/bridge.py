@@ -119,7 +119,8 @@ class EngineLoop:
         req = Request(req_id=rid, prompt=np.asarray(prompt, np.int32),
                       max_new_tokens=max_new_tokens, eos_id=eos_id,
                       tenant=tenant, priority=priority, deadline_s=deadline_s,
-                      arrival_time=time.time())
+                      arrival_time=time.time(),
+                      t_submit=time.perf_counter())
         self._inbox.put(_Submit(req, deliver))
         return req
 

@@ -50,7 +50,9 @@ class PriorityClass:
     priority: int
     ttft_slo_steps: int = 32
     ttft_slo_s: Optional[float] = None  # optional wall-clock attainment SLO
-    itl_slo_s: Optional[float] = None  # optional per-token cadence SLO
+    # optional per-token cadence SLO, judged on the request's mean
+    # inter-token gap
+    itl_slo_s: Optional[float] = None
     shed_after_steps: int = 0
     degrade_floor: int = 0
     preempt_below: bool = False

@@ -93,6 +93,8 @@ class FrontendScheduler:
         terminal immediately with a recorded reason."""
         if req.arrival_time is None:
             req.arrival_time = time.time()
+        if req.t_submit is None:  # TTFT counts the frontend's queue too
+            req.t_submit = time.perf_counter()
         req.arrival_step = self.sched.step_idx
         # clamp client-supplied priority to the configured ladder: an
         # out-of-range value (e.g. a negative one) would otherwise outrank

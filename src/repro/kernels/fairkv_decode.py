@@ -171,6 +171,7 @@ def fairkv_decode_pallas(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, S, G, Dh), q.dtype),
         interpret=interpret,
+        name="fairkv_decode",
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
     )(lengths, q_pos, q, k, v, k_pos.reshape(S, B, 1, k_pos.shape[2]))

@@ -272,6 +272,7 @@ def paged_fairkv_decode_pallas(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, S, Q * G, Dh), q.dtype),
         interpret=interpret,
+        name="paged_fairkv_decode",
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
     )(*prefetch, *inputs)

@@ -149,6 +149,7 @@ def snapkv_scores_pallas(
         out_shape=jax.ShapeDtypeStruct((B, Hkv, 1, n_blocks * block_t),
                                        jnp.float32),
         interpret=interpret,
+        name="snapkv_select",
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
     )(obs_positions, qt.reshape(B * Hkv, W * G, Dh),

@@ -10,14 +10,22 @@ format wants; the export loads directly in Perfetto / chrome://tracing.
 
 The ``span`` context manager is the instrumentation primitive::
 
-    with obs.trace.span("decode_step", rows=3):
+    with obs.trace.span("sched.decode", rows=3):
         ...
 
-and costs two ``perf_counter()`` calls plus one dict append when enabled.
-``complete()`` records a span whose timing was measured externally (the
-executors time around ``block_until_ready`` and report after the fact).
+and costs two ``perf_counter()`` calls, one dict append and one
+``TraceAnnotation`` when enabled.  ``complete()`` records a span whose
+timing was measured externally.
 Everything here is host-side: spans wrap StepFn *invocations*, never code
 inside a trace.
+
+``span`` also enters a ``jax.profiler.TraceAnnotation`` of the same name and
+args around its block, so while a ``jax.profiler`` capture is running every
+span lands on the capture's host plane, on the clock of the device's
+operations — the ring keeps recording for ``serve --trace-out`` either way.
+With no capture running the annotation is a no-op.  Span names are exact
+(``sched.admit``, ``exec.prefill.wait``); per-request identity goes in the
+args (``req=``), so every span of one request shares it.
 """
 from __future__ import annotations
 
@@ -25,6 +33,8 @@ import json
 import time
 from collections import deque
 from contextlib import contextmanager, nullcontext
+
+from jax.profiler import TraceAnnotation
 
 
 class TraceBuffer:
@@ -49,11 +59,13 @@ class TraceBuffer:
 
     @contextmanager
     def span(self, name: str, **args):
-        """Time a block as one complete ("X") event; exceptions still
-        record the span (with an ``error`` arg) before propagating."""
+        """Time a block as one complete ("X") event, annotated in any
+        running profiler capture; exceptions still record the span (with an
+        ``error`` arg) before propagating."""
         t0 = time.perf_counter()
         try:
-            yield
+            with TraceAnnotation(name, **args):
+                yield
         except BaseException as e:
             self.complete(name, t0, time.perf_counter() - t0,
                           error=type(e).__name__, **args)

@@ -10,7 +10,14 @@ is modeled explicitly so telemetry can attribute time-to-first-token to the
 prefill, and so a future async-prefill engine can hold requests there.
 
 Timestamps are recorded in *scheduler steps* (one decode tick each) and in
-wall-clock seconds; the benchmark reports both.
+seconds; the benchmark reports both.  ``arrival_time`` / ``finish_time``
+(and ``deadline_s``) are ``time.time()`` values for the frontend's deadlines
+and end-to-end latency.  Time to first token and inter-token latency come
+from ``time.perf_counter()`` stamps alone — ``t_submit`` / ``t_admit`` /
+``t_first`` / ``t_last`` and the per-gap ``itl_gaps`` — the clock a load
+generator stamps due times and tokens on, so a request's time to first
+token splits into queue wait (``t_admit - t_submit``), admission
+(``t_first - t_admit``) and whatever follows on the caller's side.
 """
 from __future__ import annotations
 
@@ -57,8 +64,17 @@ class Request:
     first_token_step: Optional[int] = None
     finish_step: Optional[int] = None
     arrival_time: Optional[float] = None
-    first_token_time: Optional[float] = None  # wall clock of the first token
     finish_time: Optional[float] = None
+    # perf_counter stamps (DESIGN.md §12): submitted (to the frontend or the
+    # scheduler, whichever saw it first), its admission prefill started, its
+    # first token on the host, its latest token on the host (the start of
+    # the next inter-token gap); ``itl_gaps`` holds one entry per pair of
+    # consecutive tokens (0 for the later tokens of a multi-token commit)
+    t_submit: Optional[float] = None
+    t_admit: Optional[float] = None
+    t_first: Optional[float] = None
+    t_last: Optional[float] = None
+    itl_gaps: List[float] = field(default_factory=list)
     n_preemptions: int = 0  # times evicted back to QUEUED (paged backend)
     degraded_from: Optional[int] = None  # original max_new_tokens pre-degrade
     # prefix cache (DESIGN.md §14): stamped on a hit — (L,) full blocks per
@@ -111,7 +127,8 @@ class Request:
             self.logits = []
         self.admit_step = None
         self.first_token_step = None
-        self.first_token_time = None
+        self.t_admit = self.t_first = self.t_last = None
+        self.itl_gaps = []
         self.prefix_shared_blocks = None  # re-stamped on re-admission
         self.prefix_hit_tokens = 0
         self.spec_proposed = 0  # the replay re-speculates from scratch
@@ -141,19 +158,17 @@ class Request:
         return self.first_token_step - self.arrival_step
 
     def ttft_seconds(self) -> Optional[float]:
-        """Arrival → first token, wall clock (queueing + prefill)."""
-        if self.first_token_time is None or self.arrival_time is None:
+        """Submission → first token on the host (queueing + admission)."""
+        if self.t_first is None or self.t_submit is None:
             return None
-        return self.first_token_time - self.arrival_time
+        return self.t_first - self.t_submit
 
-    def itl_seconds(self) -> Optional[float]:
-        """Mean inter-token latency after the first token (the streaming
-        cadence a client sees); None until a second token exists."""
-        if (self.finish_time is None or self.first_token_time is None
-                or self.n_generated < 2):
+    def mean_itl_seconds(self) -> Optional[float]:
+        """Mean of the request's inter-token gaps (the streaming cadence a
+        client sees); None until a second token exists."""
+        if not self.itl_gaps:
             return None
-        return (self.finish_time - self.first_token_time) / (
-            self.n_generated - 1)
+        return sum(self.itl_gaps) / len(self.itl_gaps)
 
 
 def poisson_arrivals(n_requests: int, rate: float,
@@ -257,9 +272,10 @@ def synthesize_requests(
 
 
 def latency_percentiles(requests: List[Request]) -> dict:
-    """p50/p99 of end-to-end latency, TTFT, and mean ITL over the finished
-    subset, in steps and seconds (seconds only when wall-clock stamps were
-    recorded).
+    """p50/p99 of end-to-end latency and TTFT over the finished subset, in
+    steps and seconds (seconds only when the stamps were recorded), and of
+    ITL over every inter-token gap of those requests (``itl_s``, the same
+    samples as the registry's ``itl_s`` histogram).
 
     Keys for an observable are present only when at least one request
     recorded it — an empty trace returns just ``{"n_finished": 0}``, never
@@ -270,7 +286,7 @@ def latency_percentiles(requests: List[Request]) -> dict:
         "s": [r.latency_seconds() for r in requests],
         "ttft_steps": [r.ttft_steps() for r in requests],
         "ttft_s": [r.ttft_seconds() for r in requests],
-        "itl_s": [r.itl_seconds() for r in requests],
+        "itl_s": [g for r in requests for g in r.itl_gaps],
     }
     out = {"n_finished": sum(1 for v in samples["steps"] if v is not None)}
     for key, vals in samples.items():

@@ -25,6 +25,7 @@ from __future__ import annotations
 import dataclasses
 import time
 from collections import deque
+from contextlib import contextmanager
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Sequence
 
@@ -319,6 +320,51 @@ class Scheduler:
             c.inc(0, outcome="rejected")
             self._sample_plan_metrics()
 
+    # ---- phases and token stamps (DESIGN.md §12) ---------------------------
+
+    @contextmanager
+    def _phase(self, phase: str, **args):
+        """One phase of the tick: a ``sched.<phase>`` span (ring and any
+        running profiler capture) and a ``sched_phase_s{phase=}`` sample."""
+        t0 = time.perf_counter()
+        with self.obs.trace.span("sched." + phase, **args):
+            yield
+        self.obs.metrics.histogram(
+            "sched_phase_s",
+            help="host seconds per scheduler phase (phase=step is the whole "
+                 "tick; the others nest inside it)",
+        ).observe(time.perf_counter() - t0, phase=phase)
+
+    def _stamp_tokens(self, req: Request, n: int, t: float) -> None:
+        """``n`` tokens of ``req`` reached the host at ``t``: one ``itl_s``
+        sample per gap (a multi-token commit's later gaps are 0, as its
+        client sees them)."""
+        h = self.obs.metrics.histogram(
+            "itl_s", help="inter-token gap, seconds: one sample per pair of "
+                          "consecutive tokens of a request (per decode step "
+                          "in one-shot mode)")
+        for i in range(n):
+            gap = t - req.t_last if i == 0 else 0.0
+            req.itl_gaps.append(gap)
+            h.observe(gap)
+        req.t_last = t
+
+    def _first_token(self, req: Request, first: int) -> None:
+        """The first token is on the host: stamp it, count the admission."""
+        req.generated.append(first)
+        req.first_token_step = self.step_idx
+        req.t_first = req.t_last = time.perf_counter()
+        self.obs.metrics.counter(
+            "sched_admissions_total",
+            help="requests admitted (prefilled + spliced)").inc()
+        ttft = req.ttft_seconds()
+        if ttft is not None:
+            self.obs.metrics.histogram(
+                "ttft_s", help="time to first token, seconds: submit to the "
+                               "first token on the host (queue wait + "
+                               "admission; the prefill alone in one-shot "
+                               "mode)").observe(ttft)
+
     # ---- engine plumbing ---------------------------------------------------
 
     def _decode(self, state, active):
@@ -354,19 +400,20 @@ class Scheduler:
         them up front, preempting if the pool is dry); after verify,
         `trim_rows` returns every block past the committed lengths to the
         pool — the rollback side of the trial-commit.  TTFT is untouched
-        (stamped at admission); ITL stays honest because `itl_seconds` is
-        the per-request *mean* cadence, which a multi-token commit
-        accelerates exactly as a client would observe."""
+        (stamped at admission); ITL stays honest because a multi-token
+        commit reaches the host, and the client, as one burst: one real
+        gap, then zero gaps (`_stamp_tokens`)."""
         spec = self.spec
         d = spec.draft_layers if spec.draft_layers > 0 else self.cfg.n_layers
-        depth = self._spec_depths()
-        self._prepare_decode(n_tokens=int(depth.max()) + 1)
+        with self._phase("prepare_decode"):
+            depth = self._spec_depths()
+            self._prepare_decode(n_tokens=int(depth.max()) + 1)
         if not self.active:  # everything got preempted reserving blocks
             return
         q_lens = jnp.asarray(depth + 1, jnp.int32)
         mask = self.active_mask()
-        with self.obs.trace.span("decode_tick", rows=len(self.active),
-                                 spec_max_depth=int(depth.max())):
+        with self._phase("decode", rows=len(self.active),
+                         spec_max_depth=int(depth.max())):
             st, props = self.executor.propose(
                 self.sp, self.state, self.pa, jnp.asarray(depth),
                 active=mask, draft_layers=d, max_k=spec.max_k)
@@ -375,9 +422,23 @@ class Scheduler:
             st, g, n_commit, logits = self.executor.verify(
                 self.sp, st, self.pa, tokens, q_lens,
                 active=mask, draft_layers=d)
+        with self._phase("readout"):
+            self._commit_speculative(st, g, n_commit, logits, depth)
+        for row in sorted(self.active):
+            req = self.active[row]
+            if self._done(req):
+                self._retire(req)
+                events["finished"].append(req.req_id)
+
+    def _commit_speculative(self, st, g, n_commit, logits,
+                            depth: np.ndarray) -> None:
+        """Trim the rejected provisional entries, read the verified tokens
+        back to the host and commit each row's accepted run."""
+        spec = self.spec
         self.state = self.backend.trim_rows(st, sorted(self.active))
         g_np, nc = np.asarray(g), np.asarray(n_commit)
         logits_np = np.asarray(logits) if self.scfg.collect_logits else None
+        now = time.perf_counter()
         tick_proposed = tick_accepted = 0
         for row in sorted(self.active):
             req = self.active[row]
@@ -388,13 +449,15 @@ class Scheduler:
             tick_accepted += max(0, n - 1)
             # commit the accepted run, truncating at EOS / max_new_tokens
             # (the cache may hold a few tokens past the cut; the row is
-            # retired right below, which frees them with the row)
+            # retired right after, which frees them with the row)
+            n0 = req.n_generated
             for i in range(n):
                 req.generated.append(int(g_np[row, i]))
                 if logits_np is not None:
                     req.logits.append(logits_np[row, i])
                 if self._done(req):
                     break
+            self._stamp_tokens(req, req.n_generated - n0, now)
             if spec.adaptive and prop > 0:
                 alpha = (n - 1) / prop
                 want = self._spec_depth[row]
@@ -414,11 +477,6 @@ class Scheduler:
             m.gauge("spec_depth",
                     help="mean adaptive speculation depth over live rows"
                     ).set(float(np.mean(depths)))
-        for row in sorted(self.active):
-            req = self.active[row]
-            if self._done(req):
-                self._retire(req)
-                events["finished"].append(req.req_id)
 
     # ---- load accounting ---------------------------------------------------
 
@@ -546,6 +604,8 @@ class Scheduler:
         req.state = RequestState.QUEUED
         if req.arrival_time is None:
             req.arrival_time = time.time()
+        if req.t_submit is None:
+            req.t_submit = time.perf_counter()
         self.queue.append(req)
 
     def _estimated_cost(self, req: Request) -> int:
@@ -567,46 +627,42 @@ class Scheduler:
         backend ran out of memory even after preempting (caller requeues)."""
         row = self.freelist.acquire()
         assert row is not None
-        req.state = RequestState.PREFILLING
-        req.row = row
-        req.admit_step = self.step_idx
-        batch = {"tokens": jnp.asarray(req.prompt, jnp.int32)[None, :]}
-        sub, logits, _lens = self.executor.prefill(
-            self.sp, batch, self.pa, rows=jnp.asarray([row]),
-            head_importance=self.head_importance)
-        try:
-            self.state = self.backend.splice(self.state, sub,
-                                             jnp.asarray([row]))
-        except PoolExhausted:
-            # admission never preempts (only decode growth does — evicting
-            # older in-flight work to admit newer would invert FCFS): undo
-            # and let the caller requeue.  Unreachable for the built-in
-            # backends, whose admissible() charge dominates the splice need;
-            # this guards plugin backends with looser admission estimates.
-            self.freelist.release(row)
-            req.state = RequestState.QUEUED
-            req.row = None
-            req.admit_step = None
-            return None
-        first = int(np.asarray(sub.last_tokens)[0])
-        req.generated.append(first)
-        req.first_token_step = self.step_idx
-        req.first_token_time = time.time()
-        self.obs.metrics.counter(
-            "sched_admissions_total",
-            help="requests admitted (prefilled + spliced)").inc()
-        ttft = req.ttft_seconds()
-        if ttft is not None:
-            self.obs.metrics.histogram(
-                "ttft_s", help="time to first token (queue wait + prefill "
-                               "wall time)").observe(ttft)
-        if self.scfg.collect_logits:
-            req.logits = [np.asarray(logits[0])]
-        req.state = RequestState.DECODING
-        self.active[row] = req
-        if self._done(req):
-            self._retire(req)
-        return row
+        with self._phase("admit", req=req.req_id, prompt_len=req.prompt_len,
+                         row=row):
+            req.state = RequestState.PREFILLING
+            req.row = row
+            req.admit_step = self.step_idx
+            req.t_admit = time.perf_counter()
+            batch = {"tokens": jnp.asarray(req.prompt, jnp.int32)[None, :]}
+            sub, logits, _lens = self.executor.prefill(
+                self.sp, batch, self.pa, rows=jnp.asarray([row]),
+                head_importance=self.head_importance)
+            try:
+                with self._phase("splice", req=req.req_id):
+                    self.state = self.backend.splice(self.state, sub,
+                                                     jnp.asarray([row]))
+            except PoolExhausted:
+                # admission never preempts (only decode growth does —
+                # evicting older in-flight work to admit newer would invert
+                # FCFS): undo and let the caller requeue.  Unreachable for
+                # the built-in backends, whose admissible() charge dominates
+                # the splice need; this guards plugin backends with looser
+                # admission estimates.
+                self.freelist.release(row)
+                req.state = RequestState.QUEUED
+                req.row = None
+                req.admit_step = req.t_admit = None
+                return None
+            with self._phase("first_token", req=req.req_id):
+                first = int(np.asarray(sub.last_tokens)[0])
+            self._first_token(req, first)
+            if self.scfg.collect_logits:
+                req.logits = [np.asarray(logits[0])]
+            req.state = RequestState.DECODING
+            self.active[row] = req
+            if self._done(req):
+                self._retire(req)
+            return row
 
     def _done(self, req: Request) -> bool:
         if req.n_generated >= req.max_new_tokens:
@@ -690,6 +746,7 @@ class Scheduler:
         req.state = RequestState.PREFILLING
         req.row = row
         req.admit_step = self.step_idx
+        req.t_admit = time.perf_counter()
         prompt = np.asarray(req.prompt, np.int32)
         if entry is not None:
             sub = self._seed_from_entry(entry, row)
@@ -727,8 +784,8 @@ class Scheduler:
             n = min(Ck, T - job.next_pos)
             chunk = np.zeros((1, Ck), np.int32)
             chunk[0, :n] = job.prompt[job.next_pos:job.next_pos + n]
-            with self.obs.trace.span("prefill_chunk", req=job.req.req_id,
-                                     start=job.next_pos, tokens=n):
+            with self._phase("prefill_chunk", req=job.req.req_id,
+                             start=job.next_pos, tokens=n):
                 job.state, logits, lens = self.executor.prefill_chunk(
                     self.sp, chunk, self.pa, job.state,
                     rows=np.asarray([row], np.int32),
@@ -754,13 +811,14 @@ class Scheduler:
             shared, _ = self._head_slot_table(job.entry, row)
         while True:
             try:
-                if shared is not None:
-                    self.state = self.backend.splice(
-                        self.state, job.state, jnp.asarray([row]),
-                        shared_blocks=shared)
-                else:
-                    self.state = self.backend.splice(self.state, job.state,
-                                                     jnp.asarray([row]))
+                with self._phase("splice", req=req.req_id):
+                    if shared is not None:
+                        self.state = self.backend.splice(
+                            self.state, job.state, jnp.asarray([row]),
+                            shared_blocks=shared)
+                    else:
+                        self.state = self.backend.splice(
+                            self.state, job.state, jnp.asarray([row]))
                 break
             except PoolExhausted:
                 # cheapest memory first: entries held only by the index
@@ -771,18 +829,9 @@ class Scheduler:
         del self.prefilling[row]
         if job.entry is not None:
             self.prefix.unpin(job.entry)
-        first = int(np.asarray(job.state.last_tokens)[0])
-        req.generated.append(first)
-        req.first_token_step = self.step_idx
-        req.first_token_time = time.time()
-        self.obs.metrics.counter(
-            "sched_admissions_total",
-            help="requests admitted (prefilled + spliced)").inc()
-        ttft = req.ttft_seconds()
-        if ttft is not None:
-            self.obs.metrics.histogram(
-                "ttft_s", help="time to first token (queue wait + prefill "
-                               "wall time)").observe(ttft)
+        with self._phase("first_token", req=req.req_id):
+            first = int(np.asarray(job.state.last_tokens)[0])
+        self._first_token(req, first)
         if self.scfg.collect_logits:
             req.logits = [job.last_logits[0]]
         req.state = RequestState.DECODING
@@ -805,7 +854,7 @@ class Scheduler:
         req.row = None
         if requeue:
             req.state = RequestState.QUEUED
-            req.admit_step = None
+            req.admit_step = req.t_admit = None
             req.generated = []
             req.prefix_shared_blocks = None
             req.prefix_hit_tokens = 0
@@ -857,31 +906,25 @@ class Scheduler:
         self._spec_depth.pop(row, None)
 
     def _retire(self, req: Request) -> None:
-        self._release_row(req)
-        req.state = RequestState.FINISHED
-        req.finish_step = self.step_idx
-        req.finish_time = time.time()
-        req.row = None
-        self.finished.append(req)
-        m = self.obs.metrics
-        m.counter("sched_retirements_total",
-                  help="requests retired (EOS or max-new-tokens)").inc()
-        self.obs.trace.instant("retire", req=req.req_id,
-                               n_generated=req.n_generated)
-        itl = req.itl_seconds()
-        if itl is not None:
-            m.histogram("itl_s",
-                        help="inter-token latency (per-request mean in "
-                             "continuous mode; per-step in one-shot mode)"
-                        ).observe(itl)
-        if req.arrival_time is not None:
-            m.histogram("e2e_s", help="end-to-end request latency"
-                        ).observe(req.finish_time - req.arrival_time)
-        if req.spec_proposed > 0:
-            m.histogram("spec_acceptance",
-                        help="per-request draft acceptance rate "
-                             "(accepted / proposed over the lifetime)"
-                        ).observe(req.spec_accepted / req.spec_proposed)
+        with self._phase("retire", req=req.req_id,
+                         n_generated=req.n_generated):
+            self._release_row(req)
+            req.state = RequestState.FINISHED
+            req.finish_step = self.step_idx
+            req.finish_time = time.time()
+            req.row = None
+            self.finished.append(req)
+            m = self.obs.metrics
+            m.counter("sched_retirements_total",
+                      help="requests retired (EOS or max-new-tokens)").inc()
+            if req.arrival_time is not None:
+                m.histogram("e2e_s", help="end-to-end request latency"
+                            ).observe(req.finish_time - req.arrival_time)
+            if req.spec_proposed > 0:
+                m.histogram("spec_acceptance",
+                            help="per-request draft acceptance rate "
+                                 "(accepted / proposed over the lifetime)"
+                            ).observe(req.spec_accepted / req.spec_proposed)
 
     # ---- cancellation + draining (DESIGN.md §13) ---------------------------
 
@@ -1038,7 +1081,7 @@ class Scheduler:
         and rejected (no state change, cooldown still consumed) unless it
         strictly reduces the per-shard imbalance.
         """
-        with self.obs.trace.span("replan"):
+        with self._phase("replan"):
             event = self._replan_impl(profile, shard_speeds)
         # outcome counter is the single source of truth for replan counts
         # (benchmarks read it instead of re-tallying replan_log)
@@ -1122,7 +1165,18 @@ class Scheduler:
         return jnp.asarray(m)
 
     def step(self) -> dict:
-        """One scheduler tick: admit → decode → retire → (maybe) replan."""
+        """One scheduler tick: admit → decode → retire → (maybe) replan.
+
+        Every phase runs under a ``sched.<phase>`` span and feeds
+        ``sched_phase_s{phase=}`` (DESIGN.md §12): ``step`` the whole tick;
+        inside it ``admit`` (with the executor's prefill, ``splice`` and
+        ``first_token``), ``admit_chunked``, ``prefill_chunk``,
+        ``prepare_decode``, ``decode``, ``readout``, ``retire``, ``load``
+        and ``replan``."""
+        with self._phase("step", step=self.step_idx):
+            return self._step()
+
+    def _step(self) -> dict:
         events: dict = {"step": self.step_idx, "admitted": [], "finished": [],
                         "preempted": 0, "replanned": False}
         preempted_before = self.n_preemptions
@@ -1144,12 +1198,11 @@ class Scheduler:
                 break
             del self.queue[i]
             if self._should_chunk(req):
-                with self.obs.trace.span("admit_chunked", req=req.req_id):
+                with self._phase("admit_chunked", req=req.req_id):
                     row = self._start_chunked(req, entry)
                 events["admitted"].append((req.req_id, row))
                 continue
-            with self.obs.trace.span("admit", req=req.req_id):
-                row = self._admit(req)
+            row = self._admit(req)
             if row is None:  # backend memory dry even after preemption
                 self.queue.appendleft(req)
                 break
@@ -1166,20 +1219,23 @@ class Scheduler:
         if self.active and self.spec is not None:
             self._decode_tick_speculative(events)
         elif self.active:
-            self._prepare_decode()  # may preempt (paged pool dry)
+            with self._phase("prepare_decode"):
+                self._prepare_decode()  # may preempt (paged pool dry)
             if self.active:
-                with self.obs.trace.span("decode_tick",
-                                         rows=len(self.active)):
+                with self._phase("decode", rows=len(self.active)):
                     self.state, logits = self._decode(self.state,
                                                       self.active_mask())
-                toks = np.asarray(self.state.last_tokens)
-                logits_np = (np.asarray(logits) if self.scfg.collect_logits
-                             else None)
-                for row in sorted(self.active):
-                    req = self.active[row]
-                    req.generated.append(int(toks[row]))
-                    if logits_np is not None:
-                        req.logits.append(logits_np[row])
+                with self._phase("readout"):
+                    toks = np.asarray(self.state.last_tokens)
+                    logits_np = (np.asarray(logits)
+                                 if self.scfg.collect_logits else None)
+                    now = time.perf_counter()
+                    for row in sorted(self.active):
+                        req = self.active[row]
+                        req.generated.append(int(toks[row]))
+                        self._stamp_tokens(req, 1, now)
+                        if logits_np is not None:
+                            req.logits.append(logits_np[row])
                 for row in sorted(self.active):
                     req = self.active[row]
                     if self._done(req):
@@ -1188,13 +1244,14 @@ class Scheduler:
         events["preempted"] = self.n_preemptions - preempted_before
         # load accounting + replan trigger (hysteresis inside the trigger);
         # the load vector feeds the trigger and the gauges from one compute
-        load = self.per_shard_load()
-        if load.sum() > self.peak_shard_load.sum():
-            self.peak_shard_load = load
-        imb = self._imbalance_from(load)
-        self.trigger.observe(imb)
-        if self.obs.enabled:
-            self._sample_step_metrics(load, imb)
+        with self._phase("load"):
+            load = self.per_shard_load()
+            if load.sum() > self.peak_shard_load.sum():
+                self.peak_shard_load = load
+            imb = self._imbalance_from(load)
+            self.trigger.observe(imb)
+            if self.obs.enabled:
+                self._sample_step_metrics(load, imb)
         if self.should_replan():
             self.trigger.fire(self.step_idx)
             events["replan"] = self.replan()

@@ -156,6 +156,31 @@ def test_local_step_hlo_audit():
                                         eng.state.last_tokens)
 
 
+@pytest.mark.parametrize("backend,kernel", [("slot", "fairkv_decode"),
+                                            ("paged", "paged_fairkv_decode")])
+def test_step_hlo_names_the_step_and_its_kernels(monkeypatch, backend,
+                                                 kernel):
+    """The compiled modules are named for their StepFn kind and carry the
+    Pallas calls' names (here in interpret mode), so a device trace's
+    ``XLA Modules`` line and its kernel events say which step and kernel
+    ran."""
+    from repro.api import PagingConfig
+    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "1")
+    cfg = _ecfg(max_seq_len=40, cache_backend=backend,
+                paging=PagingConfig(block_size=8))
+    eng = Engine.build(cfg)
+    prompts = {"tokens": np.random.default_rng(0).integers(
+        0, cfg.model.vocab_size, (2, 16)).astype(np.int32)}
+    eng.generate(prompts["tokens"], 1)
+    ex = eng.executor
+    prefill = ex.prefill_hlo(eng.sp, prompts, eng.pa)
+    assert prefill.startswith("HloModule jit_prefill_step")
+    assert "/snapkv_select/" in prefill  # Ada-SnapKV's scoring kernel
+    decode = ex.decode_hlo(eng.sp, eng.state, eng.pa, eng.state.last_tokens)
+    assert decode.startswith("HloModule jit_decode_step")
+    assert f"/{kernel}/" in decode
+
+
 # ---------------------------------------------------------------------------
 # per-model-shard admission (slot backend)
 # ---------------------------------------------------------------------------

@@ -370,6 +370,31 @@ def test_frontend_trace_slo_mode(shared_params):
     assert 'tenant="fast"' in prom
 
 
+def test_frontend_latency_comes_from_the_perf_counter_stamps(shared_params):
+    """TTFT runs from the frontend's submission, and ITL is one sample per
+    inter-token gap, as in the scheduler's own ``itl_s``."""
+    cfg, params = shared_params
+    eng = Engine.build(cfg, params=params)
+    fe = _frontend(eng)
+    reqs = _tenant_trace(cfg.model.vocab_size)
+    out = run_frontend_trace(fe, reqs, max_steps=400)
+    assert out["finished"] == out["total"]
+    served = [r for r in reqs if r.t_first is not None]
+    assert served
+    for r in served:
+        assert r.t_submit <= r.t_admit <= r.t_first
+        assert r.ttft_seconds() == r.t_first - r.t_submit
+    # the frontend takes no latency sample from a request it cancelled
+    itl = eng.obs.metrics.get("frontend_itl_seconds")
+    assert sum(itl.count(tenant=t) for t in ("fast", "slow")) == sum(
+        len(r.itl_gaps) for r in served if not r.cancelled)
+    # the registry also keeps the gaps of a run a preemption threw away
+    n_itl = eng.obs.metrics.get("itl_s").count()
+    assert n_itl >= sum(len(r.itl_gaps) for r in served)
+    if not any(r.n_preemptions for r in reqs):
+        assert n_itl == sum(len(r.itl_gaps) for r in served)
+
+
 def test_frontend_drain_sheds_queued_finishes_live(shared_params):
     cfg, params = shared_params
     eng = Engine.build(cfg, params=params)

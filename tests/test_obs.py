@@ -299,8 +299,8 @@ def test_engine_continuous_populates_metrics_and_trace():
     assert "sched_admissions_total" in eng.metrics_prometheus()
     doc = json.loads(eng.trace_export())
     names = {e["name"] for e in doc["traceEvents"]}
-    assert {"admit", "decode_tick", "retire",
-            "stepfn_prefill", "stepfn_decode"} <= names
+    assert {"sched.step", "sched.admit", "sched.decode", "sched.retire",
+            "exec.prefill", "exec.decode", "exec.decode.wait"} <= names
     # run() summary carries the satellite telemetry
     assert out["latency"]["n_finished"] == out["finished"]
     assert "p50_ttft_s" in out["latency"] and "p50_itl_s" in out["latency"]
@@ -340,6 +340,113 @@ def test_zero_recompile_invariant_as_metric():
             == out["replans"])
     # the metric agrees with the executor's own trace counter
     assert eng.executor.decode_traces == 1
+
+
+# every span the scheduler and the executor put in a profiler capture
+SCHED_SPANS = {"sched.step", "sched.admit", "sched.splice",
+               "sched.first_token", "sched.admit_chunked",
+               "sched.prefill_chunk", "sched.prepare_decode", "sched.decode",
+               "sched.readout", "sched.retire", "sched.load", "sched.replan"}
+EXEC_SPANS = {"exec.prefill", "exec.prefill.wait", "exec.prefill_chunk",
+              "exec.prefill_chunk.wait", "exec.decode", "exec.decode.wait"}
+
+
+def _program_spans(log_dir):
+    """(name, start_ns, end_ns, args) of every ``sched.*``/``exec.*`` event
+    on the host planes of the capture under ``log_dir``."""
+    from pathlib import Path
+
+    from jax.profiler import ProfileData
+    (path,) = Path(log_dir).rglob("*.xplane.pb")
+    out = []
+    for plane in ProfileData.from_file(str(path)).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith(("sched.", "exec.")):
+                    out.append((e.name, e.start_ns,
+                                e.start_ns + e.duration_ns, dict(e.stats)))
+    return out
+
+
+def test_spans_reach_the_profiler_capture(tmp_path):
+    """Under a ``jax.profiler`` capture, every scheduler phase and StepFn
+    call lands on the host plane, nested as the tick runs them and keyed
+    by request id; the perf_counter stamps order submit <= admit <= first
+    token, and ``itl_s`` takes one sample per inter-token gap."""
+    import jax
+
+    from repro.api import PrefixConfig
+
+    # prompts of 10-28 tokens against 16-token chunks: both admission
+    # paths (monolithic and chunked) run
+    eng = Engine.build(_ecfg(cache_backend="paged",
+                             paging=PagingConfig(block_size=8),
+                             prefix=PrefixConfig(chunk_tokens=16)))
+    reqs = synthesize_requests(6, 0.6, eng.cfg.model.vocab_size,
+                               min_prompt=10, max_prompt=28,
+                               max_new_tokens=4, seed=2)
+    with jax.profiler.trace(str(tmp_path)):
+        out = eng.run_trace(reqs, max_steps=300)
+        eng.scheduler.replan(profile=np.asarray(eng.profile))
+        for _ in range(3):  # idle ticks: nothing queued or live
+            eng.step()
+    assert out["finished"] == out["total"]
+    spans = _program_spans(tmp_path)
+    names = {n for n, *_ in spans}
+    assert SCHED_SPANS | EXEC_SPANS <= names, sorted(
+        (SCHED_SPANS | EXEC_SPANS) - names)
+
+    def inside(inner, outer_name):
+        return [o for o in spans if o[0] == outer_name
+                and o[1] <= inner[1] and inner[2] <= o[2]]
+
+    ids = {r.req_id for r in reqs}
+    admits = [s for s in spans if s[0] == "sched.admit"]
+    assert admits and {s[3]["req"] for s in admits} <= ids
+    assert all({"prompt_len", "row"} <= set(s[3]) for s in admits)
+    prefills = [s for s in spans if s[0] == "exec.prefill"]
+    assert len(prefills) == len(admits)
+    for p in prefills:
+        (admit,) = inside(p, "sched.admit")
+        assert len(inside(admit, "sched.step")) == 1
+        assert len(inside(p, "exec.prefill.wait")) == 0  # child, not parent
+    for w in (s for s in spans if s[0] == "exec.prefill.wait"):
+        assert len(inside(w, "exec.prefill")) == 1
+    for r in reqs:
+        assert r.t_submit <= r.t_admit <= r.t_first <= r.t_last
+        assert r.ttft_seconds() == r.t_first - r.t_submit
+        assert len(r.itl_gaps) == r.n_generated - 1
+    m = eng.obs.metrics
+    gaps = sum(r.n_generated - 1 for r in reqs)
+    assert m.get("itl_s").count() == gaps
+    assert m.get("ttft_s").count() == len(reqs)
+    # the run summary's itl_s is the same per-gap quantity
+    every_gap = [g for r in reqs for g in r.itl_gaps]
+    assert out["latency"]["p50_itl_s"] == pytest.approx(
+        float(np.percentile(every_gap, 50)))
+    ph = m.get("sched_phase_s")
+    assert ph.count(phase="step") == out["steps"] + 3
+    assert ph.count(phase="admit") == len(admits)
+    assert ph.count(phase="retire") == len(reqs)
+    # a tick is busy when it called a StepFn: its sched.step span holds an
+    # exec.* span (the three idle ticks hold none)
+    ticks = [s for s in spans if s[0] == "sched.step"]
+    busy = [t for t in ticks if any(
+        s[0].startswith("exec.") and t[1] <= s[1] and s[2] <= t[2]
+        for s in spans)]
+    assert 0 < len(busy) < len(ticks)
+
+
+def test_null_trace_records_nothing():
+    from repro.obs import NULL_TRACE
+    with NULL_TRACE.span("sched.step", step=0):
+        with NULL_TRACE.span("exec.decode"):
+            NULL_TRACE.instant("preempt", req=1)
+    NULL_TRACE.complete("sched.load", time.perf_counter(), 0.1)
+    assert len(NULL_TRACE) == 0
+    assert NULL_TRACE.to_chrome()["traceEvents"] == []
 
 
 def test_disabled_obs_keeps_outputs_identical():

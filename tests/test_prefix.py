@@ -256,7 +256,7 @@ def test_ttft_spans_all_prefill_chunks(params):
                     max_new_tokens=4)
         eng.run_trace([r], max_steps=100)
         assert r.first_token_step is not None
-        assert r.first_token_time is not None and r.ttft_seconds() > 0
+        assert r.t_first is not None and r.ttft_seconds() > 0
         results[name] = r
     assert results["mono"].first_token_step == results["mono"].admit_step
     chunked = results["chunked"]
