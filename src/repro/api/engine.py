@@ -133,7 +133,8 @@ class Engine:
             n_shards=cfg.n_shards,
             max_live_tokens_per_shard=cfg.scheduler.max_live_tokens_per_shard,
             pool_partitions=self.executor.pool_partitions,
-            row_partitions=self.executor.row_partitions, obs=self.obs)
+            row_partitions=self.executor.row_partitions, obs=self.obs,
+            donate_state=cfg.executor_cfg.donate_state)
         self.state: Optional[_serve.ServeState] = None
         self._mode: Optional[str] = None  # "oneshot" | "continuous" (last used)
         # persisted straggler speed factors (set by a speed-aware replan);
@@ -395,7 +396,8 @@ class Engine:
                         self.cfg.scheduler.max_live_tokens_per_shard),
                     pool_partitions=self.executor.pool_partitions,
                     row_partitions=self.executor.row_partitions,
-                    obs=self.obs),
+                    obs=self.obs,
+                    donate_state=self.cfg.executor_cfg.donate_state),
                 # the executor is shared: its StepFn caches are keyed by
                 # batch shape and cache layout, so one-shot and continuous
                 # traces coexist without evicting each other

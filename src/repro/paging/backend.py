@@ -15,12 +15,19 @@ decode-time exhaustion is handled by the scheduler preempting the youngest
 request (the recompute policy), which this backend signals via
 ``PoolExhausted``.  A request whose worst-case need exceeds the whole pool
 fails fast at submit (`never_fits`).
+
+The device half of an admission is one jitted program (`paged_splice`):
+the sub-cache's pagination and the row merges of `splice_state`, with the
+live state donated under ``donate_state`` so every pool scatter writes in
+place.  The caller may not touch the pre-splice state afterwards.
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Optional
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -44,6 +51,22 @@ from repro.serving import engine as _serve
 from repro.serving.cache_backend import CacheBackend
 
 
+def paged_splice(state, sub, rows, table_write, table_store, kinds):
+    """Admission's device work: page ``sub``'s cache into the blocks of
+    ``table_write`` (`paginate_rows`), then merge its last tokens (and
+    SSM/conv rows) into ``rows`` (`splice_state`)."""
+    cache = paginate_rows(state.cache, sub.cache, rows, table_write,
+                          table_store=table_store, kinds=kinds)
+    return _serve.splice_state(state, sub, rows, cache=cache)
+
+
+@functools.cache
+def _jitted(fn, donate: bool):
+    """``fn`` jitted once per donation mode; argument 0 (the live state,
+    or an empty cache being filled) is donated when ``donate``."""
+    return jax.jit(fn, donate_argnums=(0,) if donate else ())
+
+
 def _owner_mask_np(pa: PlanArrays, rows: np.ndarray) -> np.ndarray:
     """(L, S, len(rows)) bool — the §2 strided owner rule, on the host."""
     sh = np.asarray(pa.slot_head)
@@ -59,18 +82,20 @@ class PagedBackend(CacheBackend):
 
     def __init__(self, model_cfg, ccfg, max_live_tokens=None, paging=None,
                  n_shards=1, max_live_tokens_per_shard=None,
-                 pool_partitions=1, row_partitions=1, obs=None):
+                 pool_partitions=1, row_partitions=1, obs=None,
+                 donate_state=True):
         super().__init__(model_cfg, ccfg, max_live_tokens=max_live_tokens,
                          paging=paging, n_shards=n_shards,
                          max_live_tokens_per_shard=max_live_tokens_per_shard,
                          pool_partitions=pool_partitions,
-                         row_partitions=row_partitions, obs=obs)
+                         row_partitions=row_partitions, obs=obs,
+                         donate_state=donate_state)
         self.capacity = ccfg.static_capacity()
         self.block_size = self.paging.block_size
         self.max_blocks = max_blocks_per_row(self.capacity, self.block_size)
         self.pool: Optional[BlockPool] = None
         self.table: Optional[np.ndarray] = None  # host mirror (L, S, B, M)
-        self.pa: Optional[PlanArrays] = None
+        self.pa = None  # the plan, and its host copy (the `pa` setter)
         self.n_rows: Optional[int] = None  # global batch width
         # copy-on-write backlog (DESIGN.md §14): (layer, old_id, new_id)
         # device content copies queued by `prepare_decode` when a row's next
@@ -92,6 +117,17 @@ class PagedBackend(CacheBackend):
                          if self.kv_quant is not None else None)
         self.model_dtype = None  # stashed by init_state (the logical dtype)
         self._pending_scale_reset: list = []  # (layer, [block ids])
+
+    @property
+    def pa(self) -> Optional[PlanArrays]:
+        return self._pa
+
+    @pa.setter
+    def pa(self, pa: Optional[PlanArrays]) -> None:
+        # the owner rule and the kind lookup read the plan on every
+        # admission and decode tick: fetch it to the host once per plan
+        self._pa = pa
+        self._pa_np = None if pa is None else jax.device_get(pa)
 
     @property
     def partitions(self):
@@ -136,13 +172,14 @@ class PagedBackend(CacheBackend):
             raise ValueError(f"prefill capacity {C} != backend capacity "
                              f"{self.capacity}")
         empty = self.init_state(pa, B, slot.k.dtype)  # fresh pool + mirror
-        own = _owner_mask_np(pa, np.arange(B))
+        own = _owner_mask_np(self._pa_np, np.arange(B))
         table = build_table(np.asarray(slot.lengths), self.pool,
                             self.block_size, self.max_blocks, own=own,
                             partitions=self.partitions, n_rows=B)
         self.table = table.copy()
-        cache = paginate_rows(empty.cache, slot, jnp.arange(B, dtype=jnp.int32),
-                              table, kinds=self._slot_kinds(pa))
+        cache = _jitted(paginate_rows, self.donate_state)(
+            empty.cache, slot, jnp.arange(B, dtype=jnp.int32), table,
+            kinds=self._slot_kinds(self._pa_np))
         self._observe_quant_error(slot)
         return dataclasses.replace(state, cache=cache)
 
@@ -166,19 +203,36 @@ class PagedBackend(CacheBackend):
         if (leftovers > 0).any():  # defensive: target rows must be retired
             self.pool.free_table(leftovers.reshape(self.table.shape[0], -1))
             self.table[:, :, rows_np, :] = 0
-        own = _owner_mask_np(self.pa, rows_np)
-        lengths = np.asarray(sub.cache.lengths)
+        own = _owner_mask_np(self._pa_np, rows_np)
+        # the one read the allocator needs, fetched with the first token the
+        # scheduler reads next (a fetched array keeps its host copy)
+        lengths, _ = jax.device_get((sub.cache.lengths, sub.last_tokens))
         if shared_blocks is None:
-            table_sub = build_table(lengths, self.pool,
-                                    self.block_size, self.max_blocks, own=own,
-                                    partitions=self.partitions, rows=rows_np,
-                                    n_rows=self.n_rows)
-            self.table[:, :, rows_np, :] = table_sub
-            cache = paginate_rows(state.cache, sub.cache,
-                                  jnp.asarray(rows_np, jnp.int32), table_sub,
-                                  kinds=self._slot_kinds(self.pa))
-            self._observe_quant_error(sub.cache)
-            return _serve.splice_state(state, sub, rows, cache=cache)
+            table_write = table_full = build_table(
+                lengths, self.pool, self.block_size, self.max_blocks,
+                own=own, partitions=self.partitions, rows=rows_np,
+                n_rows=self.n_rows)
+        else:
+            table_write, table_full = self._share_blocks(
+                shared_blocks, lengths, own, rows_np)
+        self.table[:, :, rows_np, :] = table_full
+        old_pool = state.cache.k_pool
+        state = _jitted(paged_splice, self.donate_state)(
+            state, sub, rows_np.astype(np.int32), table_write, table_full,
+            self._slot_kinds(self._pa_np))
+        self.obs.metrics.counter(
+            "paged_splice_total",
+            help="admission splices into the paged pools, by whether the "
+                 "live pools were written in place (donated) or copied"
+        ).inc(mode="inplace" if old_pool.is_deleted() else "copy")
+        self._observe_quant_error(sub.cache)
+        return state
+
+    def _share_blocks(self, shared_blocks, lengths, own, rows_np):
+        """Shared-prefix allocation for `splice` → (write table, stored
+        table): fresh blocks for the tokens past the shared full blocks,
+        the shared ids incref'd and mapped first in the stored table, and
+        their columns zeroed in the write table."""
         shared = np.asarray(shared_blocks, np.int32)
         n_sh = (shared > 0).sum(axis=-1)  # (L, S, R) full shared blocks
         # fresh blocks cover only tokens past the shared full blocks; the
@@ -203,18 +257,12 @@ class PagedBackend(CacheBackend):
             table_full[l, s, r, f:f + nf] = fr[:nf]
             if fr.size > nf:  # fully-shared row at capacity: growth block
                 self.pool.decref(l, fr[nf:])  # has no table home, return it
-        self.table[:, :, rows_np, :] = table_full
         # write addressing zeroes the shared columns (null-redirect): the
         # shared blocks already hold the prefix content and must never be
         # written through a refcount > 1 table entry
         col = np.arange(M)[None, None, None, :]
         table_write = np.where(col < n_sh[..., None], 0, table_full)
-        cache = paginate_rows(state.cache, sub.cache,
-                              jnp.asarray(rows_np, jnp.int32), table_write,
-                              table_store=table_full,
-                              kinds=self._slot_kinds(self.pa))
-        self._observe_quant_error(sub.cache)
-        return _serve.splice_state(state, sub, rows, cache=cache)
+        return table_write, table_full
 
     def _observe_quant_error(self, slot) -> None:
         """Quantization-error observability (DESIGN.md §15): on each
@@ -225,7 +273,7 @@ class PagedBackend(CacheBackend):
         if self.kv_kinds is None or not self.obs.enabled:
             return
         # (L, S, 1, 1): broadcasts over the (L, S, B, M) block axes
-        kinds = jnp.asarray(self._slot_kinds(self.pa))[:, :, None, None]
+        kinds = jnp.asarray(self._slot_kinds(self._pa_np))[:, :, None, None]
         err_k, den_k = kvquant.roundtrip_error(slot.k, slot.pos,
                                                self.block_size, kinds)
         err_v, den_v = kvquant.roundtrip_error(slot.v, slot.pos,
@@ -285,7 +333,7 @@ class PagedBackend(CacheBackend):
         if rows.size == 0:
             return state
         lens = np.asarray(cache.lengths)[:, :, rows]  # (L, S, R)
-        own = _owner_mask_np(self.pa, rows)
+        own = _owner_mask_np(self._pa_np, rows)
         blk = self._next_write_blocks(state, lens)  # (L, S, R)
         dirty = False
         if int(self.pool.refcount.max()) > 1:
@@ -492,9 +540,9 @@ class PagedBackend(CacheBackend):
                                     pool_hbm_bytes=0),
                 dtype=self.model_dtype or cache.k_pool.dtype,
                 partitions=self.partitions, kv_quant=self.kv_quant)
-            cand = paginate_rows(empty, slot2,
-                                 jnp.arange(B, dtype=jnp.int32), table,
-                                 kinds=self._slot_kinds(new_pa))
+            cand = _jitted(paginate_rows, self.donate_state)(
+                empty, slot2, jnp.arange(B, dtype=jnp.int32), table,
+                kinds=self._slot_kinds(new_pa))
             self.pool, self.table, self.pa = trial, table, new_pa
             return cand
 
@@ -537,7 +585,7 @@ class PagedBackend(CacheBackend):
         even when the pool has global headroom (DESIGN.md §10).
         """
         P = self.pool_partitions
-        sh = np.asarray(self.pa.slot_head)  # (L, S)
+        sh = np.asarray(self._pa_np.slot_head)  # (L, S)
         L, S = sh.shape
         occ = (sh >= 0).reshape(L, P, S // P).sum(axis=2)  # (L, P)
         frac = occ / np.maximum(occ.sum(axis=1, keepdims=True), 1)

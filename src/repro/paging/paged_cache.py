@@ -133,8 +133,11 @@ def init_paged_cache(
     else:
         part = (n_slots // slot_parts) * (batch // row_parts) * M + 1
     n_blocks = part * n_partitions
-    scale = (jnp.zeros((n_layers, n_blocks), jnp.float32)
-             if kv_quant is not None else None)
+
+    def scale():  # one buffer per pool: donation cannot alias a shared one
+        return (jnp.zeros((n_layers, n_blocks), jnp.float32)
+                if kv_quant is not None else None)
+
     cache = PagedCache(
         k_pool=jnp.zeros((n_layers, n_blocks, bs, head_dim), pool_dtype),
         v_pool=jnp.zeros((n_layers, n_blocks, bs, head_dim), pool_dtype),
@@ -142,7 +145,7 @@ def init_paged_cache(
         block_table=jnp.zeros((n_layers, n_slots, batch, M), jnp.int32),
         lengths=jnp.zeros((n_layers, n_slots, batch), jnp.int32),
         positions=jnp.zeros((batch,), jnp.int32),
-        k_scale=scale, v_scale=scale,
+        k_scale=scale(), v_scale=scale(),
     )
     return cache, BlockPool(n_layers, n_blocks, n_partitions=n_partitions)
 
@@ -318,11 +321,17 @@ def paginate_rows(
     cache: PagedCache,
     sub: SlotCache,
     rows: jnp.ndarray,  # (B_sub,) target global rows
-    table_sub: np.ndarray,  # (L, S, B_sub, M) int32 freshly allocated ids
-    table_store: Optional[np.ndarray] = None,  # (L, S, B_sub, M) stored ids
-    kinds: Optional[np.ndarray] = None,  # (L, S) per-slot kind codes
+    table_sub,  # (L, S, B_sub, M) int32 freshly allocated ids
+    table_store=None,  # (L, S, B_sub, M) int32 stored ids
+    kinds=None,  # (L, S) int32 per-slot kind codes
 ) -> PagedCache:
     """Copy a prefilled slot sub-cache into freshly allocated blocks.
+
+    Pure and traceable (the tables may be host or device int32 arrays):
+    the paged backend runs it inside one jitted program with the live
+    cache donated (`backend.splice`), so the scatters write the pools in
+    place; called eagerly it is the reference that program is tested
+    against.
 
     ``table_sub`` comes from the backend's allocator (`BlockPool.alloc`):
     entry ``[l, s, b, j]`` is the block holding columns
@@ -363,26 +372,21 @@ def paginate_rows(
     k_sub = k_sub.reshape(L, S, B_sub, M, bs, Dh)
     v_sub = v_sub.reshape(L, S, B_sub, M, bs, Dh)
     p_sub = p_sub.reshape(L, S, B_sub, M, bs)
-    tbl = np.asarray(table_sub, np.int64)
-    gids = np.where(tbl > 0,
-                    np.arange(L, dtype=np.int64)[:, None, None, None] * N + tbl,
-                    0)  # null-redirect: block 0 of layer 0
-    gids = jnp.asarray(gids.reshape(-1), jnp.int32)
-    k_pool = (cache.k_pool.reshape(L * N, bs, Dh)
-              .at[gids].set(k_sub.reshape(-1, bs, Dh).astype(cache.k_pool.dtype))
-              .reshape(L, N, bs, Dh))
-    v_pool = (cache.v_pool.reshape(L * N, bs, Dh)
-              .at[gids].set(v_sub.reshape(-1, bs, Dh).astype(cache.v_pool.dtype))
-              .reshape(L, N, bs, Dh))
-    pos_pool = (cache.pos_pool.reshape(L * N, bs)
-                .at[gids].set(p_sub.reshape(-1, bs))
-                .reshape(L, N, bs))
+    # (layer, block) scatter targets, indexed on the pools' own (L, N) axes
+    # so a block axis sharded over a mesh stays sharded; unallocated entries
+    # null-redirect to block 0 of layer 0
+    tbl = jnp.asarray(table_sub, jnp.int32)
+    live = tbl > 0
+    lid = jnp.where(live, jnp.arange(L, dtype=jnp.int32)[:, None, None, None],
+                    0)
+    bid = jnp.where(live, tbl, 0)
+    k_pool = cache.k_pool.at[lid, bid].set(k_sub.astype(cache.k_pool.dtype))
+    v_pool = cache.v_pool.at[lid, bid].set(v_sub.astype(cache.v_pool.dtype))
+    pos_pool = cache.pos_pool.at[lid, bid].set(p_sub)
     k_scale, v_scale = cache.k_scale, cache.v_scale
     if k_scales is not None:
-        k_scale = (k_scale.reshape(-1).at[gids].set(k_scales.reshape(-1))
-                   .reshape(L, N))
-        v_scale = (v_scale.reshape(-1).at[gids].set(v_scales.reshape(-1))
-                   .reshape(L, N))
+        k_scale = k_scale.at[lid, bid].set(k_scales)
+        v_scale = v_scale.at[lid, bid].set(v_scales)
     rows = jnp.asarray(rows, jnp.int32)
     stored = table_sub if table_store is None else table_store
     return PagedCache(

@@ -56,6 +56,10 @@ class CacheBackend:
     block pool into per-(model shard, data shard) partitions (the mesh
     executor's layout, where each partition lives on one device and its
     free list is that shard's budget).
+
+    ``donate_state`` is the executor's flag (``ExecutorConfig``): a backend
+    whose admission writes the live state in place donates it under the
+    same contract as the decode StepFn.
     """
 
     name: str = "?"
@@ -67,7 +71,8 @@ class CacheBackend:
                  max_live_tokens_per_shard: Optional[int] = None,
                  pool_partitions: int = 1,
                  row_partitions: int = 1,
-                 obs=None):
+                 obs=None,
+                 donate_state: bool = True):
         self.cfg = model_cfg
         self.ccfg = ccfg
         self.max_live_tokens = max_live_tokens
@@ -76,6 +81,7 @@ class CacheBackend:
         self.max_live_tokens_per_shard = max_live_tokens_per_shard
         self.pool_partitions = int(pool_partitions)
         self.row_partitions = int(row_partitions)
+        self.donate_state = bool(donate_state)
         # observability handle (DESIGN.md §12); NULL_OBS unless the Engine
         # facade threads its live Obs through
         self.obs = obs if obs is not None else NULL_OBS
@@ -311,7 +317,7 @@ def make_cache_backend(name: str, model_cfg: ModelConfig,
                        max_live_tokens_per_shard: Optional[int] = None,
                        pool_partitions: int = 1,
                        row_partitions: int = 1,
-                       obs=None) -> CacheBackend:
+                       obs=None, donate_state: bool = True) -> CacheBackend:
     """Instantiate a registered backend by name (geometry kwargs: see the
     `CacheBackend` docstring)."""
     from repro.api.registry import get_cache_backend
@@ -320,4 +326,4 @@ def make_cache_backend(name: str, model_cfg: ModelConfig,
         n_shards=n_shards,
         max_live_tokens_per_shard=max_live_tokens_per_shard,
         pool_partitions=pool_partitions, row_partitions=row_partitions,
-        obs=obs)
+        obs=obs, donate_state=donate_state)

@@ -640,7 +640,7 @@ class Scheduler:
             try:
                 with self._phase("splice", req=req.req_id):
                     self.state = self.backend.splice(self.state, sub,
-                                                     jnp.asarray([row]))
+                                                     np.asarray([row]))
             except PoolExhausted:
                 # admission never preempts (only decode growth does —
                 # evicting older in-flight work to admit newer would invert
@@ -814,11 +814,11 @@ class Scheduler:
                 with self._phase("splice", req=req.req_id):
                     if shared is not None:
                         self.state = self.backend.splice(
-                            self.state, job.state, jnp.asarray([row]),
+                            self.state, job.state, np.asarray([row]),
                             shared_blocks=shared)
                     else:
                         self.state = self.backend.splice(
-                            self.state, job.state, jnp.asarray([row]))
+                            self.state, job.state, np.asarray([row]))
                 break
             except PoolExhausted:
                 # cheapest memory first: entries held only by the index

@@ -343,9 +343,13 @@ for eng in (eng_l, eng_m):
     assert out["finished"] == out["total"], out
 toks_l = {r.req_id: r.generated for r in eng_l.finished_requests}
 toks_m = {r.req_id: r.generated for r in eng_m.finished_requests}
+m = eng_m.obs.metrics
 results.append({"case": ["continuous", backend],
                 "tokens_equal": toks_l == toks_m,
-                "decode_traces": eng_m.executor.decode_traces})
+                "decode_traces": eng_m.executor.decode_traces,
+                "admissions": m.counter_value("sched_admissions_total"),
+                "splices": {k: m.counter_value("paged_splice_total", mode=k)
+                            for k in ("inplace", "copy")}})
 print(json.dumps(results))
 """
 
@@ -386,3 +390,7 @@ def test_mesh_parity_multidevice_subprocess(backend):
     for r in cont:
         assert r["tokens_equal"], r
         assert r["decode_traces"] == 1, r
+        if backend == "paged":  # every admission spliced in place on the
+            # mesh: donation aliases only where the pools keep their sharding
+            assert r["splices"] == {"inplace": r["admissions"], "copy": 0}, r
+            assert r["admissions"] == 5, r

@@ -2,6 +2,7 @@
 (bit-for-bit, property-tested over random placements/lengths), append
 parity including the recency ring, and slot↔paged round-trips."""
 import numpy as np
+import jax
 import jax.numpy as jnp
 import pytest
 
@@ -319,3 +320,134 @@ def test_migrate_quantized_cache_decode_parity():
         assert np.array_equal(np.asarray(cache.lengths[layer]),
                               np.asarray(cand.lengths[layer]))
         assert float(jnp.abs(a - b).max()) < 0.05, layer
+
+
+# ---------------------------------------------------------------------------
+# Admission splice: one jitted, donated program (DESIGN.md §9)
+# ---------------------------------------------------------------------------
+
+
+def _bits(x):
+    """Raw bytes of an array, so float comparisons are bitwise."""
+    return np.atleast_1d(np.asarray(x)).view(np.uint8)
+
+
+@pytest.mark.parametrize("case", ["bf16", "int8", "shared_prefix"])
+def test_jitted_splice_bitwise_equals_eager(case):
+    """The donated splice program writes the live pools, scales, table,
+    lengths, positions and last tokens bit for bit as the eager
+    `paginate_rows` + `splice_state` do — for a bf16 pool, an int8 pool
+    with mixed per-slot kinds, and a shared-prefix splice whose write
+    table null-redirects the shared columns — and consumes the pre-splice
+    buffers (it ran in place)."""
+    from repro.paging.backend import _jitted, paged_splice
+    from repro.paging.kvquant import KVQuantSpec
+    from repro.serving.engine import ServeState, splice_state
+
+    rng = np.random.default_rng(3)
+    L, S, B, C, Dh, bs = 2, 4, 3, 20, 8, 8
+    M = max_blocks_per_row(C, bs)
+    quant = case == "int8"
+    paging = PagingConfig(block_size=bs, n_blocks=96,
+                          kv_dtype="int8" if quant else "fp32")
+    kinds = ((np.add.outer(np.arange(L), np.arange(S)) % 2).astype(np.int32)
+             if quant else None)
+
+    def slot(batch):
+        s = _random_slot_layer(rng, S, batch, C, Dh, L=L)
+        return SlotCache(k=s.k.astype(jnp.bfloat16),
+                         v=s.v.astype(jnp.bfloat16), lengths=s.lengths,
+                         pos=s.pos, positions=s.positions)
+
+    # a live cache holding every row, then a new request spliced into row 1
+    live, pool = init_paged_cache(
+        L, S, B, C, Dh, paging, dtype=jnp.bfloat16,
+        kv_quant=KVQuantSpec(base="int8") if quant else None)
+    full = slot(B)
+    lens = np.asarray(full.lengths)
+    live = paginate_rows(live, full, jnp.arange(B, dtype=jnp.int32),
+                         build_table(lens, pool, bs, M, own=lens > 0),
+                         kinds=kinds)
+    sub = slot(1)
+    sub_lens = np.asarray(sub.lengths)
+    table = build_table(sub_lens, pool, bs, M, own=sub_lens > 0,
+                        rows=np.asarray([1]), n_rows=B)
+    table_write = table.copy()
+    if case == "shared_prefix":  # column 0 maps donor blocks, never written
+        table_write[..., 0] = 0
+    rows = np.asarray([1], np.int32)
+    state = ServeState(cache=live, ssm_state=None, conv_state=None,
+                       cross_k=None, cross_v=None,
+                       last_tokens=jnp.asarray([5, 6, 7], jnp.int32),
+                       decode_steps=jnp.int32(4))
+    sub_state = ServeState(cache=sub, ssm_state=None, conv_state=None,
+                           cross_k=None, cross_v=None,
+                           last_tokens=jnp.asarray([9], jnp.int32),
+                           decode_steps=jnp.int32(0))
+
+    eager = splice_state(state, sub_state, rows, cache=paginate_rows(
+        live, sub, rows, table_write, table_store=table, kinds=kinds))
+    # to the host first: eager passes leaves such as decode_steps through,
+    # and the donated call consumes them
+    want = [(path, np.asarray(x))
+            for path, x in jax.tree_util.tree_leaves_with_path(eager)]
+    jitted = _jitted(paged_splice, True)(state, sub_state, rows, table_write,
+                                         table, kinds)
+    assert live.k_pool.is_deleted() and live.v_pool.is_deleted()
+    got = jax.tree_util.tree_leaves(jitted)
+    assert len(want) == len(got) == (10 if quant else 8)
+    for (path, a), b in zip(want, got):
+        assert a.dtype == b.dtype and a.shape == b.shape, path
+        assert np.array_equal(_bits(a), _bits(b)), path
+    assert np.array_equal(np.asarray(jitted.cache.block_table)[:, :, 1],
+                          table[:, :, 0])
+    assert int(np.asarray(jitted.last_tokens)[1]) == 9
+
+
+@pytest.mark.parametrize("donate", [True, False])
+def test_splice_donation_follows_donate_state(donate, monkeypatch):
+    """Through the engine: with ``donate_state`` the splice consumes the
+    pre-splice pools (in place) and counts each admission under
+    ``paged_splice_total{mode="inplace"}``; without it they stay readable
+    and every admission counts under ``mode="copy"``."""
+    from repro.api import (CompressionConfig, Engine, EngineConfig,
+                           ExecutorConfig, PlannerConfig, SchedulerConfig)
+    from repro.paging.backend import PagedBackend
+    from repro.serving.request import Request
+
+    cfg = EngineConfig.smoke(
+        "minitron-8b", n_shards=4, max_seq_len=48,
+        compression=CompressionConfig(policy="ada_snapkv", budget=12,
+                                      alpha_max=2.0, obs_window=8, sink=2,
+                                      decode_margin=8),
+        planner=PlannerConfig(mode="fairkv_dp", extra_copies=4, batch_cap=2),
+        scheduler=SchedulerConfig(max_rows=2, enable_replan=False),
+        cache_backend="paged", paging=PagingConfig(block_size=8),
+        executor_cfg=ExecutorConfig(donate_state=donate))
+    eng = Engine.build(cfg)
+    seen = []
+    splice = PagedBackend.splice
+
+    def spy(self, state, sub, rows, **kw):
+        out = splice(self, state, sub, rows, **kw)
+        pools = (state.cache.k_pool, state.cache.v_pool, state.cache.pos_pool)
+        seen.append([p.is_deleted() for p in pools])
+        if not donate:  # the pre-splice state is still whole
+            np.asarray(state.cache.k_pool)
+        return out
+
+    monkeypatch.setattr(PagedBackend, "splice", spy)
+    rng = np.random.default_rng(1)
+    reqs = [Request(req_id=i, prompt=rng.integers(
+        1, cfg.model.vocab_size, size=12 + 4 * i).astype(np.int32),
+        arrival_step=i, max_new_tokens=3) for i in range(3)]
+    out = eng.run_trace(reqs, max_steps=200)
+    assert out["finished"] == out["total"] == 3
+    assert eng.scheduler.backend.donate_state is donate
+    assert len(seen) == 3  # one splice per admission
+    assert all(all(d) if donate else not any(d) for d in seen), seen
+    m = eng.obs.metrics
+    assert m.counter_value("sched_admissions_total") == 3
+    mode, other = ("inplace", "copy") if donate else ("copy", "inplace")
+    assert m.counter_value("paged_splice_total", mode=mode) == 3
+    assert m.counter_value("paged_splice_total", mode=other) == 0
