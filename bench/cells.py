@@ -7,34 +7,23 @@ adding files and entries alone.
 
 A configuration file holds the model as it is run, under the published
 config's own key names, plus the engine settings that belong to the
-model (``engine``: dtype, paging, placement).  The executor follows from
-the cell's ``chips``.  A traffic file holds the mix (``traffic.py``), the
-batch width and the HeadKV settings.
+model (``engine``: dtype, paging, placement), and names its family
+(``"family"``, ``families.DEFAULT`` where absent: the module under
+``bench/families/`` that knows the family's own keys and layers).  The
+executor follows from the cell's ``chips``.  A traffic file holds the mix
+(``traffic.py``), the batch width and the HeadKV settings.
 """
 from __future__ import annotations
 
 import json
-from dataclasses import dataclass
+from dataclasses import dataclass, replace
 from pathlib import Path
+
+import families
 
 BENCH = Path(__file__).resolve().parent
 ROOT = BENCH.parent
 
-# published config key -> model dict key; the model dict is what
-# ``weights``/``counts``/``reference`` read
-MODEL_KEYS = {
-    "num_hidden_layers": "n_layers",
-    "hidden_size": "d_model",
-    "num_attention_heads": "n_heads",
-    "num_key_value_heads": "n_kv_heads",
-    "head_dim": "head_dim",
-    "intermediate_size": "d_ff",
-    "vocab_size": "vocab_size",
-    "rope_theta": "rope_theta",
-    "rms_norm_eps": "rms_eps",
-    "tie_word_embeddings": "tie_embeddings",
-    "qkv_bias": "qkv_bias",
-}
 # published multipliers some families add to the plain decoder (Granite);
 # a configuration without them runs the plain one.  ``None`` is
 # 1 / sqrt(head_dim).
@@ -64,14 +53,38 @@ class Cell:
         return dict(self.traffic["compression"])
 
 
+def family_name(config: dict) -> str:
+    return config.get("family", families.DEFAULT)
+
+
+def family_of(config: dict):
+    return families.load(family_name(config))
+
+
+def model_keys(config: dict) -> dict:
+    """Published config key -> model-dict key: the shared ones and the
+    family's own."""
+    return {**families.SHARED_KEYS, **family_of(config).MODEL_KEYS}
+
+
 def model_dict(config: dict) -> dict:
-    missing = sorted(set(MODEL_KEYS) - set(config))
+    """What ``weights``/``counts``/``reference`` and the family read: the
+    configuration's sizes under the model-dict keys (a list, such as a
+    per-layer pattern, as a tuple: the reference's jitted layers take the
+    dict as a static argument), the multipliers, the padded vocabulary and
+    the family's name."""
+    keys = model_keys(config)
+    missing = sorted(set(keys) - set(config))
     if missing:
         raise KeyError(f"configuration lacks {missing}")
-    m = {name: config[key] for key, name in MODEL_KEYS.items()}
+    m = {}
+    for key, name in keys.items():
+        v = config[key]
+        m[name] = tuple(v) if isinstance(v, list) else v
     for key, default in MULTIPLIERS.items():
         m[key] = float(config.get(key, default or m["head_dim"] ** -0.5))
     m["padded_vocab"] = -(-m["vocab_size"] // 128) * 128
+    m["family"] = family_name(config)
     return m
 
 
@@ -94,6 +107,7 @@ def load(workload: str, root: Path = ROOT) -> Cell:
     w = cells[workload]
     cfg = next(c for c in bench["configs"] if c["name"] == w["config"])
     config = json.loads((root / cfg["file"]).read_text())
+    family_of(config)  # an unknown family fails here, with its path
     traffic = json.loads(
         (root / "bench" / "traffic" / f"{w['traffic']}.json").read_text())
     return Cell(name=workload, chips=int(w["chips"]), config_name=w["config"],
@@ -106,15 +120,39 @@ def model_config(cell: Cell):
     from repro.configs import get_config
     base = get_config(cell.config["arch"])
     m = program_model(cell.model)
-    changed = {k: m[k] for k in MODEL_KEYS.values()
-               if getattr(base, k) != m[k]}
+    fields = {k: k for k in families.SHARED_KEYS.values()}
+    fields.update(family_of(cell.config).PROGRAM_FIELDS)
+    changed = {k: m[k] for k, f in fields.items()
+               if program_field(base, f) != m[k]}
     allowed = set(cell.config.get("overrides", ()))
     bad = sorted(set(changed) - allowed)
     if bad:
         raise ValueError(
             f"{cell.config_name}: {bad} differ from the registry's "
             f"{base.name} and are not listed under 'overrides'")
-    return base.with_overrides(**changed)
+    return with_fields(base, {fields[k]: v for k, v in changed.items()})
+
+
+def program_field(cfg, field: str):
+    """A field of a `ModelConfig`, dotted into a nested group
+    (``"moe.d_expert"``)."""
+    for part in field.split("."):
+        cfg = getattr(cfg, part)
+    return cfg
+
+
+def with_fields(cfg, values: dict):
+    """``cfg`` with the dotted fields of ``values`` replaced."""
+    top, nested = {}, {}
+    for field, v in values.items():
+        head, _, rest = field.partition(".")
+        if rest:
+            nested.setdefault(head, {})[rest] = v
+        else:
+            top[head] = v
+    for head, sub in nested.items():
+        top[head] = with_fields(getattr(cfg, head), sub)
+    return replace(cfg, **top)
 
 
 def engine_config(cell: Cell, n_blocks: int):

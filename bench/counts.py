@@ -2,7 +2,8 @@
 
 Everything here is plain arithmetic on the cell's model dict (see
 ``weights.py``) and its compression settings, so every PR computes the
-same numbers in the same way:
+same numbers in the same way; what depends on the layer's mathematics is
+its family's (``families/<family>.py``):
 
 - ``headkv_keep``: the per-(layer, head) prompt tokens HeadKV retains, the
   rule a planner profile and the reference both follow;
@@ -18,6 +19,8 @@ import json
 from pathlib import Path
 
 import numpy as np
+
+import families
 
 PEAKS_FILE = Path(__file__).resolve().parent / "peaks.json"
 
@@ -77,34 +80,30 @@ def live_lengths(keep: np.ndarray, appended: int, capacity: int) -> np.ndarray:
 
 
 def matmul_params(m: dict) -> int:
-    """Weights one token multiplies through: every layer's projections and
-    MLP, plus the unembedding over the real vocabulary."""
-    D, Hq, Hkv, Dh, F = (m["d_model"], m["n_heads"], m["n_kv_heads"],
-                         m["head_dim"], m["d_ff"])
-    per_layer = D * (Hq + 2 * Hkv) * Dh + Hq * Dh * D + 3 * D * F
-    return m["n_layers"] * per_layer + m["vocab_size"] * D
+    """Weights one token multiplies through: its family's layer stack
+    (``layer_matmul_params``), plus the unembedding over the real
+    vocabulary."""
+    return (families.of(m).layer_matmul_params(m)
+            + m["vocab_size"] * m["d_model"])
 
 
 def decode_flops(m: dict, lengths: np.ndarray) -> float:
     """Model FLOPs of one decode token whose per-(layer, head) retained
     cache lengths (after its own append) are ``lengths`` (L, H): 2 per
-    weight, plus q·k and p·v over every retained entry of each query head."""
-    G = m["n_heads"] // m["n_kv_heads"]
-    attn = 4.0 * G * m["head_dim"] * float(np.asarray(lengths).sum())
-    return 2.0 * matmul_params(m) + attn
+    weight, plus its family's attention over the retained entries."""
+    return 2.0 * matmul_params(m) + families.of(m).decode_attn_flops(
+        m, lengths)
 
 
 def prefill_flops(m: dict, prompt_len: int) -> float:
-    """Model FLOPs of one prompt's prefill: every token's projections and
-    MLP, the causal attention (half the T×T score matrix), and one
-    unembedding row (only the last position's logits are formed)."""
-    D, Hq, Hkv, Dh, F = (m["d_model"], m["n_heads"], m["n_kv_heads"],
-                         m["head_dim"], m["d_ff"])
+    """Model FLOPs of one prompt's prefill: every token through the layer
+    stack, its family's causal attention, and one unembedding row (only the
+    last position's logits are formed)."""
+    fam = families.of(m)
     T = prompt_len
-    per_layer = D * (Hq + 2 * Hkv) * Dh + Hq * Dh * D + 3 * D * F
-    proj = 2.0 * T * m["n_layers"] * per_layer
-    attn = 4.0 * Hq * Dh * (T * (T + 1) / 2) * m["n_layers"]
-    return proj + attn + 2.0 * m["vocab_size"] * D
+    proj = 2.0 * T * fam.layer_matmul_params(m)
+    attn = fam.prefill_attn_flops(m, T)
+    return proj + attn + 2.0 * m["vocab_size"] * m["d_model"]
 
 
 # ---- bytes -----------------------------------------------------------------

@@ -1,4 +1,5 @@
-"""The program's own phases, read back for the per-layer metrics.
+"""The program's own phases and counters, read back for the per-layer
+metrics.
 
 The scheduler runs each phase of its tick under a ``sched.<phase>`` span and
 the executor each StepFn call under ``exec.<kind>`` (with a child
@@ -8,8 +9,13 @@ profiler's capture on the device operations' clock.  A request carries
 prefill starts) and ``t_first`` (its first token on the host), the clock on
 which the harness stamps due times and tokens.
 
-A program without these spans or stamps reads as nothing (``None``), never
-as zero.
+The harness snapshots the program's metrics registry (``Engine.metrics()``)
+at the window's two ends and hands both to the readers (``ctx["metrics0"]``,
+``ctx["metrics1"]``); ``window_change`` reads a counter's or a histogram's
+change between them.
+
+A program without these spans, stamps or counters reads as nothing
+(``None``), never as zero.
 """
 from __future__ import annotations
 
@@ -19,20 +25,43 @@ import trace_reduce
 
 PROGRAM = ("sched.", "exec.")  # span names the program writes
 HARNESS = "harness"  # idle with no program span open (outside sched.step)
-CTX_KEY = "phases.trace"  # where ``traced`` keeps the capture in the ctx
+CTX_KEY = "capture"  # where the harness hands readers its traced capture
 
 
 def traced(ctx: dict):
     """(trace, t0, t1): the run's profiler capture (``trace_reduce.load``)
-    and the bounds of its ``bench.traced`` span, loaded once per run and
-    kept in ``ctx`` under ``CTX_KEY``; None in a run without a capture."""
-    if CTX_KEY not in ctx:
-        if ctx.get("trace") is None:
-            return None
-        from run import TRACE_DIR
-        trace = trace_reduce.load(trace_reduce.find_xplane(TRACE_DIR))
-        ctx[CTX_KEY] = (trace, *trace_reduce.window_of(trace, "bench.traced"))
-    return ctx[CTX_KEY]
+    and the bounds of its ``bench.traced`` span, as the harness loaded them
+    (``run.load_capture``); None in a run without a capture."""
+    return ctx.get(CTX_KEY)
+
+
+def window_change(metrics0: dict, metrics1: dict, name: str, **labels):
+    """The change between two registry snapshots of the counter or histogram
+    ``name``, over its series whose labels include ``labels``: a float for
+    a counter, (seconds, observations) for a histogram; None where the
+    program has no such family."""
+    fam = metrics1.get(name)
+    if fam is None:
+        return None
+    kind = fam["kind"]
+    if kind not in ("counter", "histogram"):
+        raise ValueError(f"{name} is a {kind}: it has no change to read")
+    want = {k: str(v) for k, v in labels.items()}
+
+    def total(snap):
+        series = [s for s in snap.get(name, {"series": []})["series"]
+                  if want.items() <= s["labels"].items()]
+        if kind == "counter":
+            return sum((s["value"] for s in series), 0.0)
+        t, n = 0.0, 0
+        for s in series:
+            t, n = t + s["sum"], n + s["count"]
+        return t, n
+
+    if kind == "counter":
+        return total(metrics1) - total(metrics0)
+    (t1, n1), (t0, n0) = total(metrics1), total(metrics0)
+    return t1 - t0, n1 - n0
 
 
 def has_program_spans(trace: dict) -> bool:

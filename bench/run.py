@@ -24,8 +24,12 @@ The run, in order:
 With ``--trace 1`` it also records a ``jax.profiler`` trace of the window's
 first seconds and reports the cell's per-layer metrics instead of its
 end-to-end ones.  Metric values come from the readers in
-``bench/metrics/<name>.py``; everything cell-specific is read from
-``BENCHMARK.json`` and the files it names.
+``bench/metrics/<name>.py``, each handed the run's context: the window's
+bounds, the token log and the requests, the program's metrics registry
+snapshotted at the window's two ends (``metrics0``/``metrics1``, read with
+``phases.window_change``) and, traced, the capture (``phases.traced``).
+Everything cell-specific is read from ``BENCHMARK.json`` and the files it
+names.
 """
 from __future__ import annotations
 
@@ -51,6 +55,7 @@ import numpy as np  # noqa: E402
 
 import cells  # noqa: E402
 import counts  # noqa: E402
+import phases  # noqa: E402
 import stats  # noqa: E402
 import trace_reduce  # noqa: E402
 import traffic as traffic_gen  # noqa: E402
@@ -235,15 +240,20 @@ def cell_metrics(bench: dict, workload: str, trace: bool) -> list:
             if "workloads" not in m or workload in m["workloads"]]
 
 
-def stepfn_totals(eng) -> dict:
-    """{kind: (seconds, calls)} of the ``stepfn_wall_s`` histogram."""
-    fam = eng.metrics().get("stepfn_wall_s", {"series": []})
-    out = {}
-    for s in fam["series"]:
-        k = s["labels"]["kind"]
-        t, n = out.get(k, (0.0, 0))
-        out[k] = (t + s["sum"], n + s["count"])
-    return out
+def stepfn_window(metrics0: dict, metrics1: dict) -> dict:
+    """{kind: (seconds, calls)}: the window's change of the
+    ``stepfn_wall_s`` histogram, per StepFn kind."""
+    kinds = {s["labels"]["kind"]
+             for s in metrics1.get("stepfn_wall_s", {"series": []})["series"]}
+    return {k: phases.window_change(metrics0, metrics1, "stepfn_wall_s",
+                                    kind=k) for k in sorted(kinds)}
+
+
+def load_capture():
+    """(trace, start, end): the profiler's capture of a traced run
+    (``trace_reduce.load``) and the bounds of its ``bench.traced`` span."""
+    trace = trace_reduce.load(trace_reduce.find_xplane(TRACE_DIR))
+    return (trace, *trace_reduce.window_of(trace, "bench.traced"))
 
 
 # ---- correctness -----------------------------------------------------------
@@ -387,7 +397,7 @@ def run(workload: str, seed: int, seconds: float, trace: bool,
             while drv.sched.queue:
                 drv.step()
         cc.armed = True
-        stepfn0 = stepfn_totals(eng)
+        metrics0 = eng.metrics()
         tick0 = len(drv.ticks)
         prof = None
         if trace:
@@ -424,7 +434,7 @@ def run(workload: str, seed: int, seconds: float, trace: bool,
                 jax.profiler.stop_trace()
         t1 = t
         queue_end = len(drv.sched.queue)
-        stepfn1 = stepfn_totals(eng)
+        metrics1 = eng.metrics()
         cc.armed = False
         window_ticks = drv.ticks[tick0:]
         # answers due in the window that have not come yet are waited for
@@ -450,9 +460,8 @@ def run(workload: str, seed: int, seconds: float, trace: bool,
         "cell": cell, "model": m, "chips": cell.chips, "seconds": seconds,
         "t0": t0, "t1": t1, "setup_s": setup_s, "log": drv.log,
         "ticks": window_ticks,
-        "stepfn": {k: (v[0] - stepfn0.get(k, (0.0, 0))[0],
-                       v[1] - stepfn0.get(k, (0.0, 0))[1])
-                   for k, v in stepfn1.items()},
+        "metrics0": metrics0, "metrics1": metrics1,
+        "stepfn": stepfn_window(metrics0, metrics1),
         "peaks": counts.peaks(dev0.device_kind) if require_chip else None,
         "imp": imp, "reqs": drv.reqs, "trace": None,
         "admitted_prompts": _admitted_prompts(drv, t0, t1),
@@ -465,10 +474,10 @@ def run(workload: str, seed: int, seconds: float, trace: bool,
     if trace:
         kernels = {n: r.KERNEL for n, r in readers.items()
                    if hasattr(r, "KERNEL")}
-        tr_data = trace_reduce.load(trace_reduce.find_xplane(TRACE_DIR))
-        w0, w1 = trace_reduce.window_of(tr_data, "bench.traced")
-        red = trace_reduce.reduce(tr_data, w0, w1, kernels)
+        capture = load_capture()
+        red = trace_reduce.reduce(*capture, kernels)
         ctx["trace"] = red
+        ctx[phases.CTX_KEY] = capture
         ctx["trace_ticks"] = [x for x in window_ticks if x[0] <= t_tr]
         ctx["trace_host_s"] = t_tr - t0
         device["busy_s"] = red["busy_ns"] / 1e9

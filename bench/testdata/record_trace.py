@@ -60,11 +60,11 @@ def main(out_dir: str) -> int:
     dst = Path(out_dir) / "v5e_small.xplane.pb"
     shutil.copy(src, dst)
     tr = trace_reduce.load(dst)
-    w0, w1 = trace_reduce.window_of(tr, "bench.traced")
-    print(f"window {w0} {w1}")
+    start, end = trace_reduce.window_of(tr, "bench.traced")
+    print(f"window {start} {end}")
     for plane, ops in tr["devices"].items():
         for s, e, n in sorted(ops):
-            if e > w0 and s < w1:
+            if e > start and s < end:
                 print(f"op {plane} {s} {e} {n}")
     for s, e, n in sorted(tr["host"]):
         if n.startswith("bench."):

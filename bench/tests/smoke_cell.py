@@ -1,38 +1,64 @@
 """A cell at smoke size for the CPU tests: a real configuration file with
-its widths cut to a toy, and a small mix of the same kind as the real one."""
+its widths cut to its family's toy (``SMOKE``), and a small mix of the
+same kind as the cell's own (its traffic file's arrival).
+
+The cells are those of ``BENCHMARK.json`` plus any whose check file
+(``bench/checks/<cell>.json``) exists without an entry there; a check file
+carries the cell's limits at smoke size under ``"smoke_limits"``.
+"""
 import json
 
 import cells
 import run
 
-SMOKE_WIDTHS = {"num_hidden_layers": 2, "hidden_size": 64,
-                "num_attention_heads": 4, "num_key_value_heads": 2,
-                "head_dim": 16, "intermediate_size": 128, "vocab_size": 256}
-# the smoke cell of each benchmark cell: (configuration, arrival)
-CELLS = {"granite-3-2b.decode-long": ("granite-3-2b", "saturated"),
-         "qwen1.5-110b.prefill-mix": ("qwen1.5-110b", "poisson")}
-# the numbers each cell's check file compares, with limits for the smoke
-# size, set between what sound bfloat16 runs and the fp8 control read there
-# on seeds 1, 3 and 2**31 + 99 (largest sound / smallest control mean gap):
-# granite 4.1e-4 / 0.0143, qwen 2.4e-5 / 0.00245
-SMOKE_LIMITS = {"granite-3-2b.decode-long": {"mean_logit_gap": 0.003},
-                "qwen1.5-110b.prefill-mix": {"mean_logit_gap": 0.0006}}
+CONFIGS = sorted(p.stem for p in (cells.BENCH / "configs").glob("*.json"))
+SMOKE_TOKENS = 10  # compared positions a smoke run needs (requests are short)
+
+
+def _cell_files() -> dict:
+    """{cell: (configuration, traffic mix)}."""
+    bench = json.loads((cells.ROOT / "BENCHMARK.json").read_text())
+    out = {w["name"]: (w["config"], w["traffic"])
+           for w in bench["workloads"]}
+    mixes = [p.stem for p in (cells.BENCH / "traffic").glob("*.json")]
+    for p in (cells.BENCH / "checks").glob("*.json"):
+        if p.stem not in out:
+            found = [(c, t) for c in CONFIGS for t in mixes
+                     if p.stem == f"{c}.{t}"]
+            if len(found) != 1:
+                raise ValueError(f"{p}: its name is not one configuration "
+                                 f"and one traffic mix")
+            out[p.stem] = found[0]
+    return out
+
+
+CELLS = _cell_files()
 
 
 def smoke_limits(workload: str) -> dict:
     """The cell's own check file with the limits of the smoke size and
-    fewer positions (smoke requests are short)."""
+    fewer positions."""
     own = run.load_limits(workload)
-    assert set(own["limits"]) == set(SMOKE_LIMITS[workload])
-    return dict(own, min_sampled_tokens=10, limits=SMOKE_LIMITS[workload])
+    assert set(own["limits"]) == set(own["smoke_limits"])
+    return dict(own, min_sampled_tokens=SMOKE_TOKENS,
+                limits=own["smoke_limits"])
+
+
+def smoke_of(workload: str, **kw) -> cells.Cell:
+    """The smoke cell of a benchmark cell: its configuration, and the
+    arrival of its traffic file."""
+    config, mix = CELLS[workload]
+    tr = json.loads((cells.BENCH / "traffic" / f"{mix}.json").read_text())
+    return smoke_cell(config, tr["arrival"], **kw)
 
 
 def smoke_cell(config: str, arrival: str, dtype: str = "bfloat16",
                decode_margin: int = 8) -> cells.Cell:
     cfg = json.loads((cells.BENCH / "configs" / f"{config}.json").read_text())
-    cfg.update(SMOKE_WIDTHS)
-    cfg["overrides"] = ["n_layers", "d_model", "n_heads", "n_kv_heads",
-                        "head_dim", "d_ff", "vocab_size", "rms_eps"]
+    smoke = cells.family_of(cfg).SMOKE
+    cfg.update(smoke)
+    keys = cells.model_keys(cfg)
+    cfg["overrides"] = [keys[k] for k in smoke] + ["rms_eps"]
     cfg["engine"]["dtype"] = dtype
     cfg["engine"]["paging"] = {"block_size": 16, "decode_impl": "auto",
                                "kv_dtype": "fp32"}

@@ -8,14 +8,14 @@ size (``control.py``)."""
 import pytest
 
 import run
-from smoke_cell import CELLS, smoke_cell, smoke_limits
+from smoke_cell import CELLS, smoke_limits, smoke_of
 
 
 @pytest.mark.parametrize("workload", sorted(CELLS))
 @pytest.mark.parametrize("seed", [1, 3])
 def test_control_fails_where_the_program_passes(workload, seed):
     res = run.run(workload, seed, 2.0, False, require_chip=False,
-                  cell=smoke_cell(*CELLS[workload]),
+                  cell=smoke_of(workload),
                   limits=smoke_limits(workload), control=True)
     assert res["program"]["correct"], res["program"]["checks"]
     assert not res["correct"], res["checks"]

@@ -10,8 +10,9 @@ from repro.compression.policies import headkv
 COMP = {"policy": "headkv", "budget": 256, "alpha_max": 4.0,
         "headkv_base_ratio": 0.2, "obs_window": 32, "sink": 4, "pool": 7,
         "decode_margin": 64}
-M = {"n_layers": 40, "d_model": 2048, "n_heads": 32, "n_kv_heads": 8,
-     "head_dim": 64, "d_ff": 8192, "vocab_size": 49155}
+# a dense model dict: the FLOP counts go through its family's module
+M = {"family": "dense", "n_layers": 40, "d_model": 2048, "n_heads": 32,
+     "n_kv_heads": 8, "head_dim": 64, "d_ff": 8192, "vocab_size": 49155}
 
 
 @pytest.mark.parametrize("T", [2048, 3072, 300])

@@ -11,7 +11,7 @@ import jax.numpy as jnp
 import pytest
 
 import run
-from smoke_cell import CELLS, smoke_cell, smoke_limits
+from smoke_cell import CELLS, smoke_limits, smoke_of
 
 SEED = 2 ** 31 + 99
 
@@ -60,7 +60,7 @@ def token_altered(eng):
 def _run(workload, fault=None):
     return run.run(workload, SEED, 2.0, False, require_chip=False,
                    limits=smoke_limits(workload), fault=fault,
-                   cell=smoke_cell(*CELLS[workload]))
+                   cell=smoke_of(workload))
 
 
 @pytest.mark.parametrize("workload", sorted(CELLS))

@@ -11,7 +11,7 @@ import phases
 import run
 import stats
 import trace_reduce
-from smoke_cell import CELLS, smoke_cell, smoke_limits
+from smoke_cell import smoke_limits, smoke_of
 
 SEED = 2 ** 31 + 99
 CELL = "qwen1.5-110b.prefill-mix"
@@ -37,7 +37,7 @@ def smoke_ctx():
         mp.setattr(run, "_reader", reader)
         res = run.run(CELL, SEED, 2.0, False, require_chip=False,
                       limits=smoke_limits(CELL),
-                      cell=smoke_cell(*CELLS[CELL]))
+                      cell=smoke_of(CELL))
     assert res["correct"], res["checks"]
     return seen["ctx"]
 
@@ -57,6 +57,49 @@ def test_scheduler_readers_on_the_smoke_cell(smoke_ctx):
     assert phases.traced(smoke_ctx) is None
     assert _read("step_host_ms", smoke_ctx) is None
     assert _read("host_phase_idle_share", smoke_ctx) is None
+
+
+def test_window_change_reads_the_splices_of_the_window(smoke_ctx):
+    """The registry's snapshots at the window's ends: every admission of the
+    window spliced its request into the live pools once, in place."""
+    m0, m1 = smoke_ctx["metrics0"], smoke_ctx["metrics1"]
+    t0, t1 = smoke_ctx["t0"], smoke_ctx["t1"]
+    admitted = sum(1 for r in smoke_ctx["reqs"].values()
+                   if r.t_admit is not None and t0 <= r.t_admit <= t1)
+    assert admitted > 0
+    assert phases.window_change(m0, m1, "paged_splice_total",
+                                mode="inplace") == admitted
+    assert phases.window_change(m0, m1, "paged_splice_total",
+                                mode="copy") == 0
+    assert smoke_ctx["stepfn"]["prefill"] == phases.window_change(
+        m0, m1, "stepfn_wall_s", kind="prefill")
+
+
+def _fam(kind, *series):
+    return {"kind": kind, "help": "", "series": list(series)}
+
+
+def test_window_change_by_hand():
+    m0 = {"c": _fam("counter", {"labels": {"mode": "a"}, "value": 2.0}),
+          "h": _fam("histogram", {"labels": {"kind": "x", "n": "1"},
+                                  "sum": 0.5, "count": 2})}
+    m1 = {"c": _fam("counter", {"labels": {"mode": "a"}, "value": 5.0},
+                    {"labels": {"mode": "b"}, "value": 1.0}),
+          "h": _fam("histogram", {"labels": {"kind": "x", "n": "1"},
+                                  "sum": 2.0, "count": 5},
+                    {"labels": {"kind": "x", "n": "2"}, "sum": 1.0,
+                     "count": 1},
+                    {"labels": {"kind": "y", "n": "1"}, "sum": 9.0,
+                     "count": 9}),
+          "g": _fam("gauge", {"labels": {}, "value": 3.0})}
+    assert phases.window_change(m0, m1, "c", mode="a") == 3.0
+    assert phases.window_change(m0, m1, "c", mode="b") == 1.0  # new series
+    assert phases.window_change(m0, m1, "c") == 4.0
+    assert phases.window_change(m0, m1, "h", kind="x") == (2.5, 4)
+    assert phases.window_change(m0, m1, "h", n=1) == (10.5, 12)
+    assert phases.window_change(m0, m1, "absent") is None
+    with pytest.raises(ValueError):
+        phases.window_change(m0, m1, "g")
 
 
 # two busy ticks (an admission, a decode) and an idle spin between them
@@ -138,18 +181,19 @@ def test_host_phase_idle_share_on_a_recorded_program_trace(tmp_path,
     with jax.profiler.trace(str(tmp_path)):
         with jax.profiler.TraceAnnotation("bench.traced"):
             for i in range(4):
-                time.sleep(0.01)  # the generator waits between ticks
+                time.sleep(0.05)  # the generator waits between ticks
                 eng.submit(rng.integers(0, 256, 20), max_new_tokens=3)
                 eng.step()
             while eng.scheduler.active or eng.scheduler.queue:
                 eng.step()
             for _ in range(2):  # idle spins: nothing live or queued
                 eng.step()
-    # the readers load the run's capture from the harness's trace directory
+    # the harness loads the capture from its trace directory, once, and
+    # hands it to the readers
     monkeypatch.setattr(run, "TRACE_DIR", tmp_path)
-    ctx = {"trace": {}}  # the harness's reduction: a capture was taken
+    ctx = {phases.CTX_KEY: run.load_capture()}
     trace, w0, w1 = phases.traced(ctx)
-    assert phases.traced(ctx)[0] is trace  # loaded once per run
+    assert phases.traced(ctx)[0] is trace
     waits = [(s, e, n) for s, e, n in trace["host"]
              if n.startswith("exec.") and n.endswith(".wait")]
     assert waits

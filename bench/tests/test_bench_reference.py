@@ -16,7 +16,7 @@ import run
 import weights
 from repro.api import Engine
 from repro.serving.request import Request
-from smoke_cell import smoke_cell
+from smoke_cell import CONFIGS, smoke_cell
 
 
 def test_ring_deaths_by_hand():
@@ -30,7 +30,7 @@ def test_ring_deaths_by_hand():
     assert dd[0, 0].tolist() == [3, 2, 4, 9, 9, 0, 0, 0]
 
 
-@pytest.mark.parametrize("config", ["granite-3-2b", "qwen1.5-110b"])
+@pytest.mark.parametrize("config", CONFIGS)
 def test_reference_matches_the_paged_engine(config):
     cell = smoke_cell(config, "saturated", dtype="float32", decode_margin=4)
     m, seed = cell.model, 1234

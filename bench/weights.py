@@ -27,10 +27,14 @@ same key tree (``layer_weights``, ``embed_table``, ``head_table``,
 ``final_norm``).  It imports nothing of the program; a test checks that the
 two paths give identical arrays.
 
+A layer's own draw is its family's (``families/<family>.py``
+``base_layer``); this module keeps ``init_params``' key tree, the tables
+outside the layers, the seeded additions and the folding.
+
 ``m`` is the cell's model dict (``cells.model_dict``): ``n_layers``,
-``d_model``, ``n_heads``, ``n_kv_heads``, ``head_dim``, ``d_ff``,
-``vocab_size``, ``padded_vocab``, ``qkv_bias``, ``tie_embeddings`` and the
-four multipliers (``cells.MULTIPLIERS``).
+``d_model``, ``n_heads``, ``n_kv_heads``, ``head_dim``, ``vocab_size``,
+``padded_vocab``, ``qkv_bias``, ``tie_embeddings``, the four multipliers
+(``cells.MULTIPLIERS``), ``family`` and the family's own keys.
 """
 from __future__ import annotations
 
@@ -38,6 +42,8 @@ import math
 
 import jax
 import jax.numpy as jnp
+
+import families
 
 NORM_SCALE = 0.1  # std of the added RMSNorm scales (applied as 1 + scale)
 BIAS_SCALE = 0.5  # std of the added q/k/v biases
@@ -55,32 +61,6 @@ def root_key(seed: int) -> jax.Array:
 
 def _layer_key(m: dict, key: jax.Array, i: int) -> jax.Array:
     return jax.random.split(key, m["n_layers"] + 4)[2 + i]
-
-
-def base_layer(m: dict, key: jax.Array, i: int, dtype) -> dict:
-    """Layer ``i`` exactly as ``init_params`` draws it (dense family)."""
-    D, Hq, Hkv, Dh, F = (m["d_model"], m["n_heads"], m["n_kv_heads"],
-                         m["head_dim"], m["d_ff"])
-    k8 = jax.random.split(_layer_key(m, key, i), 8)
-    ka = jax.random.split(k8[0], 4)
-    s = 1.0 / math.sqrt(D)
-    w = {
-        "ln1": jnp.zeros((D,), dtype), "ln2": jnp.zeros((D,), dtype),
-        "wq": (jax.random.normal(ka[0], (D, Hq, Dh)) * s).astype(dtype),
-        "wk": (jax.random.normal(ka[1], (D, Hkv, Dh)) * s).astype(dtype),
-        "wv": (jax.random.normal(ka[2], (D, Hkv, Dh)) * s).astype(dtype),
-        "wo": (jax.random.normal(ka[3], (Hq, Dh, D))
-               * (1.0 / math.sqrt(Hq * Dh))).astype(dtype),
-    }
-    if m["qkv_bias"]:
-        w["bq"] = jnp.zeros((Hq, Dh), dtype)
-        w["bk"] = jnp.zeros((Hkv, Dh), dtype)
-        w["bv"] = jnp.zeros((Hkv, Dh), dtype)
-    k1, k2, k3 = jax.random.split(k8[3], 3)
-    w["w1"] = (jax.random.normal(k1, (D, F)) / math.sqrt(D)).astype(dtype)
-    w["w3"] = (jax.random.normal(k2, (D, F)) / math.sqrt(D)).astype(dtype)
-    w["w2"] = (jax.random.normal(k3, (F, D)) / math.sqrt(F)).astype(dtype)
-    return w
 
 
 def _layer_extras(m: dict, key: jax.Array, i: int, dtype) -> dict:
@@ -103,7 +83,7 @@ def _layer_extras(m: dict, key: jax.Array, i: int, dtype) -> dict:
 
 def layer_weights(m: dict, key: jax.Array, i: int, dtype) -> dict:
     """Layer ``i`` as the benchmark serves it."""
-    w = base_layer(m, key, i, dtype)
+    w = families.of(m).base_layer(m, _layer_key(m, key, i), i, dtype)
     w.update(_layer_extras(m, key, i, dtype))
     return w
 
@@ -146,7 +126,8 @@ def multiplier_factors(m: dict) -> tuple:
     """(q, r, z): how the served weights differ from the checkpoint of a
     family with multipliers — q and its bias times ``q``, the output and
     down projections times ``r``, the final norm's scale divided by ``z``
-    (all 1 for a plain decoder)."""
+    (all 1 for a plain decoder; which weights are q's and which are a
+    branch's output is the family's ``FOLDED``)."""
     return (m["attention_multiplier"] * math.sqrt(m["head_dim"]),
             m["residual_multiplier"] / m["embedding_multiplier"],
             m["logits_scaling"])
@@ -155,13 +136,13 @@ def multiplier_factors(m: dict) -> tuple:
 def checkpoint_layer(m: dict, key: jax.Array, i: int, dtype) -> dict:
     """Layer ``i`` of the seed's checkpoint, in float32, as the published
     decoder with its multipliers takes it: the served layer with the
-    folding undone."""
-    q, r, _ = multiplier_factors(m)
+    folding undone on the weights its family names (``FOLDED``)."""
+    factor = dict(zip("qr", multiplier_factors(m)[:2]))
     w = {k: v.astype(jnp.float32)
          for k, v in layer_weights(m, key, i, dtype).items()}
-    for name, c in (("wq", q), ("bq", q), ("wo", r), ("w2", r)):
+    for name, c in families.of(m).FOLDED.items():
         if name in w:
-            w[name] = w[name] / c
+            w[name] = w[name] / factor[c]
     return w
 
 
