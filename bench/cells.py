@@ -24,16 +24,6 @@ import families
 BENCH = Path(__file__).resolve().parent
 ROOT = BENCH.parent
 
-# published multipliers some families add to the plain decoder (Granite);
-# a configuration without them runs the plain one.  ``None`` is
-# 1 / sqrt(head_dim).
-MULTIPLIERS = {
-    "embedding_multiplier": 1.0,
-    "attention_multiplier": None,
-    "residual_multiplier": 1.0,
-    "logits_scaling": 1.0,
-}
-
 
 @dataclass
 class Cell:
@@ -71,7 +61,8 @@ def model_dict(config: dict) -> dict:
     """What ``weights``/``counts``/``reference`` and the family read: the
     configuration's sizes under the model-dict keys (a list, such as a
     per-layer pattern, as a tuple: the reference's jitted layers take the
-    dict as a static argument), the multipliers, the padded vocabulary and
+    dict as a static argument), the multipliers (published, or derived by
+    the family from the sizes: ``multipliers``), the padded vocabulary and
     the family's name."""
     keys = model_keys(config)
     missing = sorted(set(keys) - set(config))
@@ -81,21 +72,36 @@ def model_dict(config: dict) -> dict:
     for key, name in keys.items():
         v = config[key]
         m[name] = tuple(v) if isinstance(v, list) else v
-    for key, default in MULTIPLIERS.items():
+    for key, default in families.MULTIPLIERS.items():
         m[key] = float(config.get(key, default or m["head_dim"] ** -0.5))
+    fam = family_of(config)
+    derived = fam.multipliers(m)
+    unknown = sorted(set(derived) - set(families.MULTIPLIERS))
+    if unknown:
+        raise families.FamilyError(
+            f"{fam.__file__}: multipliers() derives {unknown}, which are not "
+            f"among {sorted(families.MULTIPLIERS)}")
+    twice = sorted(set(derived) & set(config))
+    if twice:
+        raise families.FamilyError(
+            f"{fam.__file__}: multipliers() derives {twice}, which the "
+            f"configuration states as well")
+    m.update({k: float(v) for k, v in derived.items()})
     m["padded_vocab"] = -(-m["vocab_size"] // 128) * 128
     m["family"] = family_name(config)
     return m
 
 
 def program_model(m: dict) -> dict:
-    """The sizes the program runs.  It has no multipliers: it serves them
-    folded into its weights (``weights.checkpoint_layer`` undoes the folding
-    for the reference) and carries the residual stream divided by the
-    embedding multiplier, so RMSNorm's eps shrinks by its square and the
-    function is unchanged."""
+    """The sizes the program runs.  It serves the multipliers its family
+    does not list under ``PROGRAM_APPLIES`` folded into its weights
+    (``weights.checkpoint_layer`` undoes the folding for the reference);
+    with the embedding multiplier folded it carries the residual stream
+    divided by it, so RMSNorm's eps shrinks by its square and the function
+    is unchanged."""
     p = dict(m)
-    p["rms_eps"] = m["rms_eps"] / m["embedding_multiplier"] ** 2
+    if "embedding_multiplier" not in families.of(m).PROGRAM_APPLIES:
+        p["rms_eps"] = m["rms_eps"] / m["embedding_multiplier"] ** 2
     return p
 
 

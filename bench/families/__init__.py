@@ -22,20 +22,50 @@ A family module supplies:
 - ``base_layer(m, key, i, dtype)``: layer ``i`` exactly as the program's
   ``init_params`` draws it, ``key`` being that layer's key in
   ``init_params``' tree.  ``weights.py`` keeps the tree, the embedding,
-  head and final-norm tables, the seeded norm scales and q/k/v biases it
-  adds (``perturb``), and the multiplier folding.
+  head and final-norm tables, the seeded extras it adds (``perturb``), and
+  the multiplier folding.  ``i`` is a Python int everywhere, the
+  reference's jitted loader included, so the draw may branch on it.
+- ``extras(m, i)``: the vectors of layer ``i`` that ``init_params`` leaves
+  at a constant and the benchmark seeds instead (``weights.perturb`` and
+  the reference alike), each name mapped to ``(shape, std)``: the seeded
+  value is ``std`` times a standard normal draw.  The family chooses each
+  std for what the vector does (dense: ``weights.NORM_SCALE`` for an
+  RMSNorm scale, applied as ``1 + scale``; 0.5 for a q/k/v bias).  Each
+  name must be a weight of the program's layer ``i``, of that shape; the
+  order fixes which seeded key draws it.  A layer without the vector (a
+  router bias on a dense layer) leaves it out: ``i`` is a Python int, as
+  for ``base_layer``.
+- ``multipliers(m)``: the multipliers (names in ``MULTIPLIERS``) that the
+  family derives from the model dict rather than reads as published keys,
+  such as an embedding scale of sqrt(hidden size) that a flag switches on;
+  ``cells.model_dict`` sets them once the widths are in the dict, so they
+  follow a cut of the widths (the smoke size).  A configuration may not
+  state one of them as well.
+- ``PROGRAM_APPLIES``: a tuple of the names in ``MULTIPLIERS`` that the
+  program applies itself.  The harness folds the others into the served
+  weights, as a checkpoint converter does (``weights.py``,
+  ``cells.program_model``); one the program applies is not folded, since
+  it would then be applied twice.  The reference applies every multiplier
+  as published either way.
 - ``FOLDED``: the layer weights that carry a folded multiplier, each mapped
   to ``"q"`` (scaled with q: times ``attention_multiplier · sqrt(head_dim)``)
   or ``"r"`` (a residual branch's output: times ``residual_multiplier /
   embedding_multiplier``); ``weights.checkpoint_layer`` undoes them.
-- ``prompt_layer(lw, h, keep, *, m, comp, fp8)`` and ``decode_layer(lw, h,
-  T, kp, vp, sel, keep, death_p, death_d, *, m, fp8)``: the reference's
-  per-layer functions, as ``reference.logits`` calls them (``m`` and
-  ``comp`` as sorted item tuples, static under ``jax.jit``).  The pieces a
-  family reuses unchanged are in ``ref_layers.py``; which of them a layer
-  takes (a window mask on some layers, say) is the family's choice.
+- ``layer_kind(m, i)``: a hashable value that says how layer ``i``
+  differs from the others (its attention window, whether it routes), the
+  same for layers of one kind.  ``reference.logits`` hands it to the layer
+  functions below as their static ``kind``, so layers of one kind share
+  one compile.
+- ``prompt_layer(lw, h, keep, *, m, comp, fp8, kind)`` and
+  ``decode_layer(lw, h, T, kp, vp, sel, keep, death_p, death_d, *, m, fp8,
+  kind)``: the reference's per-layer functions, as ``reference.logits``
+  calls them (``m`` and ``comp`` as sorted item tuples, static under
+  ``jax.jit``, like ``kind``).  The pieces a family reuses unchanged are in
+  ``ref_layers.py``, with a sliding window and RoPE as arguments; which of
+  them a layer takes is the family's choice, by its ``kind``.
 - ``FP8_WEIGHTS``: the layer weights the fp8 control rounds, each mapped to
-  the axes of its absmax scale.
+  the axes of its absmax scale (a layer without one of them, a dense layer
+  beside MoE ones, is rounded in those it has).
 - ``layer_matmul_params(m)``: weights one token multiplies through in the
   layer stack (every layer; experts only as many as a token is routed to).
   ``counts`` adds the unembedding.
@@ -68,8 +98,18 @@ SHARED_KEYS = {
     "tie_word_embeddings": "tie_embeddings",
     "qkv_bias": "qkv_bias",
 }
-FUNCTIONS = ("base_layer", "prompt_layer", "decode_layer",
-             "layer_matmul_params", "prefill_attn_flops", "decode_attn_flops")
+# published multipliers some families add to the plain decoder (Granite);
+# a configuration without them runs the plain one.  ``None`` is
+# 1 / sqrt(head_dim).
+MULTIPLIERS = {
+    "embedding_multiplier": 1.0,
+    "attention_multiplier": None,
+    "residual_multiplier": 1.0,
+    "logits_scaling": 1.0,
+}
+FUNCTIONS = ("base_layer", "extras", "multipliers", "layer_kind",
+             "prompt_layer", "decode_layer", "layer_matmul_params",
+             "prefill_attn_flops", "decode_attn_flops")
 TABLES = ("MODEL_KEYS", "PROGRAM_FIELDS", "SMOKE", "FOLDED", "FP8_WEIGHTS")
 
 _loaded = {}  # path -> module: a family's jitted layers compile once
@@ -126,3 +166,9 @@ def check(mod, path) -> None:
     folds = sorted(set(mod.FOLDED.values()) - {"q", "r"})
     if folds:
         bad(f"FOLDED maps to {folds}; only 'q' and 'r' are folded")
+    if not isinstance(getattr(mod, "PROGRAM_APPLIES", None), tuple):
+        bad("lacks the tuple PROGRAM_APPLIES")
+    unknown = sorted(set(mod.PROGRAM_APPLIES) - set(MULTIPLIERS))
+    if unknown:
+        bad(f"PROGRAM_APPLIES names {unknown}, which are not among the "
+            f"multipliers {sorted(MULTIPLIERS)}")

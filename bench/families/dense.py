@@ -1,6 +1,7 @@
 """The dense family: a GQA decoder with optional q/k/v biases and a SwiGLU
-MLP of width ``d_ff`` in every layer (Qwen1.5, Granite; Granite's published
-multipliers are folded into the served weights, ``weights.py``).
+MLP of width ``d_ff`` in every layer, all layers of one kind (Qwen1.5,
+Granite; Granite's published multipliers are folded into the served
+weights, ``weights.py``, as the program applies none of them).
 
 The family contract is in ``families/__init__.py``.
 """
@@ -15,6 +16,7 @@ import numpy as np
 
 from ref_layers import (causal_attention, decode_attention, headkv_select,
                         mm, qkv, rms)
+from weights import NORM_SCALE
 
 MODEL_KEYS = {"intermediate_size": "d_ff"}
 PROGRAM_FIELDS = {"d_ff": "d_ff"}
@@ -22,6 +24,8 @@ SMOKE = {"num_hidden_layers": 2, "hidden_size": 64,
          "num_attention_heads": 4, "num_key_value_heads": 2,
          "head_dim": 16, "intermediate_size": 128, "vocab_size": 256}
 FOLDED = {"wq": "q", "bq": "q", "wo": "r", "w2": "r"}
+PROGRAM_APPLIES = ()
+BIAS_SCALE = 0.5  # std of the seeded q/k/v biases
 FP8_WEIGHTS = {"wq": 0, "wk": 0, "wv": 0, "wo": (0, 1),
                "w1": 0, "w3": 0, "w2": 0}
 
@@ -55,7 +59,28 @@ def base_layer(m: dict, key: jax.Array, i: int, dtype) -> dict:
     return w
 
 
+def extras(m: dict, i: int) -> dict:
+    """Both norms, and the q/k/v biases where the model has them."""
+    D, Hq, Hkv, Dh = (m["d_model"], m["n_heads"], m["n_kv_heads"],
+                      m["head_dim"])
+    out = {"ln1": ((D,), NORM_SCALE), "ln2": ((D,), NORM_SCALE)}
+    if m["qkv_bias"]:
+        out.update(bq=((Hq, Dh), BIAS_SCALE), bk=((Hkv, Dh), BIAS_SCALE),
+                   bv=((Hkv, Dh), BIAS_SCALE))
+    return out
+
+
+def multipliers(m: dict) -> dict:
+    """None: Granite publishes its multipliers as keys."""
+    return {}
+
+
 # ---- reference layers ------------------------------------------------------
+
+
+def layer_kind(m: dict, i: int) -> str:
+    """Every layer is a full-attention SwiGLU layer."""
+    return "full"
 
 
 def _mlp(lw, h, m, fp8):
@@ -66,8 +91,8 @@ def _mlp(lw, h, m, fp8):
                                              "nf,fd->nd", fp8)
 
 
-@partial(jax.jit, static_argnames=("m", "comp", "fp8"))
-def prompt_layer(lw, h, keep, *, m, comp, fp8):
+@partial(jax.jit, static_argnames=("m", "comp", "fp8", "kind"))
+def prompt_layer(lw, h, keep, *, m, comp, fp8, kind):
     """One layer over a whole prompt.  Returns (h, k, v, sel): k/v the
     post-RoPE keys and values (T, Hkv, Dh), sel (Hkv, Kcap) each head's
     kept positions in ascending order, padded with T."""
@@ -81,8 +106,9 @@ def prompt_layer(lw, h, keep, *, m, comp, fp8):
     return _mlp(lw, h, m, fp8), k, v, sel
 
 
-@partial(jax.jit, static_argnames=("m", "fp8"))
-def decode_layer(lw, h, T, kp, vp, sel, keep, death_p, death_d, *, m, fp8):
+@partial(jax.jit, static_argnames=("m", "fp8", "kind"))
+def decode_layer(lw, h, T, kp, vp, sel, keep, death_p, death_d, *, m, fp8,
+                 kind):
     """One layer over a request's decode tokens (n padded queries at
     positions T, T+1, ...), each seeing the kept prompt entries and the
     decode entries its cache held at that step."""

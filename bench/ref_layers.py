@@ -9,6 +9,15 @@ over the kept prompt entries plus the generated tokens, with the cache's
 recency ring (``reference.ring_deaths``).  ``fp8`` rounds a matmul's
 activations to float8_e4m3 (the control, ``reference.py``).
 
+A sliding-window layer (``window`` > 0; 0 is full attention) follows what
+the program does on one: a query at position p attends to the keys at
+positions above ``p − window`` (and at most p); HeadKV's selection masks
+the pooled scores of positions below ``T − window`` before it adds the
+sinks and the observation window, so the sinks are still kept on such a
+layer (the guarantee overrides the mask), and the positions it then keeps
+from outside the window are never attended, since every decode query sits
+at T or later.
+
 ``m`` and ``comp`` are the cell's model dict and compression settings
 (``cells.py``), as plain dicts.
 """
@@ -57,13 +66,16 @@ def rope(x, pos, theta):
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
 
 
-def qkv(lw, x, pos, m, fp8):
-    """Post-RoPE q (n, Hq, Dh), k and v (n, Hkv, Dh) of ``x`` at ``pos``."""
+def qkv(lw, x, pos, m, fp8, rotate=True):
+    """q (n, Hq, Dh), k and v (n, Hkv, Dh) of ``x`` at ``pos``; q and k
+    rotated by RoPE unless ``rotate`` is off."""
     q = mm(x, lw["wq"], "nd,dhx->nhx", fp8)
     k = mm(x, lw["wk"], "nd,dhx->nhx", fp8)
     v = mm(x, lw["wv"], "nd,dhx->nhx", fp8)
     if m["qkv_bias"]:
         q, k, v = q + lw["bq"], k + lw["bk"], v + lw["bv"]
+    if not rotate:
+        return q, k, v
     return rope(q, pos, m["rope_theta"]), rope(k, pos, m["rope_theta"]), v
 
 
@@ -77,9 +89,10 @@ def pool(scores, width):
     return jnp.stack([p[:, i:i + T] for i in range(width)]).max(axis=0)
 
 
-def causal_attention(q, k, v, m):
+def causal_attention(q, k, v, m, window=0):
     """Causal attention of a whole prompt, a block of queries at a time:
-    q (T, Hq, Dh), k/v (T, Hkv, Dh) -> (T, Hq, Dh)."""
+    q (T, Hq, Dh), k/v (T, Hkv, Dh) -> (T, Hq, Dh); ``window`` > 0 limits
+    each query to that many positions, its own included."""
     T, Hq, Dh = q.shape
     Hkv = k.shape[1]
     G = Hq // Hkv
@@ -94,7 +107,10 @@ def causal_attention(q, k, v, m):
         s = jnp.einsum("chgd,thd->hgct", qi, k,
                        precision=HI) * m["attention_multiplier"]
         qpos = i * c + jnp.arange(c)
-        s = jnp.where(pos[None, :] <= qpos[:, None], s, NEG)
+        seen = pos[None, :] <= qpos[:, None]
+        if window > 0:
+            seen &= pos[None, :] > qpos[:, None] - window
+        s = jnp.where(seen, s, NEG)
         p = jax.nn.softmax(s, axis=-1)
         return jnp.einsum("hgct,thd->chgd", p, v, precision=HI)
 
@@ -102,11 +118,12 @@ def causal_attention(q, k, v, m):
                                                             Dh)[:T]
 
 
-def headkv_select(q, k, keep, m, comp):
+def headkv_select(q, k, keep, m, comp, window=0):
     """HeadKV's selection from the observation window's scores: SnapKV
     observation scores (the softmax of the last ``obs_window`` queries over
     the prompt, summed over the window and the query group, max-pooled by
-    ``pool``), sinks and the observation window always kept, each head
+    ``pool``; on a ``window`` layer those of positions below T − window
+    masked), sinks and the observation window always kept, each head
     keeping its ``keep`` top positions (``counts.headkv_keep``).  Returns
     (Hkv, Kcap) kept positions in ascending order, padded with T."""
     T, Hq, Dh = q.shape
@@ -119,6 +136,8 @@ def headkv_select(q, k, keep, m, comp):
                    precision=HI) * m["attention_multiplier"]
     s = jnp.where(pos[None, :] <= pos[T - W:][:, None], s, NEG)
     scores = pool(jax.nn.softmax(s, axis=-1).sum(axis=(1, 2)), comp["pool"])
+    if window > 0:
+        scores = jnp.where(pos[None, :] >= T - window, scores, -jnp.inf)
     must = (pos < comp["sink"]) | (pos >= T - comp["obs_window"])
     scores = jnp.where(must[None, :], jnp.inf, scores)
     kcap = min(static_capacity(comp), T)
@@ -127,12 +146,15 @@ def headkv_select(q, k, keep, m, comp):
     return jnp.sort(idx, axis=-1)
 
 
-def decode_attention(q, k, v, kp, vp, sel, keep, death_p, death_d, m):
+def decode_attention(q, k, v, kp, vp, sel, keep, death_p, death_d, m,
+                     window=0):
     """Attention of a request's decode queries q (n, Hq, Dh), with their own
     keys and values k/v (n, Hkv, Dh), over the kept prompt entries (``kp``,
     ``vp`` the prompt's keys and values, ``sel`` and ``keep`` the selection)
     and the decode entries the cache held at each step (``death_*``: the
-    first query no longer seeing an entry) -> (n, Hq, Dh)."""
+    first query no longer seeing an entry) -> (n, Hq, Dh).  Query i sits
+    at position T + i (T the prompt's length); ``window`` > 0 hides the
+    entries at or below its position − window."""
     n, Hq, Dh = q.shape
     Hkv = k.shape[1]
     G = Hq // Hkv
@@ -148,6 +170,10 @@ def decode_attention(q, k, v, kp, vp, sel, keep, death_p, death_d, m):
     sd = jnp.einsum("nhgd,mhd->hgnm", qg, k, precision=HI)
     dm = ((qi[None, None, :] <= qi[None, :, None])
           & (qi[None, :, None] < death_d[:, None, :]))  # (Hkv, n, n)
+    if window > 0:
+        T = kp.shape[0]
+        pm &= sel[:, None, :] > T + qi[None, :, None] - window
+        dm &= qi[None, None, :] > qi[None, :, None] - window
     s = jnp.concatenate([sp, sd], axis=-1) * m["attention_multiplier"]
     mask = jnp.concatenate([pm, dm], axis=-1)[:, None]
     s = jnp.where(mask, s, NEG)

@@ -2,12 +2,14 @@
 
 It follows the published decoder the cell runs: an embedding, the layers
 of its family (``families/<family>.py``: ``prompt_layer``/``decode_layer``,
-built from the shared pieces of ``ref_layers.py``), a final RMSNorm and an
-unembedding (tied or not), with the multipliers of the families that
-publish them (Granite): the embeddings times ``embedding_multiplier``,
-attention scores times ``attention_multiplier`` (1/sqrt(head_dim) where
-none is published), each residual branch times ``residual_multiplier``,
-the logits divided by ``logits_scaling``.
+built from the shared pieces of ``ref_layers.py``, each layer told its
+``layer_kind``), a final RMSNorm and an unembedding (tied or not), with
+the multipliers of the families that publish them (Granite), whether the
+program folds them into its weights or applies them itself: the
+embeddings times ``embedding_multiplier``, attention scores times
+``attention_multiplier`` (1/sqrt(head_dim) where none is published), each
+residual branch times ``residual_multiplier``, the logits divided by
+``logits_scaling``.
 
 On top of that it applies what the served path does to the cache, so that
 its logits are those of the same computation, exactly:
@@ -95,14 +97,17 @@ def ring_deaths(keep: np.ndarray, n_dec: int, d0: int, capacity: int,
 
 
 def _load_layer(m, key, dtype, fp8):
-    """Rebuild layer i in float32 (or fp8-rounded) on the device."""
+    """Rebuild layer i in float32 (or fp8-rounded) on the device.  ``i``
+    is static: a family's ``base_layer`` and ``extras`` may branch on it
+    (a router's bias on its MoE layers alone)."""
     def fn(key, i):
         w = checkpoint_layer(m, key, i, dtype)
         if fp8:
             for name, axes in families.of(m).FP8_WEIGHTS.items():
-                w[name] = fp8_round(w[name], axes)
+                if name in w:
+                    w[name] = fp8_round(w[name], axes)
         return w
-    return jax.jit(fn)
+    return jax.jit(fn, static_argnums=1)
 
 
 def logits(m: dict, comp: dict, imp: np.ndarray, seed_key, dtype,
@@ -134,15 +139,16 @@ def logits(m: dict, comp: dict, imp: np.ndarray, seed_key, dtype,
     load = _load_layer(m, seed_key, dtype, fp8)
     for i in range(L):
         lw = load(seed_key, i)
+        kind = fam.layer_kind(m, i)
         for st in state:
             keep_i = jnp.asarray(st["keep"][i])
             hp, kp, vp, sel = fam.prompt_layer(lw, st["hp"], keep_i, m=mk,
-                                               comp=ck, fp8=fp8)
+                                               comp=ck, fp8=fp8, kind=kind)
             kcap = sel.shape[1]
             st["hd"] = fam.decode_layer(
                 lw, st["hd"], st["T"], kp, vp, sel, keep_i,
                 jnp.asarray(st["death_p"][i][:, :kcap]),
-                jnp.asarray(st["death_d"][i]), m=mk, fp8=fp8)
+                jnp.asarray(st["death_d"][i]), m=mk, fp8=fp8, kind=kind)
             st["hp"] = hp
         del lw
     fnorm = checkpoint_final_norm(m, seed_key, dtype)

@@ -4,19 +4,17 @@ The engine serves in float32 here so that the two must agree to rounding:
 prefill logits, HeadKV's per-head selection and decode through the paged
 cache, including the recency ring (a small decode margin makes heavy heads
 reach capacity and wrap)."""
-import dataclasses
+import json
 
+import jax
 import numpy as np
 import pytest
 
-import cells
-import counts
+import ref_digest
 import reference
-import run
-import weights
-from repro.api import Engine
-from repro.serving.request import Request
-from smoke_cell import CONFIGS, smoke_cell
+from ref_layers import qkv
+from smoke_cell import (CONFIGS, GAP_LIMIT, LOGIT_ATOL, against_reference,
+                        agrees, serve_paged, smoke_cell, widest)
 
 
 def test_ring_deaths_by_hand():
@@ -33,43 +31,57 @@ def test_ring_deaths_by_hand():
 @pytest.mark.parametrize("config", CONFIGS)
 def test_reference_matches_the_paged_engine(config):
     cell = smoke_cell(config, "saturated", dtype="float32", decode_margin=4)
-    m, seed = cell.model, 1234
-    imp = counts.importance(m["n_layers"], m["n_kv_heads"],
-                            cell.traffic["importance"])
-    params = run.make_params(cells.model_config(cell), m, seed, "float32")
-    ecfg = cells.engine_config(cell, run.pool_blocks(cell, imp))
-    ecfg = ecfg.replace(scheduler=dataclasses.replace(ecfg.scheduler,
-                                                      collect_logits=True))
-    eng = Engine.build(ecfg, params=params,
-                       profile=run.plan_profile(cell, imp),
-                       head_importance=imp)
-    rng = np.random.default_rng(0)
-    reqs = [Request(req_id=i, prompt=rng.integers(0, 256, T).astype(np.int32),
-                    max_new_tokens=n)
-            for i, (T, n) in enumerate([(48, 30), (64, 12), (57, 25)])]
-    d0 = {}
-    for r in reqs[:2]:
-        eng.submit(r)
-    step = 0
-    while any(not r.is_finished for r in reqs):
-        if step == 3:
-            eng.submit(reqs[2])  # admitted mid-stream, at another phase
-        ev = eng.step()
-        for rid, _ in ev["admitted"]:
-            d0[rid] = int(eng.scheduler.state.decode_steps) - 1
-        step += 1
-    served = [reference.Served(prompt=r.prompt,
-                               tokens=np.asarray(r.generated, np.int32),
-                               d0=d0[r.req_id]) for r in reqs]
-    import jax.numpy as jnp
-    ref = reference.logits(m, cell.compression, imp, weights.root_key(seed),
-                           jnp.float32, served)
-    cap = counts.static_capacity(cell.compression)
-    wrapped = False
-    for r, lg in zip(reqs, ref):
-        got = np.stack(r.logits)[:, :m["vocab_size"]]
-        np.testing.assert_allclose(got, np.asarray(lg), atol=2e-4, rtol=0)
-        keep = counts.headkv_keep(imp, cell.compression, len(r.prompt))
-        wrapped |= bool((keep + len(r.generated) - 1 > cap).any())
-        assert reference.served_gap(lg, r.generated).max() < 1e-4
-    assert wrapped, "no head reached capacity: the ring went untested"
+    reqs, served = serve_paged(cell, 1234)
+    a = against_reference(cell.model, cell, 1234, reqs, served)
+    assert a["logit_diff"] <= LOGIT_ATOL, a
+    assert a["gap"] < GAP_LIMIT, a
+    assert a["wrapped"], "no head reached capacity: the ring went untested"
+
+
+def test_a_logit_that_is_not_finite_never_agrees():
+    assert widest([0.5, 1e-6]) == 0.5
+    assert widest([1e-6, np.nan]) == np.inf
+    assert not agrees({"logit_diff": widest([0.0, np.nan]), "gap": 0.0})
+    assert not agrees({"logit_diff": 0.0, "gap": widest([np.inf])})
+
+
+def test_qkv_without_rotation_is_the_projection_at_position_zero():
+    """RoPE is the identity at position 0, so ``rotate=False`` must give at
+    every position what the rotated projection gives there; at others the
+    rotation moves q and k and leaves v."""
+    ks = jax.random.split(jax.random.PRNGKey(3), 7)
+    D, Hq, Hkv, Dh, n = 32, 4, 2, 8, 6
+    lw = {"wq": jax.random.normal(ks[0], (D, Hq, Dh)),
+          "wk": jax.random.normal(ks[1], (D, Hkv, Dh)),
+          "wv": jax.random.normal(ks[2], (D, Hkv, Dh)),
+          "bq": jax.random.normal(ks[3], (Hq, Dh)),
+          "bk": jax.random.normal(ks[4], (Hkv, Dh)),
+          "bv": jax.random.normal(ks[5], (Hkv, Dh))}
+    x = jax.random.normal(ks[6], (n, D))
+    m = {"qkv_bias": True, "rope_theta": 10000.0}
+    pos = np.arange(n, dtype=np.int32) + 100
+    plain = qkv(lw, x, pos, m, False, rotate=False)
+    at_zero = qkv(lw, x, np.zeros(n, np.int32), m, False)
+    turned = qkv(lw, x, pos, m, False)
+    for a, b in zip(plain, at_zero):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    for a, b in zip(plain[:2], turned[:2]):
+        assert np.abs(np.asarray(a) - np.asarray(b)).max() > 0.1
+    np.testing.assert_array_equal(np.asarray(plain[2]), np.asarray(turned[2]))
+
+
+def test_ref_digest_prints_the_same_fingerprints_twice(capsys):
+    """The tool that compares two trees runs at smoke size and repeats
+    itself: the same seed gives the same JSON."""
+    args = ["--config", "qwen1.5-110b", "--seed", str(2 ** 31 + 11),
+            "--dtypes", "float32"]
+    outs = []
+    for _ in range(2):
+        assert ref_digest.main(args) == 0
+        outs.append(capsys.readouterr().out.strip().splitlines()[-1])
+    assert outs[0] == outs[1]
+    got = json.loads(outs[0])
+    assert got["cell"] == "qwen1.5-110b"
+    assert set(got["float32"]) == {"served_weights", "logits_fp32",
+                                   "gap_fp32", "logits_fp8", "gap_fp8"}
+    assert got["float32"]["logits_fp32"] != got["float32"]["logits_fp8"]

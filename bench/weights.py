@@ -2,9 +2,10 @@
 
 The served model's weights come from the program's own initializer
 (``init_params``), which draws every matrix from one PRNG key tree.  Its
-RMSNorm scales and QKV biases start at zero, which would leave the norm
-scale and the bias path untested, so the benchmark adds seeded values to
-them (``perturb``).
+RMSNorm scales and biases start at a constant (zero), which would leave the
+norm scales and the bias paths untested, so the benchmark adds seeded
+values to them (``perturb``): to the vectors its family names for each
+layer (``extras``), and to the final norm.
 
 A family whose published decoder scales the embeddings, the attention
 scores, the residual branches and the logits (Granite) is served by the
@@ -14,7 +15,9 @@ a checkpoint converter does: q and its bias times ``attention_multiplier
 ``residual_multiplier / embedding_multiplier`` (the residual stream then
 runs divided by the embedding multiplier, and RMSNorm's eps by its square,
 ``cells.program_model``), the final norm's scale divided by
-``logits_scaling``.  The seed's checkpoint of such a family is drawn at the
+``logits_scaling``.  A multiplier the program applies itself (its family's
+``PROGRAM_APPLIES``: gemma2's embedding scale) is left out of the folding,
+as its factor 1.  The seed's checkpoint of such a family is drawn at the
 scales where its folded form is what ``init_params`` draws
 (``checkpoint_layer``): at the plain decoder's scales, Granite's 1/64 on
 the attention scores and 0.22 on the branches leave attention near uniform
@@ -34,7 +37,7 @@ outside the layers, the seeded additions and the folding.
 ``m`` is the cell's model dict (``cells.model_dict``): ``n_layers``,
 ``d_model``, ``n_heads``, ``n_kv_heads``, ``head_dim``, ``vocab_size``,
 ``padded_vocab``, ``qkv_bias``, ``tie_embeddings``, the four multipliers
-(``cells.MULTIPLIERS``), ``family`` and the family's own keys.
+(``families.MULTIPLIERS``), ``family`` and the family's own keys.
 """
 from __future__ import annotations
 
@@ -46,7 +49,6 @@ import jax.numpy as jnp
 import families
 
 NORM_SCALE = 0.1  # std of the added RMSNorm scales (applied as 1 + scale)
-BIAS_SCALE = 0.5  # std of the added q/k/v biases
 _PERTURB_TAG = 1_000_003  # fold-in base of the perturbation keys
 
 
@@ -64,28 +66,32 @@ def _layer_key(m: dict, key: jax.Array, i: int) -> jax.Array:
 
 
 def _layer_extras(m: dict, key: jax.Array, i: int, dtype) -> dict:
-    """The benchmark's seeded norm scales and biases of layer ``i``."""
-    D, Hq, Hkv, Dh = m["d_model"], m["n_heads"], m["n_kv_heads"], m["head_dim"]
-    k5 = jax.random.split(jax.random.fold_in(key, _PERTURB_TAG + i), 5)
-    out = {
-        "ln1": (NORM_SCALE * jax.random.normal(k5[0], (D,))).astype(dtype),
-        "ln2": (NORM_SCALE * jax.random.normal(k5[1], (D,))).astype(dtype),
-    }
-    if m["qkv_bias"]:
-        out["bq"] = (BIAS_SCALE * jax.random.normal(k5[2], (Hq, Dh))
-                     ).astype(dtype)
-        out["bk"] = (BIAS_SCALE * jax.random.normal(k5[3], (Hkv, Dh))
-                     ).astype(dtype)
-        out["bv"] = (BIAS_SCALE * jax.random.normal(k5[4], (Hkv, Dh))
-                     ).astype(dtype)
-    return out
+    """The benchmark's seeded values of the extras its family names for
+    layer ``i``: the j-th name drawn from the j-th key of one split of the
+    layer's perturbation key (split's j-th key does not depend on how many
+    are drawn)."""
+    spec = families.of(m).extras(m, i)
+    keys = jax.random.split(jax.random.fold_in(key, _PERTURB_TAG + i),
+                            len(spec))
+    return {name: (std * jax.random.normal(k, tuple(shape))).astype(dtype)
+            for k, (name, (shape, std)) in zip(keys, spec.items())}
+
+
+def _add_extras(layer: dict, extras: dict, i: int) -> dict:
+    """``layer`` with its extras replaced by their seeded values; an extra
+    the layer does not have, or has at another shape, is an error."""
+    for name, v in extras.items():
+        if name not in layer or layer[name].shape != v.shape:
+            raise ValueError(
+                f"layer {i} has no weight {name} of shape {v.shape} to seed "
+                f"(its family's extras name it)")
+    return {**layer, **extras}
 
 
 def layer_weights(m: dict, key: jax.Array, i: int, dtype) -> dict:
     """Layer ``i`` as the benchmark serves it."""
     w = families.of(m).base_layer(m, _layer_key(m, key, i), i, dtype)
-    w.update(_layer_extras(m, key, i, dtype))
-    return w
+    return _add_extras(w, _layer_extras(m, key, i, dtype), i)
 
 
 def embed_table(m: dict, key: jax.Array, dtype) -> jax.Array:
@@ -109,17 +115,12 @@ def final_norm(m: dict, key: jax.Array, dtype) -> jax.Array:
 
 
 def perturb(params: dict, m: dict, key: jax.Array, dtype) -> dict:
-    """Add the seeded norm scales and biases to ``init_params`` output."""
+    """Put the seeded final norm and extras into ``init_params`` output."""
     out = dict(params)
     out["final_norm"] = final_norm(m, key, dtype)
-    layers = []
-    for i, pl in enumerate(params["layers"]):
-        pl = dict(pl)
-        pl.update(_layer_extras(m, key, i, dtype))
-        layers.append(pl)
-    out["layers"] = layers
+    out["layers"] = [_add_extras(pl, _layer_extras(m, key, i, dtype), i)
+                     for i, pl in enumerate(params["layers"])]
     return out
-
 
 
 def multiplier_factors(m: dict) -> tuple:
@@ -127,10 +128,14 @@ def multiplier_factors(m: dict) -> tuple:
     family with multipliers — q and its bias times ``q``, the output and
     down projections times ``r``, the final norm's scale divided by ``z``
     (all 1 for a plain decoder; which weights are q's and which are a
-    branch's output is the family's ``FOLDED``)."""
-    return (m["attention_multiplier"] * math.sqrt(m["head_dim"]),
-            m["residual_multiplier"] / m["embedding_multiplier"],
-            m["logits_scaling"])
+    branch's output is the family's ``FOLDED``).  A multiplier the program
+    applies itself (``PROGRAM_APPLIES``) counts as 1."""
+    applied = families.of(m).PROGRAM_APPLIES
+    f = {k: 1.0 if k in applied else m[k] for k in families.MULTIPLIERS}
+    q = (1.0 if "attention_multiplier" in applied
+         else f["attention_multiplier"] * math.sqrt(m["head_dim"]))
+    return (q, f["residual_multiplier"] / f["embedding_multiplier"],
+            f["logits_scaling"])
 
 
 def checkpoint_layer(m: dict, key: jax.Array, i: int, dtype) -> dict:
