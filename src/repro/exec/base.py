@@ -49,6 +49,7 @@ import numpy as np
 from repro.api.registry import get_executor
 from repro.compression.base import CompressionConfig
 from repro.configs.base import ModelConfig
+from repro.kernels import ops as K
 from repro.obs import NULL_OBS
 
 # the StepFn kind table: every compiled step an executor owns is one of
@@ -300,6 +301,18 @@ class Executor:
                  "device completion)",
         ).observe(dt, kind=kind, executor=self.name)
         return out
+
+    def _count_prefill_attn(self) -> None:
+        """One ``prefill_attn_total{impl=}`` increment per prefill StepFn
+        call, labelled with the prompt attention that call runs
+        (``kernels.ops.prefill_attention_impl``)."""
+        if self.cfg.attention_free:
+            return
+        self.obs.metrics.counter(
+            "prefill_attn_total",
+            help="prefill StepFn calls by prompt-attention implementation "
+                 "(pallas = the splash kernel of kernels/prefill_flash.py)",
+        ).inc(impl=K.prefill_attention_impl())
 
     # ---- shared normalization ---------------------------------------------
 

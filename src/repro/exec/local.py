@@ -113,6 +113,7 @@ class LocalExecutor(Executor):
             rows = jnp.arange(B, dtype=jnp.int32)
         hi = None if head_importance is None else jnp.asarray(head_importance)
         args = (sp, batch, pa, jnp.asarray(rows, jnp.int32), hi)
+        self._count_prefill_attn()
         if not self.obs.enabled:
             return self._prefill_jit(*args)
         return self._observe_step("prefill", self._prefill_jit, args)

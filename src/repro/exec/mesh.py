@@ -225,6 +225,7 @@ class MeshExecutor(Executor):
             self._prefill_jits[key] = self._build_prefill(
                 sp_specs, state_specs, hi is not None)
         args = (sp, {"tokens": tokens}, pa, rows, hi)
+        self._count_prefill_attn()
         if self.obs.enabled:
             state, logits, lengths = self._observe_step(
                 "prefill", self._prefill_jits[key], args)

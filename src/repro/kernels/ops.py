@@ -15,6 +15,10 @@
 - "jnp"       pure-jnp oracle (`ref.paged_fairkv_decode_ref`)
 - "auto"      pallas on TPU, jnp elsewhere
 
+Prefill attention (`prefill_attention`) takes no knob: the flash kernel
+(`kernels/prefill_flash.py`) on TPU, ``ref.prefill_attention_ref`` (the
+model's ``attention``) elsewhere.
+
 ``REPRO_PALLAS_INTERPRET=1`` forces every "auto" dispatch onto the Pallas
 kernels in interpret mode off-TPU — the CI ``kernels-interpret`` gate uses
 it so kernel regressions fail in a named job instead of hiding behind the
@@ -135,6 +139,24 @@ def paged_fairkv_decode(q, k_pool, v_pool, pos_pool, block_table, lengths,
         q, k_pool, v_pool, pos_pool, block_table, lengths, capacity,
         attn_cap=attn_cap, q_pos=q_pos, window=window, interpret=ipret,
         k_scale=k_scale, v_scale=v_scale, kinds=kinds, q_lens=q_lens)
+
+
+def prefill_attention_impl() -> str:
+    """The implementation ``prefill_attention`` runs: "pallas" on TPU (or
+    forced by ``REPRO_PALLAS_INTERPRET``), "jnp" elsewhere.  Executors
+    label ``prefill_attn_total`` with it per prefill call."""
+    return "pallas" if _use_pallas("auto") else "jnp"
+
+
+def prefill_attention(q, k, v, window: int = 0, attn_cap: float = 0.0):
+    """Causal attention of a whole prompt at positions ``arange(T)`` (see
+    ref.prefill_attention_ref): q (B, T, Hq, Dh), k/v (B, T, Hkv, Dh) →
+    (B, T, Hq, Dh)."""
+    if prefill_attention_impl() == "jnp":
+        return _ref.prefill_attention_ref(q, k, v, window, attn_cap)
+    from repro.kernels.prefill_flash import prefill_flash
+    return prefill_flash(q, k, v, window=window, attn_cap=attn_cap,
+                         interpret=not _on_tpu())
 
 
 def snapkv_scores(q_obs, k, obs_positions, k_positions, attn_cap: float = 0.0,

@@ -195,6 +195,22 @@ def snapkv_scores_ref(
     return p.sum(axis=(2, 3))  # (B, Hkv, T)
 
 
+def prefill_attention_ref(
+    q: jnp.ndarray,  # (B, T, Hq, Dh) prompt queries (RoPE'd)
+    k: jnp.ndarray,  # (B, T, Hkv, Dh)
+    v: jnp.ndarray,  # (B, T, Hkv, Dh)
+    window: int = 0,
+    attn_cap: float = 0.0,
+) -> jnp.ndarray:
+    """Causal prefill attention at positions ``arange(T)`` → (B, T, Hq, Dh):
+    the model's ``attention`` (fp32; dense up to 2048 keys, the chunked
+    flash scan above, so memory stays O(T) at any prompt length)."""
+    from repro.models.layers import attention
+    B, T = q.shape[:2]
+    pos = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32), (B, T))
+    return attention(q, k, v, pos, pos, window=window, attn_cap=attn_cap)
+
+
 def ssd_chunk_ref(x, dt, A_log, B_, C_, D_, chunk=64):
     """Oracle for the SSD chunk kernel — delegates to the model implementation
     (itself validated against a naive sequential scan in tests)."""

@@ -272,7 +272,10 @@ def first_weights(pl: dict, plan: PlanArrays, layer_idx: int,
 
 def _prefill_attention(pl, hn, positions, cfg, layer_idx, cache, plan, ccfg,
                        W, head_importance, rows=None, model_axis=None):
-    """Full attention + compression + slot-cache fill for one layer."""
+    """Full attention + compression + slot-cache fill for one layer.
+
+    The attention is ``kernels.ops.prefill_attention`` for every prompt
+    length (positions are ``arange(T)``)."""
     B, T, D = hn.shape
     Hkv, G, Dh = cfg.n_kv_heads, cfg.q_per_kv, cfg.head_dim
     fw = first_weights(pl, plan, layer_idx, model_axis)
@@ -286,9 +289,9 @@ def _prefill_attention(pl, hn, positions, cfg, layer_idx, cache, plan, ccfg,
     q = q.reshape(B, T, Hkv * G, Dh)
     q = L.apply_rope(q, positions, cfg.rope_theta)
     k = L.apply_rope(k, positions, cfg.rope_theta)
-    out = L.attention(q, k, v, positions, positions,
-                      window=M.layer_window(cfg, layer_idx),
-                      attn_cap=cfg.attn_softcap, causal=True)
+    window = M.layer_window(cfg, layer_idx)
+    out = K.prefill_attention(q, k, v, window=window,
+                              attn_cap=cfg.attn_softcap)
     out_flat = out.reshape(B, T, Hkv * G * Dh)
 
     # --- compression ---------------------------------------------------------
@@ -297,7 +300,6 @@ def _prefill_attention(pl, hn, positions, cfg, layer_idx, cache, plan, ccfg,
                              attn_cap=cfg.attn_softcap)
     from repro.compression.base import pool_scores
     scores = pool_scores(scores, ccfg.pool)
-    window = M.layer_window(cfg, layer_idx)
     if window > 0:
         # sliding-window layers never need positions older than the window
         pos = jnp.arange(T)
