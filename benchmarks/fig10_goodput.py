@@ -26,8 +26,7 @@ contract: the engine's Prometheus export must carry the per-tenant
 families.
 
 Acceptance (``REPRO_BENCH_SMOKE=0``): SLO-aware goodput/step strictly
-beats FCFS (gate ``goodput_gain > 1.0``; the committed run in
-``BENCH_pr7.json`` records the realized margin).
+beats FCFS (gate ``goodput_gain > 1.0``).  A CPU correctness figure.
 """
 from __future__ import annotations
 

@@ -22,8 +22,8 @@ run the same trace on warmed engines (compile cost paid before the
 measured window).
 
 Acceptance (``REPRO_BENCH_SMOKE=0``): ``capacity_gain >= 2.0`` (Arm A) and
-``p99 TTFT chunked < monolithic`` for the short cohort (Arm B); the
-committed run in ``BENCH_pr8.json`` records the realized margins.
+``p99 TTFT chunked < monolithic`` for the short cohort (Arm B).  A CPU
+correctness figure.
 """
 from __future__ import annotations
 

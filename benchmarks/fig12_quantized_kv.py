@@ -22,15 +22,15 @@ Three measurements, all against the fp32 storage baseline:
 
 3. **Equal-HBM max batch (fig7 extension)** — rerun fig7's analytic sweep
    with the pool sized in *bytes* instead of fp32 blocks: at the byte
-   budget the slot cache spends on BATCH fp32 rows, the int8 pool admits
-   ~4x the blocks, so the sustainable batch beats the committed
-   ``BENCH_pr3.json`` paged numbers at every compression ratio.  Gate:
-   int8 batch > fp32 batch for every ratio.
+   budget a dense fp32 cache spends on BATCH rows, the int8 pool admits
+   ~4x the blocks, so the sustainable batch beats the fp32 paged pool's
+   at every compression ratio.  Gate: int8 batch > fp32 batch for every
+   ratio.
 
 ``REPRO_BENCH_SMOKE=1`` shrinks the sweep for CI; the gates still run.
 
-Returns a metrics dict (recorded by ``run.py``; the PR-9 committed copy
-lives in ``BENCH_pr9.json``).
+A CPU correctness figure; returns a metrics dict (recorded by
+``run.py``).
 """
 from __future__ import annotations
 
@@ -143,7 +143,7 @@ def equal_hbm_batch(ratio: float) -> dict:
     S = plan.n_shards * plan.slots_per_shard
     cap_blocks = blocks_for_tokens(int(round(alpha_max * budget)),
                                    BLOCK_SIZE)
-    # equal HBM: the bytes the fp32 slot cache spends on BATCH rows
+    # equal HBM: the bytes a dense fp32 cache spends on BATCH rows
     fp32_block = block_hbm_bytes(BLOCK_SIZE, DH, jnp.float32, False)
     int8_block = block_hbm_bytes(BLOCK_SIZE, DH, jnp.int8, True)
     hbm_bytes = N_LAYERS * S * BATCH * cap_blocks * fp32_block

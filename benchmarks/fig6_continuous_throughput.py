@@ -7,7 +7,7 @@ model, and reports end-to-end tokens/s plus p50/p99 request latency (in
 scheduler steps and wall seconds).
 
 This measures the *system* path the paper's 1.66× claim lives on: sustained
-multi-request load against the slot cache, not a single fixed batch.  On CPU
+multi-request load against the paged cache, not a single fixed batch.  On CPU
 the absolute tok/s is compile-dominated; the latency-step percentiles and the
 sha-vs-fairkv comparison are the meaningful outputs.
 """
@@ -98,7 +98,7 @@ def main():
             / results["fairkv_dp"]["wall_s"]) / (
         results["sha"]["generated_tokens"] / results["sha"]["wall_s"])
     print(f"fig6/gain_dp_over_sha,0,gain={gain:.3f}")
-    return {  # machine-readable summary for BENCH_pr3.json
+    return {  # machine-readable summary (run.py records it)
         planner: {
             "tokens_per_s": r["generated_tokens"] / r["wall_s"],
             "p50_steps": r["pct"]["p50_steps"],

@@ -1,31 +1,34 @@
-"""Figure 7 (repo extension): paged vs slot cache memory at equal HBM.
+"""Figure 7 (repo extension): paged vs dense cache memory at equal HBM.
 
-The slot cache pads every (slot, row) to the static capacity ``C``; the
-paged backend (DESIGN.md §9) allocates fixed-size blocks proportional to
-each (slot, row)'s *realized* retained length.  The waste the paged backend
-recovers is largest exactly when compression is most imbalanced — the
-Ada-SnapKV regime FairKV targets — and it converts directly into batch
-capacity and throughput.
+A dense slot-layout cache pads every (slot, row) to the static capacity
+``C`` — ``L·S·B·C·Dh`` entries per K and V; the paged cache (DESIGN.md §9)
+allocates fixed-size blocks proportional to each (slot, row)'s *realized*
+retained length.  The waste paging recovers is largest exactly when
+compression is most imbalanced — the Ada-SnapKV regime FairKV targets —
+and it converts directly into batch capacity and throughput.
 
 Two measurements:
 
 1. **Analytic max sustainable batch** — run the real Ada-SnapKV selection
    (`benchmarks.common.realized_lengths`) across compression ratios, place
    heads with the fairkv_dp planner, and count how many request rows fit in
-   the HBM the slot cache spends on a reference batch.  The per-row paged
+   the HBM a dense cache spends on a reference batch.  The per-row paged
    cost honors block rounding and the one-block-per-owned-head floor, so
    the gain is what the allocator would actually realize.
 
-2. **System throughput** — drive the real continuous-batching engine (slot
-   vs paged at an equal cache-byte budget, paged getting the freed bytes
-   back as extra decode rows) over one Poisson trace and report end-to-end
-   tokens/s and preemptions.  CPU wall times are compile-dominated; the
-   comparison and the admission/preemption telemetry are the signal.
+2. **System throughput** — drive the real continuous-batching engine over
+   one Poisson trace in two arms at one cache-byte budget, the dense
+   footprint of a reference batch (its formula): the *dense* arm serves
+   that batch on a pool of the dense worst case (every row can fill its
+   capacity, so it admits on free rows alone, as a dense cache does); the
+   *paged* arm gets the same bytes as blocks and twice the decode rows.
+   Report end-to-end tokens/s and preemptions.  CPU wall times are
+   compile-dominated; the comparison and the admission/preemption
+   telemetry are the signal.
 
-``REPRO_BENCH_SMOKE=1`` shrinks the sweep for CI.
-
-Returns a metrics dict (recorded by ``run.py`` — ``BENCH.json`` by
-default; the PR-3-era committed copy lives in ``BENCH_pr3.json``).
+``REPRO_BENCH_SMOKE=1`` shrinks the sweep for CI.  A CPU correctness
+figure.  Returns a metrics dict (recorded by ``run.py`` in ``BENCH.json``
+by default).
 """
 from __future__ import annotations
 
@@ -59,14 +62,14 @@ N_LAYERS = 4 if SMOKE else 8
 N_HEADS = 8
 N_SHARDS = 4
 T = 2048 if SMOKE else 8192
-BATCH = 8  # reference rows the slot cache budget is sized for
+BATCH = 8  # reference rows the dense cache budget is sized for
 BLOCK_SIZE = 16
 RATIOS = [0.02, 0.08] if SMOKE else [0.01, 0.02, 0.05, 0.10, 0.20]
 HEAD_SKEW = 1.0  # Ada-SnapKV-style imbalanced profile
 
 # system run: smoke engine, identical trace for both arms
 ARCH = "minitron-8b"
-SYS_ROWS_SLOT = 2
+SYS_ROWS_DENSE = 2
 SYS_GEN = 6
 SYS_REQUESTS = 6
 SYS_BUDGET = 12
@@ -92,7 +95,7 @@ def paged_row_blocks(lengths_lhb: np.ndarray, plan, block_size: int
 
 
 def analytic_max_batch(ratio: float) -> dict:
-    """Max sustainable batch at equal HBM, slot vs paged, one ratio."""
+    """Max sustainable batch at equal HBM, dense vs paged, one ratio."""
     budget = max(8, int(round(ratio * T)))
     alpha_max = 4.0
     lengths = realized_lengths(N_LAYERS, N_HEADS, budget, BATCH, T=T,
@@ -104,7 +107,7 @@ def analytic_max_batch(ratio: float) -> dict:
     S = plan.n_shards * plan.slots_per_shard
     cap = int(round(alpha_max * budget))
     cap_blocks = blocks_for_tokens(cap, BLOCK_SIZE)
-    # equal HBM budget: the bytes the slot cache spends on BATCH rows,
+    # equal HBM budget: the bytes a dense cache spends on BATCH rows,
     # in block units (C rounded up to whole blocks on both sides)
     hbm_blocks = N_LAYERS * S * BATCH * cap_blocks
     row_blocks = paged_row_blocks(lengths, plan, BLOCK_SIZE)
@@ -113,15 +116,15 @@ def analytic_max_batch(ratio: float) -> dict:
     return {
         "budget": budget,
         "ratio": budget / T,
-        "slot_batch": BATCH,
+        "dense_batch": BATCH,
         "paged_batch": paged_batch,
         "gain": paged_batch / BATCH,
         "mean_row_blocks": mean_row,
-        "slot_row_blocks": N_LAYERS * S * cap_blocks,
+        "dense_row_blocks": N_LAYERS * S * cap_blocks,
     }
 
 
-def system_run(backend: str, rows: int, n_blocks: int, params=None):
+def system_run(rows: int, n_blocks: int, params=None):
     cfg = EngineConfig.smoke(
         ARCH, n_shards=4, max_seq_len=24 + SYS_GEN + 8,
         compression=CompressionConfig(policy="ada_snapkv", budget=SYS_BUDGET,
@@ -130,7 +133,6 @@ def system_run(backend: str, rows: int, n_blocks: int, params=None):
         planner=PlannerConfig(mode="fairkv_dp", extra_copies=4,
                               batch_cap=rows),
         scheduler=SchedulerConfig(max_rows=rows, enable_replan=False),
-        cache_backend=backend,
         paging=PagingConfig(block_size=8, n_blocks=n_blocks))
     eng = Engine.build(cfg, params=params)
     eng.warmup()
@@ -154,7 +156,7 @@ def main():
         metrics["analytic"].append(r)
         print(f"fig7/max_batch/ratio_{r['ratio']:.3f},"
               f"{(time.time() - t0) * 1e6:.0f},"
-              f"slot_batch={r['slot_batch']};paged_batch={r['paged_batch']};"
+              f"dense_batch={r['dense_batch']};paged_batch={r['paged_batch']};"
               f"gain={r['gain']:.2f}")
     gains = [r["gain"] for r in metrics["analytic"]]
     metrics["min_gain"] = min(gains)
@@ -162,8 +164,9 @@ def main():
     print(f"fig7/max_batch_gain,0,min={min(gains):.2f};max={max(gains):.2f}")
 
     # --- system run: equal cache bytes, paged gets the bytes back as rows ----
-    # slot arm cache bytes/layer: S * ROWS * C; paged pool sized to match
-    # (n_blocks-1 usable blocks of BLOCK bs tokens), decode width doubled.
+    # the dense footprint of SYS_ROWS_DENSE rows: L * S * ROWS * C * Dh per
+    # K and V; the paged pool holds the same bytes as blocks of 8 tokens
+    # (C rounded up to whole blocks), decode width doubled.
     base = EngineConfig.smoke(ARCH)
     params = init_params(base.model, jax.random.PRNGKey(base.seed),
                          dtype=jnp.float32, max_seq_len=24 + SYS_GEN + 8)
@@ -173,13 +176,17 @@ def main():
     cap = ccfg.static_capacity()
     # untimed warmup arm (fig6 pattern): populate the op-dispatch/compile
     # caches so neither timed arm pays the one-time tracing cost
-    system_run("slot", SYS_ROWS_SLOT, 0, params=params)
-    eng_s, out_s = system_run("slot", SYS_ROWS_SLOT, 0, params=params)
-    S = eng_s.plan.n_shards * eng_s.plan.slots_per_shard
-    equal_blocks = S * SYS_ROWS_SLOT * blocks_for_tokens(cap, 8) + 1
-    eng_p, out_p = system_run("paged", 2 * SYS_ROWS_SLOT, equal_blocks,
+    system_run(SYS_ROWS_DENSE, 0, params=params)
+    eng_d, out_d = system_run(SYS_ROWS_DENSE, 0, params=params)
+    m = base.model
+    S = eng_d.plan.n_shards * eng_d.plan.slots_per_shard
+    dense_bytes = (2 * m.n_layers * S * SYS_ROWS_DENSE * cap * m.head_dim
+                   * jnp.dtype(jnp.float32).itemsize)
+    metrics["system"]["dense_cache_bytes"] = dense_bytes
+    equal_blocks = S * SYS_ROWS_DENSE * blocks_for_tokens(cap, 8) + 1
+    eng_p, out_p = system_run(2 * SYS_ROWS_DENSE, equal_blocks,
                               params=params)
-    for name, out in (("slot", out_s), ("paged", out_p)):
+    for name, out in (("dense", out_d), ("paged", out_p)):
         tps = out["generated_tokens"] / out["wall_s"]
         tpstep = out["generated_tokens"] / out["steps"]
         metrics["system"][name] = {
@@ -197,12 +204,12 @@ def main():
     # grows with batch width — on the HBM-bound accelerator decode path,
     # per-step cost tracks Σ retained lengths, which is equal here.)
     step_gain = (metrics["system"]["paged"]["tokens_per_step"]
-                 / metrics["system"]["slot"]["tokens_per_step"])
+                 / metrics["system"]["dense"]["tokens_per_step"])
     tps_gain = (metrics["system"]["paged"]["tokens_per_s"]
-                / metrics["system"]["slot"]["tokens_per_s"])
+                / metrics["system"]["dense"]["tokens_per_s"])
     metrics["system"]["tokens_per_step_gain"] = step_gain
     metrics["system"]["tokens_per_s_gain"] = tps_gain
-    print(f"fig7/system/gain_paged_over_slot,0,"
+    print(f"fig7/system/gain_paged_over_dense,0,"
           f"tokens_per_step_gain={step_gain:.3f};"
           f"wall_tokens_per_s_gain={tps_gain:.3f}")
     return metrics

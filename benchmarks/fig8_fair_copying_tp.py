@@ -3,10 +3,11 @@
 The paper's headline claim — replicating memory-hot heads (Fair-Copying)
 lifts multi-GPU decode throughput over plain tensor parallelism — measured
 on the *system*, not the simulator: both arms drive the continuous-batching
-engine through the `repro.api` facade with **per-model-shard admission**
-(``SchedulerConfig.max_live_tokens_per_shard``, DESIGN.md §10).  Admission
-is gated by the bottleneck shard, exactly as on a real mesh where one
-device's memory is the resource that runs out:
+scheduler over a paged pool split into **one partition per model shard**
+(the mesh executor's layout, DESIGN.md §10), each partition sized by this
+script's arithmetic.  A shard's partition running dry stalls admission or
+preempts, exactly as on a real mesh where one device's memory is the
+resource that runs out:
 
 The workload is HeadKV with a skewed per-head importance vector — the
 BaKlaVa-style (arXiv:2502.13176) per-head budget allocation that makes TP
@@ -15,21 +16,22 @@ the KV of the cold ones.
 
 - **plain TP** — SHA placement, single copy per head
   (``fill_empty_slots=False``): the heads the compression policy keeps
-  long pile their KV onto whichever shard holds them, that shard's budget
-  saturates first, and admission stalls with free rows still idle.
+  long pile their KV blocks onto whichever shard holds them, that shard's
+  partition runs dry first, and requests stall or replay with free rows
+  still idle.
 - **Fair-Copying** — ``fairkv_dp`` with extra copies on the same slot
   grid and the same measured profile: heavy heads are replicated, replicas
-  split rows, per-shard live load flattens, and the same budget sustains
-  more concurrent requests.
+  split rows, per-shard live load flattens, and the same partitions
+  sustain more concurrent requests.
 
 Both arms run the identical Poisson trace on identical weights; the
 recorded signal is **tokens per scheduler step** (concurrency the budget
 sustains) plus the analytic device-time gain (max-shard load ratio on the
 measured profile, the fig3-style Eq. 4/5 number) across 2/4/8 shards.
 
-``REPRO_BENCH_SMOKE=1`` trims the shard sweep for CI.
-Returns a metrics dict (recorded by ``run.py`` — ``BENCH.json`` by
-default; the PR-4-era committed copy lives in ``BENCH_pr4.json``).
+``REPRO_BENCH_SMOKE=1`` trims the shard sweep for CI.  A CPU correctness
+figure: tokens per step, not a device time.  Returns a metrics dict
+(recorded by ``run.py`` in ``BENCH.json`` by default).
 """
 from __future__ import annotations
 
@@ -43,12 +45,15 @@ from repro.api import (
     CompressionConfig,
     Engine,
     EngineConfig,
+    PagingConfig,
     PlannerConfig,
+    Scheduler,
     SchedulerConfig,
     get_smoke_config,
     init_params,
     synthesize_requests,
 )
+from repro.paging.backend import PagedBackend
 
 SMOKE = os.environ.get("REPRO_BENCH_SMOKE", "") not in ("", "0")
 
@@ -60,7 +65,11 @@ N_REQUESTS = 20
 RATE = 2.0  # arrivals/step: admission-limited, not arrival-limited
 BUDGET = 12  # compression budget (tokens/head)
 HEAD_COLD = 0.1  # importance of the cold heads (hot heads get 1.0)
-HEADROOM = 1.40  # per-shard budget over Fair-Copying's balanced need
+# per-shard partition over Fair-Copying's busiest-shard need: below the
+# plain-TP hot shard's excess over it (28 vs 20 blocks per row here — block
+# rounding narrows the 1/E = 1.54 token ratio)
+HEADROOM = 1.10
+BLOCK = 4  # tokens per KV block
 
 
 def _model():
@@ -85,8 +94,7 @@ def _head_importance(model) -> np.ndarray:
     return np.tile(imp, (model.n_layers, 1))
 
 
-def _config(model, n_shards: int, planner: PlannerConfig,
-            budget_per_shard: int) -> EngineConfig:
+def _config(model, n_shards: int, planner: PlannerConfig) -> EngineConfig:
     return EngineConfig(
         model=model, n_shards=n_shards,
         max_seq_len=PROMPT[1] + GEN + 8,
@@ -94,9 +102,24 @@ def _config(model, n_shards: int, planner: PlannerConfig,
                                       alpha_max=2.0, obs_window=4, sink=2,
                                       decode_margin=GEN),
         planner=planner,
-        scheduler=SchedulerConfig(
-            max_rows=ROWS, enable_replan=False,
-            max_live_tokens_per_shard=budget_per_shard))
+        scheduler=SchedulerConfig(max_rows=ROWS, enable_replan=False))
+
+
+def _partitioned_scheduler(eng: Engine, part_blocks: int) -> Scheduler:
+    """The engine's model, plan and executor over a paged pool of one
+    ``part_blocks``-block partition per model shard, every layer."""
+    cfg = eng.cfg
+    backend = PagedBackend(
+        cfg.model, cfg.compression,
+        paging=PagingConfig(block_size=BLOCK,
+                            n_blocks=cfg.n_shards * part_blocks),
+        pool_partitions=cfg.n_shards)
+    return Scheduler(cfg.model, eng.params, eng.plan, cfg.compression,
+                     cfg.scheduler, planner_cfg=cfg.planner, dtype=eng.dtype,
+                     serve_params=eng.sp, backend=backend,
+                     executor=eng.executor,
+                     head_importance=eng.head_importance,
+                     plan_profile=eng.profile)
 
 
 def _arm_planner(arm: str, n_shards: int, n_heads: int) -> PlannerConfig:
@@ -110,24 +133,45 @@ def _arm_planner(arm: str, n_shards: int, n_heads: int) -> PlannerConfig:
                          slots_per_shard=slots, batch_cap=ROWS)
 
 
+def _row_blocks_per_shard(plan, profile) -> np.ndarray:
+    """(n_shards,) blocks one row pins in each model shard's partition of
+    the worst layer's pool: each head's profile length plus its decode
+    growth, block-rounded, split over the head's replicas (they split the
+    rows)."""
+    out = np.zeros(plan.n_shards)
+    for lyr, lp in enumerate(plan.layers):
+        per = np.zeros(plan.n_shards)
+        for s, h in enumerate(lp.slot_head):
+            if h >= 0:
+                per[s // plan.slots_per_shard] += (
+                    np.ceil((profile[lyr, h] + GEN) / BLOCK)
+                    / lp.replica_count[s])
+        out = np.maximum(out, per)
+    return out
+
+
 def run_shards(model, params, profile, head_imp, n_shards: int) -> dict:
-    # per-shard budget: enough for Fair-Copying to keep ~ROWS rows live
-    # when the load is balanced; the plain-TP hot shard needs ~E⁻¹× more
-    per_row = float(profile.sum())  # mean Σ lengths one row pins
-    budget_per_shard = int(HEADROOM * ROWS * per_row / n_shards)
-    out = {"n_shards": n_shards, "budget_per_shard": budget_per_shard}
-    for arm in ("tp", "fairkv"):
-        cfg = _config(model, n_shards,
-                      _arm_planner(arm, n_shards, model.n_kv_heads),
-                      budget_per_shard)
-        eng = Engine.build(cfg, params=params, profile=profile,
-                           head_importance=head_imp)
-        eng.warmup()
+    engines = {arm: Engine.build(
+        _config(model, n_shards, _arm_planner(arm, n_shards,
+                                              model.n_kv_heads)),
+        params=params, profile=profile, head_importance=head_imp)
+        for arm in ("tp", "fairkv")}
+    # per-shard partition: enough blocks for Fair-Copying's busiest shard
+    # to keep ~ROWS rows live; the plain-TP hot shard needs ~E⁻¹× more
+    need = {arm: _row_blocks_per_shard(eng.plan, profile)
+            for arm, eng in engines.items()}
+    part_blocks = int(HEADROOM * ROWS * need["fairkv"].max()) + 1  # + null
+    out = {"n_shards": n_shards, "partition_blocks": part_blocks,
+           "block_size": BLOCK,
+           "row_blocks_hot_shard": {a: float(n.max())
+                                    for a, n in need.items()}}
+    for arm, eng in engines.items():
+        sched = _partitioned_scheduler(eng, part_blocks)
         reqs = synthesize_requests(N_REQUESTS, RATE, model.vocab_size,
                                    min_prompt=PROMPT[0], max_prompt=PROMPT[1],
                                    max_new_tokens=GEN, seed=11)
         t0 = time.time()
-        trace = eng.run_trace(reqs, max_steps=4000)
+        trace = sched.run(reqs, max_steps=4000)
         wall = time.time() - t0
         assert trace["finished"] == trace["total"], trace
         tps = trace["generated_tokens"] / trace["steps"]
@@ -136,16 +180,17 @@ def run_shards(model, params, profile, head_imp, n_shards: int) -> dict:
             "tokens_per_step": tps,
             "steps": trace["steps"],
             "wall_s": wall,
+            "preemptions": trace["preemptions"],
             "efficiency_E": float(eng.plan.efficiency(profile)),
             "makespan": float(load.max()),
             "replication_overhead": eng.plan.replication_overhead(),
-            # from the consolidated stats snapshot (the scheduler's own
-            # counters), not a re-tally of replan_log — replans are off
-            # here, so both outcomes reading 0 is itself part of the check
-            "replans": eng.stats().scheduler.replans_accepted,
-            "replans_rejected": eng.stats().scheduler.replans_rejected,
+            # replans are off here, so the scheduler's own counter
+            # reading 0 is itself part of the check
+            "replans": sched.n_replans,
         }
-        assert out[arm]["replans"] == trace["replans"], trace
+        assert out[arm]["replans"] == trace["replans"] == 0, trace
+        sched.backend.pool.check_invariants()
+        assert sched.backend.pool.blocks_in_use() == 0
     out["tokens_per_step_gain"] = (out["fairkv"]["tokens_per_step"]
                                    / out["tp"]["tokens_per_step"])
     # fig3-style device-time gain on the same profile: throughput ∝ 1/makespan
@@ -164,7 +209,7 @@ def main():
     head_imp = _head_importance(model)
     # measured (L, H) profile (paper §4.1): both arms plan from the same
     # realized per-head workload, so the comparison is placement-only
-    probe = Engine.build(_config(model, 2, _arm_planner("tp", 2, 8), 10**9),
+    probe = Engine.build(_config(model, 2, _arm_planner("tp", 2, 8)),
                          params=params, head_importance=head_imp)
     rng = np.random.default_rng(5)
     profile = probe.measure_profile(

@@ -1,25 +1,25 @@
-"""Figure 9 (repo extension): paged decode — gather vs native kernel.
+"""Figure 9 (repo extension): paged decode — materialized views vs the
+native kernel, as an analytic HBM-bytes model.
 
-The gather-based paged decode (`kernels/paged_decode.py`) materializes each
-row's blocks into full capacity-sized ``(S, B, C, Dh)`` contiguous views
-every decode step: it reads the allocated blocks, *writes* ``S·B·C``
-columns, and the slot kernel reads them back — slot-cache-scale HBM traffic
-at the hottest point of the stack, no matter how little the compression
-retained.  The native kernel (`kernels/paged_fairkv_decode.py`,
-``impl="pallas"``) consumes the pools and block tables directly, so its
-HBM→VMEM traffic is proportional to the **allocated blocks** — the realized
-retained lengths FairKV balances (DESIGN.md §11).
+A paged decode that gathers each row's blocks into full capacity-sized
+``(S, B, C, Dh)`` contiguous views every step (the design the native kernel
+replaced) reads the allocated blocks, *writes* ``S·B·C`` columns, and a
+dense kernel reads them back — dense-cache-scale HBM traffic at the hottest
+point of the stack, no matter how little the compression retained.  The
+native kernel (`kernels/paged_fairkv_decode.py`, ``impl="pallas"``)
+consumes the pools and block tables directly, so its HBM→VMEM traffic is
+proportional to the **allocated blocks** — the realized retained lengths
+FairKV balances (DESIGN.md §11).
 
-This container has no TPU, so the committed numbers come from an explicit
-HBM-bytes model evaluated on *measured* realized lengths (the real
-Ada-SnapKV selection at paper-like operating points, placed by the
-fairkv_dp planner):
+The numbers are an explicit HBM-bytes model, not a device measurement,
+evaluated on *measured* realized lengths (the real Ada-SnapKV selection at
+paper-like operating points, placed by the fairkv_dp planner):
 
 - ``native_bytes``  = K+V reads of every owned (layer, slot, row)'s
   allocated blocks (one-block floor included).
 - ``gather_bytes``  = the same block reads, plus writing the capacity-sized
-  views, plus the slot kernel re-reading them (unowned (slot, row) pairs
-  pay full capacity too — the gather cannot skip them).
+  views, plus a dense kernel re-reading them (unowned (slot, row) pairs
+  pay full capacity too — a gather cannot skip them).
 
 Modeled decode throughput at a reference HBM bandwidth turns the byte ratio
 into tokens/step-time: ``tokens_per_step_gain = gather_bytes /
@@ -30,11 +30,9 @@ recorded in the metrics dict (`conditions`) and asserted when not smoke.
 
 Ride-alongs keep the model honest on CPU: an interpret-mode parity check of
 the native kernel against ``ref.paged_fairkv_decode_ref`` on a random paged
-layer, and a wall-clock sanity timing of the jnp vs gather dispatch impls.
-
-Returns a metrics dict (recorded by ``run.py`` — ``BENCH.json`` by
-default; the committed ``REPRO_BENCH_SMOKE=0`` run lives in
-``BENCH_pr5.json``).
+layer, and a wall-clock sanity timing of the jnp oracle.  A CPU
+correctness figure; returns a metrics dict (recorded by ``run.py`` in
+``BENCH.json`` by default).
 """
 from __future__ import annotations
 
@@ -69,8 +67,9 @@ DTYPE_BYTES = 2  # bf16 serving dtype
 
 
 def byte_model(ratio: float) -> dict:
-    """gather vs native HBM bytes per decode step at one compression ratio,
-    on realized Ada-SnapKV lengths placed by the fairkv_dp planner."""
+    """materialized-view vs native HBM bytes per decode step at one
+    compression ratio, on realized Ada-SnapKV lengths placed by the
+    fairkv_dp planner."""
     budget = max(8, int(round(ratio * T)))
     lengths = realized_lengths(N_LAYERS, N_HEADS, budget, BATCH, T=T,
                                head_skew=HEAD_SKEW, policy="ada_snapkv",
@@ -175,7 +174,7 @@ def main():
     print(f"fig9/interpret_parity,0,max_err={err:.2e}")
     assert err < 1e-5, err
 
-    wall = {impl: cpu_wall_us(impl) for impl in ("jnp", "gather")}
+    wall = {"jnp": cpu_wall_us("jnp")}
     metrics["cpu_wall_us"] = wall
     print(f"fig9/cpu_wall,0," + ";".join(
         f"{k}={v:.0f}us" for k, v in wall.items()))

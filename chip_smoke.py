@@ -7,13 +7,13 @@ with random weights made from ``--seed``, and checks what comes out.
 ``python chip_smoke.py`` needs one chip and runs three phases in this one
 process:
 
-  (a) ``Engine.generate`` on the slot backend: one prefill of 4 prompts of
-      1024 tokens, then 8 decode steps.
-  (b) ``Engine.run_trace`` on the continuous scheduler with the paged
-      backend, Ada-SnapKV per-head budgets and the FairKV-DP planner:
-      8 requests of 1024 prompt tokens and 32 new tokens each, on 4 rows.
-  (c) the first decode step's logits on the paged backend with the native
-      Pallas kernel against the jnp oracle, on the prompts of (a).
+  (a) ``Engine.generate``: one prefill of 4 prompts of 1024 tokens, then
+      8 decode steps over the paged cache.
+  (b) ``Engine.run_trace`` on the continuous scheduler with Ada-SnapKV
+      per-head budgets and the FairKV-DP planner: 8 requests of 1024
+      prompt tokens and 32 new tokens each, on 4 rows.
+  (c) the first decode step's logits with the native paged Pallas kernel
+      against the jnp oracle, on the prompts of (a).
 
 ``python chip_smoke.py --four-chips`` needs four chips and runs only the
 mesh executor on a (data=1, model=4) mesh: the traffic of (b) once under
@@ -87,8 +87,7 @@ class Setup:
                          "--gen", str(self.oneshot_gen), *extra)
 
     def continuous_args(self, *extra: str) -> argparse.Namespace:
-        return self.args("--continuous", "--cache-backend", "paged",
-                         "--rows", str(self.rows),
+        return self.args("--continuous", "--rows", str(self.rows),
                          "--requests", str(self.requests),
                          "--min-prompt", str(self.prompt),
                          "--max-prompt", str(self.prompt),
@@ -185,7 +184,7 @@ def check_close(what: str, got, ref) -> float:
 
 
 def phase_oneshot(setup: Setup, params) -> None:
-    log("== phase (a): Engine.generate, slot backend")
+    log("== phase (a): Engine.generate")
     args = setup.oneshot_args()
     eng = build(setup, args, params)
     prompts = prompt_batch(setup, eng.cfg.model)
@@ -259,8 +258,7 @@ def run_continuous(setup: Setup, params, *extra: str):
 
 
 def phase_continuous(setup: Setup, params) -> None:
-    log("== phase (b): Engine.run_trace, paged backend, ada_snapkv, "
-        "fairkv_dp")
+    log("== phase (b): Engine.run_trace, ada_snapkv, fairkv_dp")
     eng, _ = run_continuous(setup, params)
     sched, ex = eng.scheduler, eng.executor
     tokens = {"tokens": np.zeros((1, setup.prompt), np.int32)}
@@ -275,8 +273,7 @@ def phase_kernel_parity(setup: Setup, params) -> None:
         "oracle")
     logits = {}
     for impl in ("pallas", "jnp"):
-        args = setup.oneshot_args("--cache-backend", "paged",
-                                  "--paged-impl", impl)
+        args = setup.oneshot_args("--paged-impl", impl)
         eng = build(setup, args, params)
         res = eng.generate(prompt_batch(setup, eng.cfg.model), 1,
                            collect_logits=True)

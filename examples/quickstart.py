@@ -9,12 +9,11 @@ Everything goes through the `repro.api` facade:
 3. `Engine.generate` a batch under each plan,
 4. show that logits are identical (the plan is a layout, not math) while
    the simulated shard balance improves,
-5. print the realized cache-memory footprint of the selected cache
-   backend — with ``--cache-backend paged`` the block pool only pins
-   memory proportional to the realized per-head retained lengths, so the
-   footprint line shows the win over the dense slot cache in one glance.
+5. print the realized cache-memory footprint — the paged block pool only
+   pins memory proportional to the realized per-head retained lengths, so
+   the footprint line shows the win over a dense slot cache in one glance.
 
-Run:  PYTHONPATH=src python examples/quickstart.py [--cache-backend paged]
+Run:  PYTHONPATH=src python examples/quickstart.py
 """
 import argparse
 
@@ -26,7 +25,6 @@ from repro.api import (
     EngineConfig,
     PagingConfig,
     PlannerConfig,
-    list_cache_backends,
 )
 from repro.configs.base import InputShape
 from repro.training.data import SyntheticLM
@@ -39,10 +37,8 @@ T, B, GEN = 96, 2, 8
 
 def main(argv=None):
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
-    ap.add_argument("--cache-backend", default="slot",
-                    help=f"cache backend; registered: {list_cache_backends()}")
     ap.add_argument("--block-size", type=int, default=8,
-                    help="paged backend: tokens per KV block")
+                    help="tokens per KV block")
     args = ap.parse_args(argv)
 
     base_cfg = EngineConfig.smoke(
@@ -51,7 +47,6 @@ def main(argv=None):
                                       alpha_max=2.0, obs_window=8, sink=2,
                                       decode_margin=8),
         planner=PlannerConfig(mode="sha", batch_cap=B),
-        cache_backend=args.cache_backend,
         paging=PagingConfig(block_size=args.block_size))
     data = SyntheticLM(base_cfg.model, InputShape("qs", T, B, "prefill"))
     batch = data.get_batch(0)
@@ -90,18 +85,12 @@ def main(argv=None):
     print(f"balance gain (SHA makespan / FairKV-DP makespan) = {gain:.2f}x")
     assert d < 1e-3
 
-    # --- realized memory footprint of the selected backend ------------------
-    if mem.get("backend") == "paged":
-        slot_eq = mem["slot_equivalent_bytes"]
-        print(f"\ncache footprint [paged]: {mem['cache_bytes']} B in "
-              f"{mem['blocks_in_use']} blocks of {mem['block_size']} tokens "
-              f"vs slot-cache {slot_eq} B "
-              f"({slot_eq / max(1, mem['cache_bytes']):.2f}x saved)")
-    else:
-        print(f"\ncache footprint [slot]: {mem['cache_bytes']} B reserved, "
-              f"{mem['live_tokens']}/{mem['capacity_tokens']} tokens live "
-              f"({100 * mem['utilization']:.0f}% utilized) — rerun with "
-              f"--cache-backend paged to pay only for what is retained")
+    # --- realized memory footprint of the paged cache ------------------------
+    slot_eq = mem["slot_equivalent_bytes"]
+    print(f"\ncache footprint: {mem['cache_bytes']} B in "
+          f"{mem['blocks_in_use']} blocks of {mem['block_size']} tokens "
+          f"vs a dense slot cache's {slot_eq} B "
+          f"({slot_eq / max(1, mem['cache_bytes']):.2f}x saved)")
 
 
 if __name__ == "__main__":

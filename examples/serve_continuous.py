@@ -5,8 +5,8 @@ config: requests queue while the batch is full, get admitted into freed rows
 mid-stream, and — because Ada-SnapKV's per-head budgets are imbalanced — the
 realized per-shard KV load drifts.  The replan trigger is set aggressively so
 the trace demonstrates an online replan: the head placement is rebuilt from
-the *realized* profile, the live cache is migrated into the new slot layout,
-and decoding continues without interruption.  `Engine.stream` yields one
+the *realized* profile, the live paged cache is migrated into the new slot
+layout, and decoding continues without interruption.  `Engine.stream` yields one
 `StreamEvent` per generated token, so the example also shows request-level
 token streaming.
 
@@ -15,7 +15,7 @@ token streaming.
 devices first with ``XLA_FLAGS=--xla_force_host_platform_device_count=8``.
 
 Run:  PYTHONPATH=src JAX_PLATFORMS=cpu python examples/serve_continuous.py \
-          [--cache-backend paged] [--executor mesh [--data 2]]
+          [--executor mesh [--data 2]]
 """
 import argparse
 
@@ -27,7 +27,6 @@ from repro.api import (
     PlannerConfig,
     SchedulerConfig,
     latency_percentiles,
-    list_cache_backends,
     list_executors,
     synthesize_requests,
 )
@@ -40,8 +39,6 @@ GEN = 10
 
 def main(argv=None):
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
-    ap.add_argument("--cache-backend", default="slot",
-                    help=f"cache backend; registered: {list_cache_backends()}")
     ap.add_argument("--block-size", type=int, default=8)
     ap.add_argument("--executor", default="local",
                     help=f"execution strategy; registered: {list_executors()}")
@@ -65,7 +62,6 @@ def main(argv=None):
                               batch_cap=ROWS),
         scheduler=SchedulerConfig(max_rows=ROWS, replan_window=4,
                                   replan_threshold=1.05, replan_cooldown=10),
-        cache_backend=args.cache_backend,
         paging=PagingConfig(block_size=args.block_size),
         executor=args.executor)
     eng = Engine.build(cfg, mesh=mesh)
@@ -99,11 +95,10 @@ def main(argv=None):
               if r.admit_step > first_admit)
     print(f"\np50 {pct['p50_steps']:.0f} / p99 {pct['p99_steps']:.0f} steps | "
           f"{n_tokens} tokens streamed | mid-stream admissions {mid}")
-    mem = eng.memory_stats()
-    if mem.get("backend") == "paged":
-        print(f"paged cache: {mem['blocks_in_use']} blocks in use "
-              f"(pool {mem['pool_bytes']} B) vs slot-equivalent "
-              f"{mem['slot_equivalent_bytes']} B")
+    mem = eng.stats().pool.detail
+    print(f"paged cache: {mem['blocks_in_use']} blocks in use "
+          f"(pool {mem['pool_bytes']} B) vs slot-equivalent "
+          f"{mem['slot_equivalent_bytes']} B")
     if eng.replan_log:
         for ev in eng.replan_log:
             tag = "accepted" if ev["accepted"] else "rejected"

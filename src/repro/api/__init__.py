@@ -29,20 +29,16 @@ from __future__ import annotations
 
 from repro.api.registry import (  # noqa: F401
     ASSIGNMENT_ENGINE_REGISTRY,
-    CACHE_BACKEND_REGISTRY,
     EXECUTOR_REGISTRY,
     POLICY_REGISTRY,
     Registry,
     get_assignment_engine,
-    get_cache_backend,
     get_executor,
     get_policy,
-    list_cache_backends,
     list_engines,
     list_executors,
     list_policies,
     register_assignment_engine,
-    register_cache_backend,
     register_executor,
     register_policy,
 )
@@ -104,8 +100,6 @@ _LAZY = {
     "PoolExhausted": "repro.paging.block_pool:PoolExhausted",
     "BlockPool": "repro.paging.block_pool:BlockPool",
     "PagedCache": "repro.paging.paged_cache:PagedCache",
-    "CacheBackend": "repro.serving.cache_backend:CacheBackend",
-    "make_cache_backend": "repro.serving.cache_backend:make_cache_backend",
     # executor layer (DESIGN.md §10)
     "Executor": "repro.exec.base:Executor",
     "ExecutorConfig": "repro.exec.base:ExecutorConfig",
@@ -126,12 +120,11 @@ _LAZY = {
 }
 
 __all__ = sorted(
-    ["ASSIGNMENT_ENGINE_REGISTRY", "CACHE_BACKEND_REGISTRY",
-     "EXECUTOR_REGISTRY", "POLICY_REGISTRY", "Registry",
-     "get_assignment_engine", "get_cache_backend", "get_executor",
-     "get_policy", "list_cache_backends", "list_engines", "list_executors",
-     "list_policies", "register_assignment_engine", "register_cache_backend",
-     "register_executor", "register_policy", *_LAZY])
+    ["ASSIGNMENT_ENGINE_REGISTRY", "EXECUTOR_REGISTRY", "POLICY_REGISTRY",
+     "Registry", "get_assignment_engine", "get_executor", "get_policy",
+     "list_engines", "list_executors", "list_policies",
+     "register_assignment_engine", "register_executor", "register_policy",
+     *_LAZY])
 
 
 def __getattr__(name: str):

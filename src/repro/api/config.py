@@ -20,12 +20,7 @@ from dataclasses import dataclass, field
 
 import jax.numpy as jnp
 
-from repro.api.registry import (
-    list_cache_backends,
-    list_engines,
-    list_executors,
-    list_policies,
-)
+from repro.api.registry import list_engines, list_executors, list_policies
 from repro.compression.base import CompressionConfig
 from repro.configs import get_config, get_smoke_config
 from repro.configs.base import ModelConfig, MoEConfig, SSMConfig
@@ -63,10 +58,10 @@ class EngineConfig:
     seed: int = 0  # PRNG seed for default parameter init
     profile_skew: float = 1.0
     profile_seed: int = 1
-    # cache storage backend: "slot" (dense static-capacity, DESIGN.md §2) or
-    # "paged" (block-pool allocation proportional to realized lengths, §9);
-    # third parties extend via @repro.api.register_cache_backend
-    cache_backend: str = "slot"
+    # the KV cache is the paged block pool (DESIGN.md §9), sized and tuned
+    # by ``paging``; ``cache_backend`` accepts only "paged" and stays for
+    # callers that still name it
+    cache_backend: str = "paged"
     paging: PagingConfig = field(default_factory=PagingConfig)
     # device-execution strategy (DESIGN.md §10): "local" (single-device jit)
     # or "mesh" (shard_map over a (data, model) mesh, passed to Engine.build
@@ -83,11 +78,11 @@ class EngineConfig:
     frontend: FrontendConfig = field(default_factory=FrontendConfig)
     # shared-prefix block reuse + chunked prefill (DESIGN.md §14): chunking
     # needs chunk_tokens > 0 and a dense-attention model; block sharing
-    # additionally needs the paged backend on a single-partition pool —
-    # the scheduler degrades gracefully when a piece is missing
+    # additionally needs a single-partition pool — the scheduler degrades
+    # gracefully when a piece is missing
     prefix: PrefixConfig = field(default_factory=PrefixConfig)
     # speculative decoding (DESIGN.md §16): draft-propose + multi-query
-    # verify on the paged executor; disabled by default (zero-cost)
+    # verify over the paged cache; disabled by default (zero-cost)
     speculation: SpeculationConfig = field(default_factory=SpeculationConfig)
 
     def __post_init__(self):
@@ -125,25 +120,19 @@ class EngineConfig:
             raise ValueError(
                 f"scheduler.max_rows must be >= 1, got "
                 f"{self.scheduler.max_rows}")
-        if self.cache_backend not in list_cache_backends():
+        if self.cache_backend != "paged":
             raise ValueError(
-                f"unknown cache backend {self.cache_backend!r}; registered: "
-                f"{list_cache_backends()}; add backends with "
-                f"@repro.api.register_cache_backend")
+                f"unknown cache backend {self.cache_backend!r}; the KV "
+                f"cache is the paged block pool, 'paged' (size and tune it "
+                f"through paging=PagingConfig(...))")
         if not isinstance(self.paging, PagingConfig):
             raise TypeError(
                 f"paging must be a PagingConfig, got "
                 f"{type(self.paging).__name__}")
-        # quantized KV pools (DESIGN.md §15) exist only on the paged
-        # backend, and per-head overrides must address real (layer, head)
-        # cells of this model — catch both at construction, not in-trace
+        # quantized KV pools (DESIGN.md §15): per-head overrides must
+        # address real (layer, head) cells of this model — catch it at
+        # construction, not in-trace
         if self.paging.kv_dtype != "fp32":
-            if self.cache_backend != "paged":
-                raise ValueError(
-                    f"paging.kv_dtype={self.paging.kv_dtype!r} (quantized "
-                    f"KV pools) requires cache_backend='paged', got "
-                    f"{self.cache_backend!r}; the slot backend stores KV "
-                    f"in the engine dtype only")
             L, H = self.model.n_layers, self.model.n_kv_heads
             for lyr, hd, dt in self.paging.kv_dtype_overrides:
                 if lyr >= L or hd >= H:
@@ -171,22 +160,11 @@ class EngineConfig:
             raise TypeError(
                 f"prefix must be a PrefixConfig, got "
                 f"{type(self.prefix).__name__}")
-        if self.prefix.enabled and self.cache_backend != "paged":
-            raise ValueError(
-                "prefix.enabled (shared-prefix block reuse) requires "
-                f"cache_backend='paged', got {self.cache_backend!r}; "
-                "chunked prefill alone (prefix.chunk_tokens > 0, "
-                "enabled=False) works on any backend")
         if not isinstance(self.speculation, SpeculationConfig):
             raise TypeError(
                 f"speculation must be a SpeculationConfig, got "
                 f"{type(self.speculation).__name__}")
         if self.speculation.enabled:
-            if self.cache_backend != "paged":
-                raise ValueError(
-                    "speculation.enabled requires cache_backend='paged' "
-                    "(provisional blocks + rollback-on-reject), got "
-                    f"{self.cache_backend!r}")
             if (self.model.family != "dense" or self.model.attention_free
                     or self.model.is_encoder_decoder or self.model.is_vlm):
                 raise ValueError(

@@ -46,8 +46,9 @@ from repro.core.profiles import profile_from_lengths, synthetic_profile
 from repro.exec.base import make_executor
 from repro.models import init_params
 from repro.obs import Obs
+from repro.paging.backend import PagedBackend
+from repro.paging.block_pool import PoolExhausted
 from repro.serving import engine as _serve
-from repro.serving.cache_backend import make_cache_backend
 from repro.serving.request import Request
 from repro.serving.scheduler import Scheduler
 
@@ -126,15 +127,8 @@ class Engine:
                                       paging=cfg.paging, obs=self.obs)
         self.sp = self.executor.shard_params(
             _serve.slotify_params(params, plan, cfg.model))
-        # cache storage backend (DESIGN.md §9): "slot" | "paged" | plugin
-        self.backend = make_cache_backend(
-            cfg.cache_backend, cfg.model, cfg.compression,
-            max_live_tokens=cfg.scheduler.max_live_tokens, paging=cfg.paging,
-            n_shards=cfg.n_shards,
-            max_live_tokens_per_shard=cfg.scheduler.max_live_tokens_per_shard,
-            pool_partitions=self.executor.pool_partitions,
-            row_partitions=self.executor.row_partitions, obs=self.obs,
-            donate_state=cfg.executor_cfg.donate_state)
+        # the paged KV cache (DESIGN.md §9) one-shot mode holds
+        self.backend = self._new_backend()
         self.state: Optional[_serve.ServeState] = None
         self._mode: Optional[str] = None  # "oneshot" | "continuous" (last used)
         # persisted straggler speed factors (set by a speed-aware replan);
@@ -186,6 +180,16 @@ class Engine:
 
     # ---- low-level views ---------------------------------------------------
 
+    def _new_backend(self) -> PagedBackend:
+        """A paged cache over this engine's pool geometry.  Each mode gets
+        its own: the backend carries allocator state (pool + table
+        mirror), and a one-shot `prefill` resets it."""
+        return PagedBackend(
+            self.cfg.model, self.cfg.compression, paging=self.cfg.paging,
+            pool_partitions=self.executor.pool_partitions,
+            row_partitions=self.executor.row_partitions, obs=self.obs,
+            donate_state=self.cfg.executor_cfg.donate_state)
+
     @property
     def plan_arrays(self) -> PlanArrays:
         return self.pa
@@ -208,18 +212,34 @@ class Engine:
 
     # ---- one-shot serving --------------------------------------------------
 
-    def prefill(self, batch: Union[Dict[str, jnp.ndarray], np.ndarray],
-                rows: Optional[jnp.ndarray] = None):
-        """Run the prompt through prefill+compression; holds the resulting
-        cache on ``self.state``.  Returns (logits (B, V), lengths
-        (L, Hkv, B))."""
-        batch = self._as_batch(batch)
-        state, logits, lengths = self.executor.prefill(
-            self.sp, batch, self.pa, rows=rows,
-            head_importance=self.head_importance)
-        self.state = state
-        self._mode = "oneshot"
+    def prefill(self, batch: Union[Dict[str, jnp.ndarray], np.ndarray]):
+        """Run the prompt through prefill+compression and hold the result
+        on ``self.state``, its cache re-housed in the paged pool (blocks
+        proportional to the realized retained lengths).  Returns (logits
+        (B, V), lengths (L, Hkv, B))."""
+        state, logits, lengths = self._run_prefill(batch)
+        self._adopt(state)
         return logits, lengths
+
+    def _run_prefill(self, batch):
+        """The prefill StepFn alone: (slot-layout state, logits,
+        lengths); leaves the engine and its pool untouched."""
+        return self.executor.prefill(self.sp, self._as_batch(batch), self.pa,
+                                     head_importance=self.head_importance)
+
+    def _adopt(self, state) -> None:
+        """Hold a full-batch prefill result, its cache paged into a fresh
+        pool.  One-shot mode has no request queue to preempt into, so an
+        undersized pool is a config error, not a scheduling event — fail
+        with the remedy instead of a raw signal."""
+        try:
+            self.state = self.backend.from_prefill(state, self.pa)
+        except PoolExhausted as e:
+            raise ValueError(
+                f"cache pool too small for one-shot generation ({e}); "
+                f"raise PagingConfig.n_blocks or leave it 0 for "
+                f"worst-case sizing") from e
+        self._mode = "oneshot"
 
     def generate(self, prompts: Union[Dict[str, jnp.ndarray], np.ndarray],
                  max_new_tokens: int,
@@ -234,7 +254,7 @@ class Engine:
         returned ``tokens`` are still the model's argmax choices.
         """
         t0 = time.perf_counter()
-        logits, lengths = self.prefill(prompts)
+        state, logits, lengths = self._run_prefill(prompts)
         jax.block_until_ready(logits)
         prefill_s = time.perf_counter() - t0
         # one-shot TTFT is the prefill wall (no queue to wait in)
@@ -243,19 +263,7 @@ class Engine:
                            "first token on the host (queue wait + "
                            "admission; the prefill alone in one-shot "
                            "mode)").observe(prefill_s)
-        # re-house the prefilled cache in the configured backend's layout
-        # (identity for "slot"; "paged" allocates blocks proportional to the
-        # realized retained lengths).  One-shot mode has no request queue to
-        # preempt into, so an undersized pool is a config error, not a
-        # scheduling event — fail with the remedy instead of a raw signal.
-        from repro.paging.block_pool import PoolExhausted
-        try:
-            self.state = self.backend.from_prefill(self.state, self.pa)
-        except PoolExhausted as e:
-            raise ValueError(
-                f"cache pool too small for one-shot generation ({e}); "
-                f"raise PagingConfig.n_blocks or leave it 0 for "
-                f"worst-case sizing") from e
+        self._adopt(state)
         state = self.state
         tokens = [np.asarray(state.last_tokens)]
         logits_all = [np.asarray(logits)] if collect_logits else None
@@ -306,12 +314,8 @@ class Engine:
         The compression selection is plan-independent, so the measurement is
         valid for planning *any* layout.  Engine state is left untouched.
         """
-        saved = self.state
-        try:
-            _, lengths = self.prefill(batch)
-            return profile_from_lengths(np.asarray(lengths, np.float64))
-        finally:
-            self.state = saved
+        _, _, lengths = self._run_prefill(batch)
+        return profile_from_lengths(np.asarray(lengths, np.float64))
 
     def _as_batch(self, batch) -> Dict[str, jnp.ndarray]:
         if isinstance(batch, dict):
@@ -351,16 +355,9 @@ class Engine:
         self._invalidate()
         migrated = False
         if self.state is not None and self.state.cache is not None:
-            from repro.cache.slot_cache import SlotCache, migrate_cache
-            if isinstance(self.state.cache, SlotCache):
-                # prefill leaves the cache in slot layout regardless of
-                # backend (generate() adopts it later); migrate it in place
-                cache = migrate_cache(self.state.cache, old_pa, self.pa)
-            else:
-                _, commit = self.backend.migrate_cache(self.state.cache,
-                                                       old_pa, self.pa)
-                cache = commit()
-            self.state = dataclasses.replace(self.state, cache=cache)
+            _, commit = self.backend.migrate_cache(self.state.cache, old_pa,
+                                                   self.pa)
+            self.state = dataclasses.replace(self.state, cache=commit())
             migrated = True
         return {"plan": self.plan, "migrated_cache": migrated,
                 "shard_speeds": (None if self._shard_speeds is None
@@ -377,27 +374,15 @@ class Engine:
     def _ensure_scheduler(self) -> Scheduler:
         self._mode = "continuous"
         if self._scheduler is None:
-            # the scheduler gets its OWN backend instance: backends carry
-            # allocator state (pool + table mirror), and a later one-shot
-            # generate() resets the engine's backend — sharing one instance
-            # would silently invalidate the scheduler's live block topology
+            # the scheduler gets its OWN backend instance: a later one-shot
+            # prefill resets the engine's, and sharing one would silently
+            # invalidate the scheduler's live block topology
             self._scheduler = Scheduler(
                 self.cfg.model, self.params, self.plan,
                 self.cfg.compression, self.cfg.scheduler,
                 planner_cfg=self.cfg.planner, dtype=self.dtype,
                 serve_params=self.sp,  # same plan -> reuse slot weights
-                backend=make_cache_backend(
-                    self.cfg.cache_backend, self.cfg.model,
-                    self.cfg.compression,
-                    max_live_tokens=self.cfg.scheduler.max_live_tokens,
-                    paging=self.cfg.paging,
-                    n_shards=self.cfg.n_shards,
-                    max_live_tokens_per_shard=(
-                        self.cfg.scheduler.max_live_tokens_per_shard),
-                    pool_partitions=self.executor.pool_partitions,
-                    row_partitions=self.executor.row_partitions,
-                    obs=self.obs,
-                    donate_state=self.cfg.executor_cfg.donate_state),
+                backend=self._new_backend(),
                 # the executor is shared: its StepFn caches are keyed by
                 # batch shape and cache layout, so one-shot and continuous
                 # traces coexist without evicting each other
@@ -462,10 +447,10 @@ class Engine:
 
     def cancel(self, request_id: int) -> bool:
         """Retire an in-flight or queued request early (continuous mode):
-        its batch row and — on the paged backend — its pool blocks are
-        released immediately (refcounts decremented), exactly like a
-        normal retirement.  The client-disconnect path for SSE streams.
-        Returns False when the id is unknown or already finished."""
+        its batch row and its pool blocks are released immediately
+        (refcounts decremented), exactly like a normal retirement.  The
+        client-disconnect path for SSE streams.  Returns False when the id
+        is unknown or already finished."""
         if self._scheduler is None:
             return False
         return self._scheduler.cancel(request_id)

@@ -18,12 +18,6 @@ Decorator-based registries replace what used to be hardcoded tables:
   solver for the makespan problem (Eq. 4) that ``assign_items`` and
   ``PlannerConfig.engine`` can name.
 
-- **cache backends** — ``@register_cache_backend("name")`` adds a cache
-  storage strategy (a ``serving.cache_backend.CacheBackend`` subclass)
-  selectable via ``EngineConfig.cache_backend``; built-ins ``"slot"``
-  (dense static-capacity layout) and ``"paged"`` (block-pool allocation,
-  DESIGN.md §9).
-
 - **executors** — ``@register_executor("name")`` adds a device-execution
   strategy (a ``repro.exec.Executor`` subclass owning the compiled
   prefill/decode StepFns, DESIGN.md §10) selectable via
@@ -113,12 +107,10 @@ class Registry(Mapping):
 
 POLICY_REGISTRY = Registry("compression policy")
 ASSIGNMENT_ENGINE_REGISTRY = Registry("assignment engine")
-CACHE_BACKEND_REGISTRY = Registry("cache backend")
 EXECUTOR_REGISTRY = Registry("executor")
 
 register_policy = POLICY_REGISTRY.register
 register_assignment_engine = ASSIGNMENT_ENGINE_REGISTRY.register
-register_cache_backend = CACHE_BACKEND_REGISTRY.register
 register_executor = EXECUTOR_REGISTRY.register
 
 
@@ -133,8 +125,6 @@ def _ensure_builtin() -> None:
     import repro.core.assignment  # noqa: F401
     import repro.exec.local  # noqa: F401
     import repro.exec.mesh  # noqa: F401
-    import repro.paging.backend  # noqa: F401
-    import repro.serving.cache_backend  # noqa: F401
 
 
 def get_policy(name: str) -> Callable:
@@ -157,17 +147,6 @@ def list_engines() -> List[str]:
     """Registered assignment-engine names (built-ins + plugins)."""
     _ensure_builtin()
     return ASSIGNMENT_ENGINE_REGISTRY.names()
-
-
-def get_cache_backend(name: str) -> Callable:
-    _ensure_builtin()
-    return CACHE_BACKEND_REGISTRY[name]
-
-
-def list_cache_backends() -> List[str]:
-    """Registered cache-backend names (built-ins + plugins)."""
-    _ensure_builtin()
-    return CACHE_BACKEND_REGISTRY.names()
 
 
 def get_executor(name: str) -> Callable:

@@ -7,7 +7,7 @@ different shape (dict / float / list) with availability rules scattered
 across docstrings.  `Engine.stats()` consolidates them into one nested
 frozen dataclass — ``scheduler`` / ``pool`` / ``prefix`` / ``plan`` /
 ``speculation`` — that is always constructible: sections that have no
-live source (no scheduler yet, obs disabled, slot backend) come back
+live source (no scheduler yet, obs disabled, no live cache) come back
 with ``None``-valued fields and an empty ``detail`` dict instead of
 raising.
 
@@ -27,8 +27,8 @@ from typing import List, Optional
 class PoolStats:
     """Cache-memory footprint (the old ``memory_stats`` dict, typed)."""
 
-    backend: Optional[str] = None  # "slot" | "paged" | plugin name
-    blocks_total: Optional[int] = None  # paged only
+    backend: Optional[str] = None  # "paged"
+    blocks_total: Optional[int] = None
     blocks_in_use: Optional[int] = None
     cache_bytes: Optional[int] = None
     slot_equivalent_bytes: Optional[int] = None

@@ -2,7 +2,6 @@
 from repro.cache.slot_cache import (  # noqa: F401
     PlanArrays,
     SlotCache,
-    append_token,
     fill_from_selection,
     init_cache,
     ring_write_index,

@@ -1,20 +1,23 @@
-"""Slot-layout budgeted KV cache — the FairKV-native runtime structure.
+"""Slot-layout KV cache: what prefill writes, and a replan's intermediate.
 
 Layout (see DESIGN.md §2):  per layer, every model shard owns
 ``slots_per_shard`` *slots*; globally the cache tensors are
 
     k, v     : (L, S, B, C, Dh)   S = total slots (sharded over "model"),
                                    C = static capacity per slot-row
-
-Under the ``mesh`` executor (DESIGN.md §10) this sharding is physical:
-S splits over the model mesh axis and B over the data axis inside the
-decode StepFn's ``shard_map``; every op below is written batch- and
-slot-local, so it runs unchanged on one device or per-shard slices
-(``migrate_cache``'s head-layout round-trip runs on global arrays between
-steps, where XLA repartitions freely).
     lengths  : (L, S, B) int32     retained tokens per (slot, row); 0 for
                                    unowned rows and empty slots
     positions: (B,) int32          next absolute position per row (for RoPE)
+
+Prefill and chunked prefill fill this layout (`fill_from_selection`,
+`append_selection`); the paged backend (`paging/backend.py`) re-houses it
+in blocks, where the live cache and every decode step are.  A replan
+round-trips the paged cache through this layout (`migrate_cache`).  Under
+the ``mesh`` executor (DESIGN.md §10) S splits over the model mesh axis
+and B over the data axis inside the prefill StepFn's ``shard_map``; the
+ops below are written batch- and slot-local, so they run unchanged on one
+device or per-shard slices (``migrate_cache``'s head-layout round-trip
+runs on global arrays between steps, where XLA repartitions freely).
 
 Replicas of one head split the batch by the strided rule
 ``owner(slot, b) = (b % replica_count) == replica_idx``; a slot only ever has
@@ -22,10 +25,11 @@ nonzero ``lengths`` on rows it owns, which simultaneously implements
 best-effort assignment, fair-copying, and empty-slot padding: work inside the
 decode kernel is proportional to Σ lengths.
 
-Decode appends are ring-buffered in the tail of the capacity region once a
-row is full: keys are stored post-RoPE (rotation at absolute positions), so
-attention is order-independent and overwriting the oldest *dynamic* entry
-implements a recency window without any re-sorting.
+Decode appends (`paging.paged_cache.paged_append_token`) are ring-buffered
+in the tail of the capacity region once a row is full (`ring_write_index`):
+keys are stored post-RoPE (rotation at absolute positions), so attention is
+order-independent and overwriting the oldest *dynamic* entry implements a
+recency window without any re-sorting.
 """
 from __future__ import annotations
 
@@ -142,59 +146,6 @@ def ring_write_index(lengths: jnp.ndarray, total_appended: jnp.ndarray,
     ring_start = capacity - ring
     cyc = ring_start + total_appended % ring  # phase shared across rows; a ring
     return jnp.where(lengths < capacity, lengths, cyc).astype(jnp.int32)
-
-
-def append_token(
-    cache: SlotCache,
-    layer: int,
-    k_new: jnp.ndarray,  # (S, B, Dh) post-RoPE
-    v_new: jnp.ndarray,  # (S, B, Dh)
-    own: jnp.ndarray,  # (S, B) bool
-    decode_step: jnp.ndarray,  # scalar int32: appends since prefill
-    ring: int = 128,
-    mode: str = "scatter",
-) -> SlotCache:
-    """Append one token into layer ``layer`` for owned (slot, row) pairs.
-
-    ``mode="scatter"`` uses advanced-index scatter (baseline; XLA SPMD falls
-    back to a replicated scatter — ~4 collectives per layer on the (S,B,Dh)
-    projections).  ``mode="onehot"`` writes via an elementwise mask over the
-    capacity dim — fully local under (slot, batch) sharding at the cost of a
-    full cache-slice rewrite (measured trade in EXPERIMENTS.md §Perf).
-    """
-    L, S, B, C, Dh = cache.k.shape
-    lengths = cache.lengths[layer]  # (S, B)
-    idx = ring_write_index(lengths, decode_step, C, ring)  # (S, B)
-    k_layer = cache.k[layer]
-    v_layer = cache.v[layer]
-    p_layer = cache.pos[layer]
-    k_new = k_new.astype(cache.k.dtype)
-    v_new = v_new.astype(cache.v.dtype)
-    p_new = jnp.broadcast_to(cache.positions[None, :], (S, B))
-    if mode == "onehot":
-        sel = (jnp.arange(C, dtype=jnp.int32)[None, None, :] == idx[:, :, None])
-        sel &= own[:, :, None]  # (S, B, C)
-        k_layer = jnp.where(sel[..., None], k_new[:, :, None, :], k_layer)
-        v_layer = jnp.where(sel[..., None], v_new[:, :, None, :], v_layer)
-        p_layer = jnp.where(sel, p_new[:, :, None], p_layer)
-    else:
-        s_ix = jnp.arange(S)[:, None].repeat(B, 1)
-        b_ix = jnp.arange(B)[None, :].repeat(S, 0)
-        # write only where owned (unowned rows keep old values)
-        k_upd = jnp.where(own[..., None], k_new, k_layer[s_ix, b_ix, idx])
-        v_upd = jnp.where(own[..., None], v_new, v_layer[s_ix, b_ix, idx])
-        p_upd = jnp.where(own, p_new, p_layer[s_ix, b_ix, idx])
-        k_layer = k_layer.at[s_ix, b_ix, idx].set(k_upd)
-        v_layer = v_layer.at[s_ix, b_ix, idx].set(v_upd)
-        p_layer = p_layer.at[s_ix, b_ix, idx].set(p_upd.astype(jnp.int32))
-    new_len = jnp.where(own, jnp.minimum(lengths + 1, C), lengths)
-    return SlotCache(
-        k=cache.k.at[layer].set(k_layer),
-        v=cache.v.at[layer].set(v_layer),
-        lengths=cache.lengths.at[layer].set(new_len.astype(jnp.int32)),
-        pos=cache.pos.at[layer].set(p_layer),
-        positions=cache.positions,
-    )
 
 
 def fill_from_selection(
@@ -325,7 +276,7 @@ def append_selection(
 
 
 # ---------------------------------------------------------------------------
-# Row-level ops (continuous batching, DESIGN.md §7)
+# Row masks and replan migration
 # ---------------------------------------------------------------------------
 
 
@@ -335,49 +286,6 @@ def rows_to_mask(rows, batch: int) -> jnp.ndarray:
     if rows.dtype == jnp.bool_:
         return rows
     return jnp.zeros((batch,), jnp.bool_).at[rows].set(True)
-
-
-def reset_rows(cache: SlotCache, rows) -> SlotCache:
-    """Retire batch rows: zero K/V and ``lengths``, invalidate ``pos``, and
-    reset ``positions`` for every (layer, slot) of the given rows.
-
-    ``rows`` is a (B,) bool mask or an int index array.  A reset row's decode
-    output is exactly zero (the kernel masks by length), so retired rows ride
-    along in the batched decode step for free until re-admission.
-    """
-    B = cache.k.shape[2]
-    m = rows_to_mask(rows, B)
-    return SlotCache(
-        k=jnp.where(m[None, None, :, None, None], 0, cache.k),
-        v=jnp.where(m[None, None, :, None, None], 0, cache.v),
-        lengths=jnp.where(m[None, None, :], 0, cache.lengths),
-        pos=jnp.where(m[None, None, :, None], -1, cache.pos),
-        positions=jnp.where(m, 0, cache.positions),
-    )
-
-
-def insert_rows(cache: SlotCache, sub: SlotCache, rows: jnp.ndarray) -> SlotCache:
-    """Splice a freshly prefilled sub-cache into the live cache.
-
-    ``sub`` has batch ``len(rows)`` and must share (L, S, C, Dh) with
-    ``cache``; its contents fully replace the target rows (lengths, pos and
-    per-row ``positions`` included).  The sub-cache must have been filled with
-    ownership computed at the *target* global row ids
-    (``fill_from_selection(..., rows=rows)``), or replicas will disagree about
-    who owns the spliced rows.
-    """
-    L, S, B, C, Dh = cache.k.shape
-    if sub.k.shape[0] != L or sub.k.shape[1] != S or sub.k.shape[3:] != (C, Dh):
-        raise ValueError(
-            f"sub-cache layout {sub.k.shape} incompatible with {cache.k.shape}")
-    rows = jnp.asarray(rows, jnp.int32)
-    return SlotCache(
-        k=cache.k.at[:, :, rows].set(sub.k.astype(cache.k.dtype)),
-        v=cache.v.at[:, :, rows].set(sub.v.astype(cache.v.dtype)),
-        lengths=cache.lengths.at[:, :, rows].set(sub.lengths),
-        pos=cache.pos.at[:, :, rows].set(sub.pos),
-        positions=cache.positions.at[rows].set(sub.positions),
-    )
 
 
 def gather_head_layout(cache: SlotCache, plan: PlanArrays):

@@ -36,11 +36,6 @@ class CompressionConfig:
     headkv_base_ratio: float = 0.2
     # PyramidKV: budget decays linearly across layers by +/- this fraction
     pyramid_beta: float = 0.6
-    # decode-append implementation: "scatter" (jnp .at[] — the baseline used
-    # for the §Dry-run sweep) or "onehot" (elementwise masked write —
-    # SPMD-local, avoids XLA's replicated-scatter fallback; 47x collective
-    # reduction measured, EXPERIMENTS.md §Perf).  Production default: onehot.
-    append_mode: str = "onehot"
 
     def static_capacity(self) -> int:
         cap = self.capacity or int(round(self.alpha_max * self.budget))

@@ -9,16 +9,17 @@ here (DESIGN.md §10).  Two built-ins register with
 
 - ``"local"`` — single-device ``jax.jit`` (the PR-1..3 baseline path).
 - ``"mesh"``  — ``shard_map`` over a ``(data, model)`` mesh: slot-dim
-  weights and both cache backends shard over ``model``, batch rows over
-  ``data``; the o-projection contraction over slots is the step's one
-  collective (a psum that reassembles the full batch).
+  weights, prefill's slot-layout cache and the paged pool shard over
+  ``model``, batch rows over ``data``; the o-projection contraction over
+  slots is the step's one collective (a psum that reassembles the full
+  batch).
 
 StepFn contract (the no-retrace rule): the jitted callables close over the
 *static* configuration only (`ModelConfig`, `CompressionConfig`, mesh/axis
 names).  Everything a replan changes — slot-layout weights and plan arrays —
 is a **traced argument**, so swapping placements re-executes the same
 executable; as long as the slot grid and capacity are shape-stable the
-decode StepFn compiles exactly once per (batch shape, cache backend).
+decode StepFn compiles exactly once per (batch shape, pool shape).
 ``tokens``/``active``/``rows`` are always materialized arrays (never None
 inside the trace) so one decode trace serves one-shot generation, teacher
 forcing, and continuous batching alike.  The decode ``state`` argument is
@@ -194,9 +195,9 @@ class Executor:
     def shard_state(self, state):
         """Lay a ServeState out for this executor.
 
-        Cache backends build state with no layout information — the empty
-        continuous state, and the block table each time the paged backend
-        re-uploads its host mirror.  The mesh executor places such state
+        The paged backend builds state with no layout information — the
+        empty continuous state, and the block table each time it re-uploads
+        its host mirror.  The mesh executor places such state
         under its decode in_specs here (its decode / propose / verify entry
         points do so on every call), so the cache is sharded before the
         first step and a re-uploaded table never retraces a StepFn.

@@ -1,7 +1,7 @@
 """SLO-aware admission control (DESIGN.md §13).
 
-The controller prices a queued request with the cache backend's *projected*
-cost machinery (`CacheBackend.request_cost` / `admissible` /
+The controller prices a queued request with the paged cache's *projected*
+cost machinery (`PagedBackend.request_cost` / `admissible` /
 `never_fits` — the same §7/§9 projections scheduler admission enforces) and
 decides one of four actions per pump tick:
 

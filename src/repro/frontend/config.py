@@ -11,7 +11,7 @@ Two layered policies are configured here:
 - **fairness** between tenants: deficit-round-robin over per-tenant FIFO
   queues with a token-budget quota (``quantum_tokens`` refilled per pump
   tick, banked deficit capped at ``quota_cap_tokens``).  Costs are the
-  backend's *projected* request tokens/blocks, so fairness is cost-aware —
+  paged cache's *projected* request blocks, so fairness is cost-aware —
   a tenant sending long imbalanced-budget prompts drains its quota faster
   than one sending short ones, exactly the FairKV premise that per-request
   cost is heterogeneous.

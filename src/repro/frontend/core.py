@@ -52,8 +52,8 @@ class FrontendScheduler:
         if self.cfg.admission == "slo":
             # FrontendConfig's quota knobs are denominated in *request*
             # tokens (prompt + generation), but the DRR charges the
-            # backend's request_cost — L·H-scaled projected tokens (slot)
-            # or blocks (paged).  Calibrate the quantum/cap into backend
+            # backend's request_cost — projected worst-case blocks.
+            # Calibrate the quantum/cap into backend
             # units so a "512-token" quantum means 512 request tokens on
             # any model geometry; without this, every request on a
             # many-layer model outprices the cap and could never be

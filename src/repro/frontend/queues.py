@@ -2,7 +2,7 @@
 (DESIGN.md §13).
 
 Classic DRR (Shreedhar & Varghese) with the packet length replaced by the
-cache backend's *projected request cost* in tokens — admission fairness is
+paged cache's *projected request cost* — admission fairness is
 therefore cost-aware: a tenant whose requests pin more projected KV (long
 prompts, imbalanced per-head budgets) drains its quota proportionally
 faster than one sending cheap requests, even at equal request counts.

@@ -1,19 +1,13 @@
 """Jit'd kernel entry points with backend dispatch.
 
-``backend`` (slot-layout kernels):
-- "jnp"       pure-jnp reference (always available; used under pjit where the
-              XLA partitioner handles sharding)
-- "pallas"    the Pallas TPU kernel (TARGET path; on CPU runs via
-              ``interpret=True`` for correctness validation)
-- "auto"      pallas on TPU, jnp elsewhere
-
-``impl`` (paged decode):
+``impl`` (paged decode, the serving path's one decode attention):
 - "pallas"    native block-table kernel (`kernels/paged_fairkv_decode.py`):
-              HBM traffic proportional to allocated blocks (TARGET path)
-- "gather"    materialize capacity-sized contiguous views, reuse the slot
-              kernel (`kernels/paged_decode.py`) — the migration/debug path
+              HBM traffic proportional to allocated blocks (TARGET path; on
+              CPU runs via ``interpret=True`` for correctness validation)
 - "jnp"       pure-jnp oracle (`ref.paged_fairkv_decode_ref`)
 - "auto"      pallas on TPU, jnp elsewhere
+
+``backend`` (snapkv scores): the same "jnp" / "pallas" / "auto" choice.
 
 Prefill attention (`prefill_attention`) takes no knob: the flash kernel
 (`kernels/prefill_flash.py`) on TPU, ``ref.prefill_attention_ref`` (the
@@ -36,7 +30,7 @@ from repro.kernels import ref as _ref
 
 # paged decode implementations accepted by `paged_fairkv_decode` (and by
 # `PagingConfig.decode_impl`, which validates against this tuple)
-PAGED_DECODE_IMPLS = ("auto", "pallas", "gather", "jnp")
+PAGED_DECODE_IMPLS = ("auto", "pallas", "jnp")
 
 
 def _on_tpu() -> bool:
@@ -69,39 +63,24 @@ def pallas_in_decode(paged_impl: str = "auto") -> bool:
     decode ``shard_map`` with ``check_vma=False`` (``pallas_call`` has no
     varying-manual-axes rule for the static checker; the psum-reassembly
     contract is unchanged, only its static verification is skipped)."""
-    # slot kernel and "auto"/"gather" paged dispatch all hit pallas then
-    return _use_pallas("auto") or paged_impl == "pallas"
-
-
-def fairkv_decode(q, k, v, lengths, attn_cap: float = 0.0,
-                  k_pos=None, q_pos=None, window: int = 0,
-                  backend: str = "auto", block_c: int = 128,
-                  interpret: Optional[bool] = None):
-    """Slot-layout decode attention (see ref.fairkv_decode_ref)."""
-    if not _use_pallas(backend):
-        return _ref.fairkv_decode_ref(q, k, v, lengths, attn_cap,
-                                      k_pos=k_pos, q_pos=q_pos, window=window)
-    from repro.kernels.fairkv_decode import fairkv_decode_pallas
-    ipret = (not _on_tpu()) if interpret is None else interpret
-    return fairkv_decode_pallas(q, k, v, lengths, attn_cap=attn_cap,
-                                k_pos=k_pos, q_pos=q_pos, window=window,
-                                block_c=block_c, interpret=ipret)
+    if paged_impl == "auto":
+        return _use_pallas("auto")
+    return paged_impl == "pallas"
 
 
 def paged_fairkv_decode(q, k_pool, v_pool, pos_pool, block_table, lengths,
                         capacity: int, attn_cap: float = 0.0, q_pos=None,
                         window: int = 0, impl: str = "auto",
-                        block_c: int = 128,
                         interpret: Optional[bool] = None,
                         k_scale=None, v_scale=None, kinds=None,
                         q_lens=None):
     """Paged decode attention (see ref.paged_fairkv_decode_ref).
 
-    Same contract as ``fairkv_decode`` with (k, v, k_pos) replaced by one
-    layer's (pools, block table); ``impl`` picks the implementation (module
-    docstring).  All impls agree on the valid prefix — the native kernel is
-    validated against the oracle in tests/test_paged_kernel.py and holds
-    token parity with the gather and slot paths through `Engine.generate`.
+    Same contract as ``ref.fairkv_decode_ref`` with (k, v, k_pos) replaced
+    by one layer's (pools, block table); ``impl`` picks the implementation
+    (module docstring).  Both impls agree on the valid prefix — the native
+    kernel is validated against the oracle in tests/test_paged_kernel.py
+    and holds token parity with it through `Engine.generate`.
 
     ``k_scale``/``v_scale`` ((N,) fp32) and ``kinds`` ((S,) int32) carry the
     quantized-pool dequant state (DESIGN.md §15); every impl applies the
@@ -125,13 +104,6 @@ def paged_fairkv_decode(q, k_pool, v_pool, pos_pool, block_table, lengths,
         return _ref.paged_fairkv_decode_ref(
             q, k_pool, v_pool, pos_pool, block_table, lengths, capacity,
             attn_cap, q_pos=q_pos, window=window,
-            k_scale=k_scale, v_scale=v_scale, kinds=kinds, q_lens=q_lens)
-    if impl == "gather":
-        from repro.kernels.paged_decode import paged_fairkv_decode_gather
-        return paged_fairkv_decode_gather(
-            q, k_pool, v_pool, pos_pool, block_table, lengths, capacity,
-            attn_cap=attn_cap, q_pos=q_pos, window=window, backend="auto",
-            block_c=block_c, interpret=interpret,
             k_scale=k_scale, v_scale=v_scale, kinds=kinds, q_lens=q_lens)
     from repro.kernels.paged_fairkv_decode import paged_fairkv_decode_pallas
     ipret = (not _on_tpu()) if interpret is None else interpret

@@ -1,13 +1,11 @@
 """Pallas TPU kernel: native paged decode attention over block pools.
 
-The gather path (`kernels/paged_decode.py`) materializes each row's blocks
-into a full capacity-sized ``(S, B, C, Dh)`` contiguous view before reusing
-the slot kernel, so its decode HBM traffic is paid at *slot-cache* scale
-even when compression retained a fraction of the capacity.  This kernel is
-the paged analog of vLLM's PagedAttention: it consumes the ``(N, bs, Dh)``
-pools and the ``(S, B, M)`` block table directly, so HBM→VMEM traffic (the
-decode bottleneck) is proportional to the **allocated blocks** — the
-realized retained lengths FairKV balances across shards (DESIGN.md §11).
+The serving path's one decode attention (DESIGN.md §11), the paged analog
+of vLLM's PagedAttention: it consumes the ``(N, bs, Dh)`` pools and the
+``(S, B, M)`` block table directly, never materializing a capacity-sized
+``(S, B, C, Dh)`` view, so HBM→VMEM traffic (the decode bottleneck) is
+proportional to the **allocated blocks** — the realized retained lengths
+FairKV balances across shards.
 
 Design (TPU-adapted flash-decoding over block tables):
 - grid = (S, B, M); one program attends one (slot, row) over one pool
@@ -30,7 +28,7 @@ Design (TPU-adapted flash-decoding over block tables):
   writes ``acc / l`` (exact zeros for rows the slot does not own).
 - sliding-window masking uses the pool's per-entry absolute positions
   (gemma2 local layers / hymba) and gemma2's attention softcap is applied
-  before masking, matching the slot kernel bit-for-bit on the same math.
+  before masking, the same math as the jnp oracle.
 
 Quantized pools (DESIGN.md §15): when the backend stores int8 codes the
 kernel takes two extra ``(N, 1, 1)`` fp32 scale operands whose BlockSpecs ride

@@ -1,8 +1,12 @@
 """Pure-jnp oracles for the Pallas kernels.
 
 These are the semantics-defining references: kernels must match them (fp32
-accumulation) across the shape/dtype sweeps in tests/test_kernels.py.  They
-are also the production fallback path on CPU and in the XLA-only dry-run.
+accumulation) across the shape/dtype sweeps in tests/test_kernels.py and
+tests/test_paged_kernel.py.  They are also the production fallback path on
+CPU and in the XLA-only dry-run.  The decode chain is `fairkv_decode_ref`
+(one query over a contiguous slot-layout view) → `fairkv_decode_mq_ref`
+(the speculative window) → `paged_fairkv_decode_ref` (the same math over
+block pools, the oracle of the serving kernel).
 """
 from __future__ import annotations
 
@@ -138,12 +142,12 @@ def paged_fairkv_decode_ref(
     kinds: Optional[jnp.ndarray] = None,  # (S,) int32 per-slot kind codes
     q_lens: Optional[jnp.ndarray] = None,  # (B,) valid queries (5D q only)
 ) -> jnp.ndarray:
-    """Oracle for the paged decode path (`kernels.paged_decode`).
+    """Oracle for the paged decode kernel (`kernels.paged_fairkv_decode`).
 
     Gathers each (slot, row)'s blocks into the contiguous view the slot
     cache would hold — column ``c`` at offset ``c % bs`` of block
     ``table[c // bs]`` — then applies `fairkv_decode_ref` unchanged, so the
-    paged path's semantics are *defined* as slot-path semantics over the
+    paged path's semantics are *defined* as slot-layout semantics over the
     gathered view.  Quantized pools (``k_scale is not None``) dequantize
     the gathered blocks first (`dequant_block_codes`) — all-int8 kinds
     assumed when ``kinds`` is omitted.  A 5-D ``q`` selects the multi-query

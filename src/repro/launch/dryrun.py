@@ -132,11 +132,12 @@ def run_executor_audit(arch: str, out_dir: str,
     weight all-gathers in the decode hot loop.
     """
     import jax.numpy as jnp
-    from repro.cache.slot_cache import SlotCache
     from repro.compression.base import CompressionConfig
     from repro.exec.mesh import MeshExecutor
     from repro.launch.specs import cell_plan, serve_params_sds
     from repro.api import PlanArrays, ServeState
+    from repro.paging.block_pool import PagingConfig
+    from repro.paging.paged_cache import PagedCache, max_blocks_per_row
 
     cfg = get_config(arch)
     shape = SHAPES["decode_32k"]
@@ -151,12 +152,19 @@ def run_executor_audit(arch: str, out_dir: str,
     B, L, S = shape.global_batch, cfg.n_layers, plan.n_slots
     cap, Dh = ccfg.static_capacity(), cfg.head_dim
     sds = jax.ShapeDtypeStruct
+    # the worst-case paged pool, one partition per (model, data) device
+    # (`init_paged_cache` with n_blocks == 0)
+    n_data = mesh.shape["data"]
+    bs = PagingConfig().block_size
+    M = max_blocks_per_row(cap, bs)
+    N = n_model * n_data * ((S // n_model) * (B // n_data) * M + 1)
     state_sds = ServeState(
-        cache=SlotCache(
-            k=sds((L, S, B, cap, Dh), jnp.bfloat16),
-            v=sds((L, S, B, cap, Dh), jnp.bfloat16),
+        cache=PagedCache(
+            k_pool=sds((L, N, bs, Dh), jnp.bfloat16),
+            v_pool=sds((L, N, bs, Dh), jnp.bfloat16),
+            pos_pool=sds((L, N, bs), jnp.int32),
+            block_table=sds((L, S, B, M), jnp.int32),
             lengths=sds((L, S, B), jnp.int32),
-            pos=sds((L, S, B, cap), jnp.int32),
             positions=sds((B,), jnp.int32)),
         ssm_state=None, conv_state=None, cross_k=None, cross_v=None,
         last_tokens=sds((B,), jnp.int32), decode_steps=sds((), jnp.int32))

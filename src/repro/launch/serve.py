@@ -1,9 +1,10 @@
 """Serving driver: ``python -m repro.launch.serve --arch <id> [--smoke]``.
 
 Default (one-shot) mode: `repro.api.Engine.generate` — prefill + compression
-(Ada-SnapKV by default) → FairKV plan → slot-layout decode over a fixed
-batch.  Prints per-step latency, the realized per-head budget imbalance, the
-plan's efficiency E, and the generated tokens.
+(Ada-SnapKV by default) → FairKV plan → slot-layout decode over the paged
+KV cache for a fixed batch.  Prints per-step latency, the realized
+per-head budget imbalance, the plan's efficiency E, and the generated
+tokens.
 
 ``--continuous`` mode drives the continuous-batching scheduler through the
 same facade (`Engine.run_trace`, DESIGN.md §7): a Poisson trace of requests
@@ -27,7 +28,7 @@ the decode StepFn's per-device collective audit (parsed from the compiled
 HLO via ``repro.distributed.hlo_stats``) — on CPU, fake the devices with
 ``XLA_FLAGS=--xla_force_host_platform_device_count=N``.
 
-Policy, planner, backend and executor names are validated by `EngineConfig`
+Policy, planner and executor names are validated by `EngineConfig`
 against the live registries — ``--help`` lists whatever is registered,
 including plugins.
 """
@@ -51,7 +52,6 @@ from repro.api import (
     SchedulerConfig,
     SpeculationConfig,
     latency_percentiles,
-    list_cache_backends,
     list_engines,
     list_executors,
     list_policies,
@@ -82,17 +82,6 @@ def _engine_config(args, max_seq_len: int, batch_cap: int,
                               extra_copies=args.copies, batch_cap=batch_cap,
                               slots_per_shard=args.slots_per_shard or None),
         scheduler=scheduler,
-        # --prefix-cache needs block refcounts, --kv-dtype needs block
-        # storage, and --speculate needs provisional-block rollback — all
-        # paged-backend features; promote slot (the default) rather than
-        # erroring on the common invocation — any other backend choice
-        # still errors through EngineConfig validation
-        cache_backend=("paged"
-                       if ((getattr(args, "prefix_cache", False)
-                            or getattr(args, "kv_dtype", "fp32") != "fp32"
-                            or speculate > 0)
-                           and args.cache_backend == "slot")
-                       else args.cache_backend),
         paging=PagingConfig(block_size=args.block_size,
                             n_blocks=args.pool_blocks,
                             decode_impl=args.paged_impl,
@@ -124,7 +113,6 @@ _CLI_FIELD_MAP = {
     "engine": ("planner", "engine"),
     "copies": ("planner", "extra_copies"),
     "slots_per_shard": ("planner", "slots_per_shard"),
-    "cache_backend": ("cache_backend",),
     "block_size": ("paging", "block_size"),
     "pool_blocks": ("paging", "n_blocks"),
     "paged_impl": ("paging", "decode_impl"),
@@ -162,8 +150,6 @@ def _engine_config_from_file(args, max_seq_len: int, batch_cap: int,
         cfg = cfg.replace(speculation=dataclasses.replace(
             cfg.speculation, enabled=args.speculate > 0,
             max_k=max(1, args.speculate)))
-    if cfg.speculation.enabled and cfg.cache_backend == "slot":
-        cfg = cfg.replace(cache_backend="paged")
     # workload-derived floors (never shrink what the file asked for)
     cfg = cfg.replace(max_seq_len=max(cfg.max_seq_len, max_seq_len))
     if cfg.planner.batch_cap is None or cfg.planner.batch_cap < batch_cap:
@@ -224,7 +210,6 @@ def _export_obs(eng: Engine, args) -> None:
 def _scheduler_config(args) -> SchedulerConfig:
     return SchedulerConfig(
         max_rows=args.rows,
-        max_live_tokens=args.max_live_tokens or None,
         replan_window=args.replan_window,
         replan_threshold=args.replan_threshold,
         replan_cooldown=args.replan_cooldown,
@@ -319,10 +304,9 @@ def run_continuous(args) -> None:
     print(f"mid-stream admissions: {out['mid_stream_admissions']} | "
           f"replans: {out['replans']} | preemptions: {out['preemptions']}")
     st = eng.stats()  # one typed snapshot (DESIGN.md §8)
-    if st.pool.backend == "paged":
-        print(f"paged cache: {st.pool.blocks_in_use}/{st.pool.blocks_total} "
-              f"blocks ({st.pool.cache_bytes} B) vs slot-equivalent "
-              f"{st.pool.slot_equivalent_bytes} B")
+    print(f"paged cache: {st.pool.blocks_in_use}/{st.pool.blocks_total} "
+          f"blocks ({st.pool.cache_bytes} B) vs slot-equivalent "
+          f"{st.pool.slot_equivalent_bytes} B")
     if st.prefix.enabled:
         print(f"prefix cache: {st.prefix.hits} hits / {st.prefix.misses} "
               f"misses | {st.prefix.entries} entries holding "
@@ -380,7 +364,7 @@ def run_http(args) -> None:
         await server.start()
         print(f"serving on http://{server.host}:{server.port} "
               f"(admission={fcfg.admission}, rows={args.rows}, "
-              f"backend={ecfg.cache_backend}, executor={ecfg.executor})",
+              f"executor={ecfg.executor})",
               flush=True)
         stop = asyncio.Event()
         loop = asyncio.get_running_loop()
@@ -420,10 +404,9 @@ def run_oneshot(args) -> None:
     print(f"decode  {np.median(res.step_s) * 1e3:7.1f} ms/step (median of "
           f"{args.gen}; first {res.step_s[0] * 1e3:.0f} ms incl. compile)")
     pool = eng.stats().pool
-    if pool.backend == "paged":
-        print(f"paged cache: {pool.cache_bytes} B in "
-              f"{pool.blocks_in_use} blocks vs slot-equivalent "
-              f"{pool.slot_equivalent_bytes} B")
+    print(f"paged cache: {pool.cache_bytes} B in "
+          f"{pool.blocks_in_use} blocks vs slot-equivalent "
+          f"{pool.slot_equivalent_bytes} B")
     _collective_audit(eng)
     _export_obs(eng, args)
     for b in range(min(args.batch, 2)):
@@ -458,27 +441,24 @@ def build_parser() -> argparse.ArgumentParser:
     ap.add_argument("--slots-per-shard", type=int, default=0,
                     help="head slots per shard (0 = ceil(kv heads / "
                          "shards), which leaves no spare slot for --copies)")
-    # --- cache backend (DESIGN.md §9) ----------------------------------------
-    ap.add_argument("--cache-backend", default="slot",
-                    help=f"cache storage backend; registered: "
-                         f"{list_cache_backends()}")
+    # --- paged KV cache (DESIGN.md §9) ---------------------------------------
     ap.add_argument("--block-size", type=int, default=16,
-                    help="paged backend: tokens per KV block")
+                    help="tokens per KV block")
     ap.add_argument("--pool-blocks", type=int, default=0,
-                    help="paged backend: blocks per layer pool "
-                         "(0 = slot-equivalent worst case)")
+                    help="blocks per layer pool (0 = slot-equivalent worst "
+                         "case; the admission budget beyond free rows)")
     ap.add_argument("--paged-impl", default="auto",
-                    choices=["auto", "pallas", "gather", "jnp"],
-                    help="paged backend: decode-attention implementation "
-                         "(DESIGN.md §11; auto = native pallas kernel on "
-                         "TPU, jnp oracle elsewhere)")
+                    choices=["auto", "pallas", "jnp"],
+                    help="decode-attention implementation (DESIGN.md §11; "
+                         "auto = native pallas kernel on TPU, jnp oracle "
+                         "elsewhere)")
     ap.add_argument("--kv-dtype", default="fp32",
                     choices=["fp32", "int8", "fp8"],
-                    help="paged backend: KV block storage format "
+                    help="KV block storage format "
                          "(DESIGN.md §15; quantized pools carry per-block "
                          "scales and dequantize in the decode kernel)")
     ap.add_argument("--pool-hbm-bytes", type=int, default=0,
-                    help="paged backend: size the per-layer pool from an "
+                    help="size the per-layer pool from an "
                          "HBM byte budget instead of --pool-blocks "
                          "(bytes-aware admission: int8 pools hold ~4x the "
                          "blocks of fp32 at the same budget)")
@@ -486,8 +466,7 @@ def build_parser() -> argparse.ArgumentParser:
     ap.add_argument("--speculate", type=int, default=0, metavar="K",
                     help="speculative decoding: propose up to K draft "
                          "tokens per tick and verify them in one "
-                         "multi-query pass (0 = off; implies "
-                         "--cache-backend paged)")
+                         "multi-query pass (0 = off)")
     ap.add_argument("--draft-layers", type=int, default=0,
                     help="early-exit depth of the self-speculative draft "
                          "(first N layers + the target's unembedding; "
@@ -500,8 +479,8 @@ def build_parser() -> argparse.ArgumentParser:
                          "monolithic prefill); dense-attention models only")
     ap.add_argument("--prefix-cache", action="store_true",
                     help="content-addressed shared-prefix block reuse "
-                         "(requires --cache-backend paged; implies "
-                         "--prefill-chunk 32 when no chunk size is given)")
+                         "(implies --prefill-chunk 32 when no chunk size "
+                         "is given)")
     ap.add_argument("--prefix-entries", type=int, default=256,
                     help="prefix index capacity (LRU-evicted entries)")
     ap.add_argument("--prefix-templates", type=int, default=0,
@@ -535,8 +514,6 @@ def build_parser() -> argparse.ArgumentParser:
     ap.add_argument("--min-prompt", type=int, default=12)
     ap.add_argument("--max-prompt", type=int, default=24)
     ap.add_argument("--max-steps", type=int, default=2000)
-    ap.add_argument("--max-live-tokens", type=int, default=0,
-                    help="admission token budget (0 = rows-only admission)")
     ap.add_argument("--replan-window", type=int, default=8)
     ap.add_argument("--replan-threshold", type=float, default=1.25)
     ap.add_argument("--replan-cooldown", type=int, default=16)

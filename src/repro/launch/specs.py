@@ -24,7 +24,7 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro import api
-from repro.cache.slot_cache import PlanArrays, SlotCache
+from repro.cache.slot_cache import PlanArrays
 from repro.compression.base import CompressionConfig
 from repro.configs.base import InputShape, ModelConfig
 from repro.core.placement import HeadPlacement
@@ -33,6 +33,8 @@ from repro.core.profiles import synthetic_profile
 from repro.distributed.param_specs import guarded, tree_shardings
 from repro.distributed.sharding import ShardingRules, serve_rules, train_rules, use_rules
 from repro.models import transformer as M
+from repro.paging.block_pool import PagingConfig
+from repro.paging.paged_cache import PagedCache, max_blocks_per_row
 from repro.training.optimizer import AdamWState, OptimizerConfig
 from repro.training.train_loop import train_step
 
@@ -135,7 +137,10 @@ def opt_sds(p_sds) -> AdamWState:
 def serve_state_sds(cfg: ModelConfig, shape: InputShape,
                     plan: Optional[HeadPlacement], ccfg: CompressionConfig,
                     rules: ShardingRules, dtype=BF16) -> api.ServeState:
-    """Decode-time state, with explicit shardings."""
+    """Decode-time state, with explicit shardings: the paged cache the
+    serving decode step runs on, its pool at the worst-case size
+    (`init_paged_cache` with ``n_blocks == 0``), partitioned by model
+    shard when the slots split evenly."""
     B = shape.global_batch
     L = cfg.n_layers
     cap = ccfg.static_capacity()
@@ -150,15 +155,23 @@ def serve_state_sds(cfg: ModelConfig, shape: InputShape,
     if not cfg.attention_free:
         S_ = plan.n_slots
         Dh = cfg.head_dim
-        kv_shape = (L, S_, B, cap, Dh)
-        kv_log = (None, "kv_slot", "batch", "cache_len", None)
-        cache = SlotCache(
-            k=_sds(kv_shape, dtype, ns(kv_shape, kv_log)),
-            v=_sds(kv_shape, dtype, ns(kv_shape, kv_log)),
+        bs = PagingConfig().block_size
+        M_ = max_blocks_per_row(cap, bs)
+        n_model = mesh.shape.get("model", 1)
+        parts = n_model if S_ % n_model == 0 else 1
+        N = parts * ((S_ // parts) * B * M_ + 1)
+        pool_shape = (L, N, bs, Dh)
+        pool_log = (None, "kv_slot", None, None)
+        table_shape = (L, S_, B, M_)
+        cache = PagedCache(
+            k_pool=_sds(pool_shape, dtype, ns(pool_shape, pool_log)),
+            v_pool=_sds(pool_shape, dtype, ns(pool_shape, pool_log)),
+            pos_pool=_sds((L, N, bs), jnp.int32,
+                          ns((L, N, bs), pool_log[:3])),
+            block_table=_sds(table_shape, jnp.int32,
+                             ns(table_shape, (None, "kv_slot", "batch", None))),
             lengths=_sds((L, S_, B), jnp.int32,
                          ns((L, S_, B), (None, "kv_slot", "batch"))),
-            pos=_sds((L, S_, B, cap), jnp.int32,
-                     ns((L, S_, B, cap), (None, "kv_slot", "batch", "cache_len"))),
             positions=_sds((B,), jnp.int32, ns((B,), ("batch",))),
         )
     ssm_state = conv_state = None

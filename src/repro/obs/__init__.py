@@ -18,7 +18,7 @@ One `Obs` handle bundles the two collectors:
 for no-op singletons, so instrumented call sites cost one attribute load
 when observability is off.  The `Engine` facade builds one `Obs` per engine
 from ``EngineConfig.obs`` and threads it through the scheduler, executor,
-and cache backend; standalone construction of those components defaults to
+and paged cache; standalone construction of those components defaults to
 `NULL_OBS`.
 """
 from __future__ import annotations

@@ -1,10 +1,9 @@
-"""Paged KV backend (DESIGN.md §9): block-pool allocator, paged cache
-arrays, and the `CacheBackend` implementation that plugs them into the
-serving stack via ``EngineConfig.cache_backend = "paged"``.
+"""Paged KV cache (DESIGN.md §9): block-pool allocator, paged cache arrays,
+and `PagedBackend`, the one cache store the scheduler and `Engine` use.
 
 Import graph note: ``paged_cache``/``block_pool`` are leaves (no serving
-imports) so the serving engine can dispatch on `PagedCache` without a
-cycle; ``backend`` sits on top of serving and registers itself.
+imports) so the serving engine can use `PagedCache` without a cycle;
+``backend`` sits on top of serving.
 """
 from repro.paging.block_pool import (  # noqa: F401
     BlockPool,

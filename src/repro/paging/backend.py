@@ -1,25 +1,35 @@
-"""`PagedBackend`: the block-pool cache backend (DESIGN.md §9).
+"""`PagedBackend`: the serving stack's one KV cache store (DESIGN.md §9).
 
 Bridges the host-side allocator (`BlockPool`) and the device-side arrays
-(`PagedCache`) behind the `CacheBackend` interface.  The backend keeps a
-host ``numpy`` mirror of the block table as the single source of truth for
-*topology* (which blocks belong to which (layer, slot, row)); every
-topology change rebuilds the device table from the mirror, while *content*
-(K/V values, lengths) flows only through the pure array ops so the jitted
-decode step stays functional.
+(`PagedCache`).  The backend keeps a host ``numpy`` mirror of the block
+table as the single source of truth for *topology* (which blocks belong to
+which (layer, slot, row)); every topology change rebuilds the device table
+from the mirror, while *content* (K/V values, lengths) flows only through
+the pure array ops so the jitted decode step stays functional.
 
 Admission is a free-**block** budget: a request is admissible when every
 layer's free list covers its projected prefill blocks plus one growth block
-per owned head.  Growth beyond that is intentionally *not* reserved —
-decode-time exhaustion is handled by the scheduler preempting the youngest
-request (the recompute policy), which this backend signals via
+per owned head — per (layer, model-shard) partition when the pool is split
+over a mesh (DESIGN.md §10).  Growth beyond that is intentionally *not*
+reserved — decode-time exhaustion is handled by the scheduler preempting
+the youngest request (the recompute policy), which this backend signals via
 ``PoolExhausted``.  A request whose worst-case need exceeds the whole pool
-fails fast at submit (`never_fits`).
+(or one partition of it) fails fast at submit (`never_fits`).
 
-The device half of an admission is one jitted program (`paged_splice`):
-the sub-cache's pagination and the row merges of `splice_state`, with the
-live state donated under ``donate_state`` so every pool scatter writes in
-place.  The caller may not touch the pre-splice state afterwards.
+Prefill writes the slot layout (`cache/slot_cache.py`); `from_prefill` and
+`splice` re-house it in blocks.  The device half of an admission is one
+jitted program (`paged_splice`): the sub-cache's pagination and the row
+merges of `splice_state`, with the live state donated under
+``donate_state`` so every pool scatter writes in place; a retirement's is
+another (`paged_release`), donated the same way.  The caller may not touch
+the pre-splice or pre-release state afterwards.
+
+Contract notes: state-transforming methods are *pure* on the ServeState
+pytree but mutate the host bookkeeping (allocator, table mirror);
+``splice`` / ``prepare_decode`` may raise ``PoolExhausted``, which the
+scheduler treats as a preemption signal; ``migrate_cache`` returns a
+``(candidate_lengths, commit)`` pair so a replan can be scored and
+*rejected* without leaking bookkeeping.
 """
 from __future__ import annotations
 
@@ -31,12 +41,14 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.api.registry import register_cache_backend
 from repro.cache.slot_cache import PlanArrays
 from repro.cache.slot_cache import migrate_cache as migrate_slot_cache
+from repro.compression.base import CompressionConfig
 from repro.compression.policies import layer_keep_bound
+from repro.configs.base import ModelConfig
+from repro.obs import NULL_OBS
 from repro.paging import kvquant
-from repro.paging.block_pool import BlockPool
+from repro.paging.block_pool import BlockPool, PagingConfig
 from repro.paging.paged_cache import (
     PagedCache,
     block_hbm_bytes,
@@ -48,7 +60,6 @@ from repro.paging.paged_cache import (
     release_rows,
 )
 from repro.serving import engine as _serve
-from repro.serving.cache_backend import CacheBackend
 
 
 def paged_splice(state, sub, rows, table_write, table_store, kinds):
@@ -58,6 +69,14 @@ def paged_splice(state, sub, rows, table_write, table_store, kinds):
     cache = paginate_rows(state.cache, sub.cache, rows, table_write,
                           table_store=table_store, kinds=kinds)
     return _serve.splice_state(state, sub, rows, cache=cache)
+
+
+def paged_release(state, rows):
+    """Retirement's device work: clear ``rows``' block table, lengths and
+    positions (`release_rows`), then their last tokens and SSM/conv rows
+    (`reset_state_rows`)."""
+    return _serve.reset_state_rows(state, rows,
+                                   cache=release_rows(state.cache, rows))
 
 
 @functools.cache
@@ -76,20 +95,32 @@ def _owner_mask_np(pa: PlanArrays, rows: np.ndarray) -> np.ndarray:
     return (sh >= 0)[:, :, None] & ((rows % rc) == ri)
 
 
-@register_cache_backend("paged")
-class PagedBackend(CacheBackend):
+class PagedBackend:
+    """Block-pool KV storage and admission accounting.
+
+    ``pool_partitions`` / ``row_partitions`` split the pool into
+    per-(model shard, data shard) partitions (the mesh executor's layout,
+    where each partition lives on one device and its free list is that
+    shard's budget).  ``donate_state`` is the executor's flag
+    (``ExecutorConfig``): admissions write the live state in place under
+    the same contract as the decode StepFn.
+    """
+
     name = "paged"
 
-    def __init__(self, model_cfg, ccfg, max_live_tokens=None, paging=None,
-                 n_shards=1, max_live_tokens_per_shard=None,
-                 pool_partitions=1, row_partitions=1, obs=None,
-                 donate_state=True):
-        super().__init__(model_cfg, ccfg, max_live_tokens=max_live_tokens,
-                         paging=paging, n_shards=n_shards,
-                         max_live_tokens_per_shard=max_live_tokens_per_shard,
-                         pool_partitions=pool_partitions,
-                         row_partitions=row_partitions, obs=obs,
-                         donate_state=donate_state)
+    def __init__(self, model_cfg: ModelConfig, ccfg: CompressionConfig,
+                 paging: Optional[PagingConfig] = None,
+                 pool_partitions: int = 1, row_partitions: int = 1,
+                 obs=None, donate_state: bool = True):
+        self.cfg = model_cfg
+        self.ccfg = ccfg
+        self.paging = paging or PagingConfig()
+        self.pool_partitions = int(pool_partitions)
+        self.row_partitions = int(row_partitions)
+        self.donate_state = bool(donate_state)
+        # observability handle (DESIGN.md §12); NULL_OBS unless the Engine
+        # facade threads its live Obs through
+        self.obs = obs if obs is not None else NULL_OBS
         self.capacity = ccfg.static_capacity()
         self.block_size = self.paging.block_size
         self.max_blocks = max_blocks_per_row(self.capacity, self.block_size)
@@ -145,20 +176,29 @@ class PagedBackend(CacheBackend):
         return kvquant.slot_kinds(self.kv_kinds, np.asarray(pa.slot_head))
 
     def init_state(self, pa, batch, dtype):
+        """Empty ``batch``-row ServeState over a fresh pool and mirror."""
+        if self.cfg.attention_free:
+            self.pa, self.n_rows, self.model_dtype = pa, int(batch), dtype
+            return _serve.init_serve_state(self.cfg, pa, batch, self.ccfg,
+                                           dtype=dtype)
+        return _serve.init_serve_state(self.cfg, pa, batch, self.ccfg,
+                                       dtype=dtype,
+                                       cache=self._fresh_pool(pa, batch,
+                                                              dtype))
+
+    def _fresh_pool(self, pa, batch, dtype) -> PagedCache:
+        """An empty ``batch``-row paged cache under plan ``pa``, with a new
+        allocator and table mirror."""
         self.pa = pa
         self.n_rows = int(batch)
         self.model_dtype = dtype
-        if self.cfg.attention_free:
-            return _serve.init_serve_state(self.cfg, pa, batch, self.ccfg,
-                                           dtype=dtype)
         cache, self.pool = init_paged_cache(
             self.cfg.n_layers, int(pa.slot_head.shape[1]), batch,
             self.capacity, self.cfg.head_dim, self.paging, dtype=dtype,
             partitions=self.partitions, kv_quant=self.kv_quant)
         self.pool.obs = self.obs  # alloc/free/exhaustion counters (§12)
         self.table = np.zeros(cache.block_table.shape, np.int32)
-        return _serve.init_serve_state(self.cfg, pa, batch, self.ccfg,
-                                       dtype=dtype, cache=cache)
+        return cache
 
     def from_prefill(self, state, pa):
         """One-shot adoption: re-house a full-batch slot prefill in blocks
@@ -171,14 +211,14 @@ class PagedBackend(CacheBackend):
         if C != self.capacity:
             raise ValueError(f"prefill capacity {C} != backend capacity "
                              f"{self.capacity}")
-        empty = self.init_state(pa, B, slot.k.dtype)  # fresh pool + mirror
+        empty = self._fresh_pool(pa, B, slot.k.dtype)
         own = _owner_mask_np(self._pa_np, np.arange(B))
         table = build_table(np.asarray(slot.lengths), self.pool,
                             self.block_size, self.max_blocks, own=own,
                             partitions=self.partitions, n_rows=B)
         self.table = table.copy()
         cache = _jitted(paginate_rows, self.donate_state)(
-            empty.cache, slot, jnp.arange(B, dtype=jnp.int32), table,
+            empty, slot, jnp.arange(B, dtype=jnp.int32), table,
             kinds=self._slot_kinds(self._pa_np))
         self._observe_quant_error(slot)
         return dataclasses.replace(state, cache=cache)
@@ -197,7 +237,7 @@ class PagedBackend(CacheBackend):
         cache hit costs ``need − shared`` new blocks.
         """
         if state.cache is None:
-            return _serve.splice_state(state, sub, rows)
+            return _serve.splice_state(state, sub, rows, cache=None)
         rows_np = np.asarray(rows, np.int64)
         leftovers = self.table[:, :, rows_np, :]
         if (leftovers > 0).any():  # defensive: target rows must be retired
@@ -289,14 +329,17 @@ class PagedBackend(CacheBackend):
         ).set(float((err_k + err_v) / max(den_k + den_v, 1e-9)))
 
     def release_rows(self, state, rows):
+        """Retire rows: free their blocks and clear their state, the
+        device half one jitted program (`paged_release`) with the live
+        state donated like `splice`'s."""
         if state.cache is None:
-            return _serve.reset_state_rows(state, rows)
+            return _serve.reset_state_rows(state, rows, cache=None)
         rows_np = np.asarray(rows, np.int64)
         held = self.table[:, :, rows_np, :]
         self.pool.free_table(held.reshape(self.table.shape[0], -1))
         self.table[:, :, rows_np, :] = 0
-        cache = release_rows(state.cache, jnp.asarray(rows_np, jnp.int32))
-        return _serve.reset_state_rows(state, rows, cache=cache)
+        return _jitted(paged_release, self.donate_state)(
+            state, rows_np.astype(np.int32))
 
     def prepare_decode(self, state, active, n_tokens: int = 1):
         """Allocate the blocks backing each active row's next appends.
@@ -604,19 +647,28 @@ class PagedBackend(CacheBackend):
                       + slack)
         return out
 
-    def request_cost(self, req):
+    def request_cost(self, req) -> int:
+        """Worst-case blocks the request can ever pin (telemetry and
+        fail-fast)."""
         if self.cfg.attention_free:
             return 0
         return int(self._layer_blocks(req.prompt_len, req.max_new_tokens,
                                       worst_case=True).sum())
 
-    def admissible(self, state, req, pending=()):
+    def admissible(self, state, req, pending=()) -> bool:
+        """Do the free blocks cover the request's projected prefill need?
+
+        ``pending`` are requests already accepted but not yet spliced into
+        ``state`` (admitted earlier in the same frontend tick, or chunked
+        prefills still in flight) — their projected charge counts against
+        the free blocks too, so a burst of individually-admissible
+        requests cannot jointly over-commit."""
         if self.cfg.attention_free or self.pool is None:
             return True
         if self.pool.n_partitions > 1:
             need = self._partition_need(req.prompt_len, req.max_new_tokens,
                                         worst_case=False)  # (L, slot_parts)
-            for p in pending:  # accepted-not-yet-spliced charge (see base)
+            for p in pending:
                 need = need + self._partition_need(
                     p.prompt_len, p.max_new_tokens, worst_case=False)
             free = self.pool.free_blocks_by_partition()
@@ -646,7 +698,9 @@ class PagedBackend(CacheBackend):
             return need
         return np.maximum(need - np.asarray(sh, np.int64), 0)
 
-    def never_fits(self, req):
+    def never_fits(self, req) -> Optional[str]:
+        """Reason string when the request cannot fit even an empty pool —
+        or one (layer, model-shard) partition of it — else None."""
         if self.cfg.attention_free:
             return None
         if self.pool is not None and self.pool.n_partitions > 1:
@@ -654,9 +708,10 @@ class PagedBackend(CacheBackend):
                                         worst_case=True)
             usable = self.pool.part_size - 1
             if int(need.max()) > usable:
+                l, p = np.unravel_index(int(need.argmax()), need.shape)
                 return (f"worst-case need of {int(need.max())} blocks in "
-                        f"one (layer, model-shard) partition exceeds the "
-                        f"partition ({usable} usable blocks)")
+                        f"the (layer {l}, model shard {p}) partition "
+                        f"exceeds the partition ({usable} usable blocks)")
             return None
         need = self._layer_blocks(req.prompt_len, req.max_new_tokens,
                                   worst_case=True)
@@ -671,6 +726,9 @@ class PagedBackend(CacheBackend):
     # ---- telemetry ---------------------------------------------------------
 
     def sample_metrics(self, state) -> None:
+        """Per-step gauge sampling (host-side, outside jit): the pool's
+        free/used gauges, live tokens, and HBM bytes per live token.  The
+        scheduler calls it once per tick when observability is on."""
         if self.pool is None:
             return
         self.pool.sample_gauges(self.obs.metrics)
@@ -680,32 +738,18 @@ class PagedBackend(CacheBackend):
                 "cache_live_tokens",
                 help="Σ retained KV tokens across the live cache"
             ).set(live)
-            if isinstance(state.cache, PagedCache):
-                per_block = block_hbm_bytes(
-                    self.block_size, self.cfg.head_dim,
-                    state.cache.k_pool.dtype, self.kv_kinds is not None)
-                self.obs.metrics.gauge(
-                    "kv_bytes_per_token",
-                    help="HBM bytes pinned per live KV token (allocated "
-                         "blocks x per-block footprint incl. scales / "
-                         "live tokens) — the decode-bandwidth unit the "
-                         "kv_dtype knob halves (DESIGN.md §15)"
-                ).set(self.pool.blocks_in_use() * per_block / max(live, 1))
+            per_block = block_hbm_bytes(
+                self.block_size, self.cfg.head_dim,
+                state.cache.k_pool.dtype, self.kv_kinds is not None)
+            self.obs.metrics.gauge(
+                "kv_bytes_per_token",
+                help="HBM bytes pinned per live KV token (allocated "
+                     "blocks x per-block footprint incl. scales / "
+                     "live tokens) — the decode-bandwidth unit the "
+                     "kv_dtype knob halves (DESIGN.md §15)"
+            ).set(self.pool.blocks_in_use() * per_block / max(live, 1))
 
     def memory_stats(self, state) -> dict:
-        if (state.cache is not None
-                and not isinstance(state.cache, PagedCache)):
-            # prefill() leaves the cache in slot layout until generate()
-            # adopts it — report the dense footprint it actually occupies
-            c = state.cache
-            L, S, B, C, Dh = c.k.shape
-            return {"backend": self.name, "layout": "slot (pre-adoption)",
-                    "block_size": self.block_size,
-                    "blocks_in_use": 0, "blocks_total": 0,
-                    "cache_bytes": int(2 * L * S * B * C * Dh
-                                       * c.k.dtype.itemsize),
-                    "pool_bytes": 0, "slot_equivalent_bytes": 0,
-                    "live_tokens": int(np.asarray(c.lengths).sum())}
         if state.cache is None or self.pool is None:
             return {"backend": self.name, "block_size": self.block_size,
                     "blocks_in_use": 0, "blocks_total": 0, "cache_bytes": 0,

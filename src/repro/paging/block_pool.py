@@ -39,22 +39,21 @@ class PoolExhausted(RuntimeError):
 
 @dataclass(frozen=True)
 class PagingConfig:
-    """Knobs for the paged cache backend.
+    """Knobs for the paged KV cache (the serving stack's one cache store).
 
     ``block_size``: tokens per K/V block (per slot-row, per layer).
     ``n_blocks``: per-layer pool size *including* the reserved null block;
-    0 sizes the pool to the slot-cache worst case (every (slot, row) fully
-    allocated) so nothing can ever be preempted — useful as a drop-in
+    0 sizes the pool to the dense worst case (every (slot, row) fully
+    allocated) so nothing can ever be preempted — the default, a
     correctness mode.  Undersize it deliberately to trade preemptions for
     HBM (the fig7 benchmark's equal-HBM comparison).
     ``decode_impl``: the paged decode-attention implementation
     (``kernels.ops.PAGED_DECODE_IMPLS``): "pallas" is the native
     block-table kernel (HBM traffic proportional to allocated blocks,
-    DESIGN.md §11), "gather" materializes capacity-sized views and reuses
-    the slot kernel, "jnp" is the pure-jnp oracle, and "auto" (default)
-    picks pallas on TPU and jnp elsewhere.  Validated here at construction
-    (`EngineConfig` composes this config), so a typo fails before any
-    StepFn traces.
+    DESIGN.md §11), "jnp" is the pure-jnp oracle it is tested against, and
+    "auto" (default) picks pallas on TPU and jnp elsewhere.  Validated here
+    at construction (`EngineConfig` composes this config), so a typo fails
+    before any StepFn traces.
     ``kv_dtype``: KV pool storage format (DESIGN.md §15) — "fp32" (the
     default: pools in the engine dtype, no quantization, bit-identical to
     pre-quantization behavior), "int8", or "fp8" (requires a jax with

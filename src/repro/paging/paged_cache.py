@@ -1,9 +1,10 @@
 """Paged slot-layout KV cache: block pools + block tables (DESIGN.md §9).
 
-The slot cache (`cache/slot_cache.py`) pads every (slot, row) to the static
-capacity ``C``, so a head compressed to 12% of ``C`` still reserves 100% of
-it.  The paged layout stores the same logical cache in fixed-size blocks
-allocated proportional to each (slot, row)'s *realized* retained length:
+The live KV cache.  The slot layout prefill writes (`cache/slot_cache.py`)
+pads every (slot, row) to the static capacity ``C``, so a head compressed
+to 12% of ``C`` would still reserve 100% of it.  The paged layout stores the
+same logical cache in fixed-size blocks allocated proportional to each
+(slot, row)'s *realized* retained length:
 
     k_pool, v_pool : (L, N, bs, Dh)   N blocks of bs tokens per layer
     pos_pool       : (L, N, bs) int32 absolute entry positions (−1 = empty)
@@ -20,8 +21,8 @@ is that *partially* retained rows (the common case under imbalanced
 compression) only pin ``ceil(len / bs)`` blocks.  Logical column ``c`` of a
 (slot, row) lives at offset ``c % bs`` of block ``table[c // bs]``, so a
 block gather followed by a reshape reconstructs the exact contiguous
-``(S, B, C, Dh)`` view the decode kernel already understands — decode
-masking, ring appends, and the ownership rule (§2) all carry over unchanged.
+``(S, B, C, Dh)`` slot view (`paged_to_slot`, the jnp oracle) — decode
+masking, ring appends, and the ownership rule (§2) are the slot layout's.
 
 Allocation topology (which table entries are nonzero) is owned by the
 host-side ``BlockPool``; every function here trusts the table it is given.
@@ -153,16 +154,16 @@ def init_paged_cache(
 # ---------------------------------------------------------------------------
 # Views
 # ---------------------------------------------------------------------------
-# The single-layer block gather lives in kernels/paged_decode
-# .paged_gather_views, next to its consumer; ref.paged_fairkv_decode_ref
-# deliberately carries an independent copy (oracles stay self-contained so
-# the parity test cannot compare a bug against itself).
+# ref.paged_fairkv_decode_ref carries its own single-layer block gather
+# (oracles stay self-contained so the parity test cannot compare a bug
+# against itself).
 
 
 def paged_to_slot(cache: PagedCache, capacity: int,
                   kinds: Optional[jnp.ndarray] = None,
                   out_dtype=None) -> SlotCache:
-    """Full materialization into a SlotCache (migration / debugging).
+    """Full materialization into a SlotCache (replan migration, prefix
+    seeds).
 
     Entries outside each (slot, row)'s valid prefix are zeroed (pos −1) so
     the result obeys the slot-cache masking contract exactly; the decode
@@ -229,14 +230,14 @@ def paged_append_token(
     table_layer: Optional[jnp.ndarray] = None,  # (S, B, M) addressing override
     kinds: Optional[jnp.ndarray] = None,  # (S,) per-slot kind codes
 ) -> PagedCache:
-    """Append one token for owned (slot, row) pairs — `append_token` parity.
+    """Append one token for owned (slot, row) pairs.
 
-    The write index (including the full-row recency ring) is identical to
-    the slot cache's `ring_write_index`; the backend must have allocated the
-    block covering it (`prepare_decode`) before the jitted step runs.
-    Unowned pairs — and, defensively, owned pairs whose block is missing —
-    are redirected into the null block, never corrupting live data.
-    Length accounting matches the slot cache exactly (`own` increments).
+    The write index (including the full-row recency ring) is the slot
+    layout's `ring_write_index`; the backend must have allocated the block
+    covering it (`prepare_decode`) before the jitted step runs.  Unowned
+    pairs — and, defensively, owned pairs whose block is missing — are
+    redirected into the null block, never corrupting live data.  Owned
+    pairs' lengths grow by one, up to ``capacity``.
 
     ``table_layer`` overrides the table used for *addressing* only (the
     stored ``block_table`` is untouched): the mesh executor passes a
@@ -402,9 +403,8 @@ def paginate_rows(
 def release_rows(cache: PagedCache, rows) -> PagedCache:
     """Device half of row retirement: clear table/lengths/positions.
 
-    ``rows`` is a (B,) bool mask or an int index array (like
-    `slot_cache.reset_rows`).  Pool contents are left in place —
-    unreferenced blocks are recycled by the host allocator
+    ``rows`` is a (B,) bool mask or an int index array.  Pool contents are
+    left in place — unreferenced blocks are recycled by the host allocator
     (`BlockPool.decref`), which the backend drives.
     """
     m = rows_to_mask(rows, cache.positions.shape[0])

@@ -21,7 +21,7 @@ class PrefixConfig:
                      earlier requests are shared (refcounted) with later
                      requests whose prompts start with the same tokens.
                      Requires ``chunk_tokens > 0`` (hash-chain granularity
-                     is the chunk) and the paged cache backend.
+                     is the chunk) and a single-partition pool.
     chunk_tokens   — split prompt prefill into fixed chunks of this many
                      tokens, interleaved with decode ticks; 0 = monolithic
                      prefill (the pre-PR-8 behavior).

@@ -1,5 +1,6 @@
-"""Serving runtime: prefill + compression + FairKV slot-layout decode,
-plus the continuous-batching request scheduler (DESIGN.md §7)."""
+"""Serving runtime: prefill + compression + FairKV slot-layout decode over
+the paged cache, plus the continuous-batching request scheduler
+(DESIGN.md §7)."""
 from repro.serving.engine import (  # noqa: F401
     ServeState,
     decode_step,
@@ -16,12 +17,6 @@ from repro.serving.request import (  # noqa: F401
     latency_percentiles,
     poisson_arrivals,
     synthesize_requests,
-)
-from repro.serving.cache_backend import (  # noqa: F401
-    CacheBackend,
-    PoolExhausted,
-    SlotBackend,
-    make_cache_backend,
 )
 from repro.serving.scheduler import (  # noqa: F401
     ReplanTrigger,

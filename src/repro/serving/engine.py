@@ -14,15 +14,18 @@ The FairKV plan enters the runtime in two places:
    output is exactly zero and the o-projection contraction over S (an
    all-reduce across model shards) reassembles the full batch.
 
-The decode step is the paper's measured quantity; its attention inner loop is
-``kernels.ops.fairkv_decode`` (Pallas on TPU, jnp ref elsewhere).
+Prefill writes the slot layout (`SlotCache`), which the paged backend
+re-houses in blocks (`paging/backend.py`); the decode step is the paper's
+measured quantity, and its attention inner loop is
+``kernels.ops.paged_fairkv_decode`` over the block pool (the native Pallas
+kernel on TPU, the jnp oracle elsewhere).
 """
 from __future__ import annotations
 
 import dataclasses
 import math
 from dataclasses import dataclass
-from typing import Dict, Optional, Tuple
+from typing import Dict, Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -32,11 +35,8 @@ from repro.cache.slot_cache import (
     PlanArrays,
     SlotCache,
     append_selection,
-    append_token,
     fill_from_selection,
     init_cache,
-    insert_rows,
-    reset_rows,
     rows_to_mask,
 )
 from repro.compression.base import CompressionConfig
@@ -49,7 +49,6 @@ from repro.models import layers as L
 from repro.models import ssm as S
 from repro.models import transformer as M
 from repro.paging.paged_cache import PagedCache, paged_append_token
-from repro.paging.paged_cache import release_rows as paged_release_rows
 
 
 # ---------------------------------------------------------------------------
@@ -60,7 +59,9 @@ from repro.paging.paged_cache import release_rows as paged_release_rows
 @jax.tree_util.register_dataclass
 @dataclass
 class ServeState:
-    cache: Optional[SlotCache]
+    # slot layout as prefill writes it; a PagedCache once live (the paged
+    # backend re-houses every prefill in blocks)
+    cache: Optional[Union[SlotCache, PagedCache]]
     ssm_state: Optional[jnp.ndarray]  # (L, B, H, P, N) fp32
     conv_state: Optional[jnp.ndarray]  # (L, B, W-1, conv_dim)
     cross_k: Optional[jnp.ndarray]  # (L, B, T_enc, Hkv, Dh)
@@ -140,8 +141,8 @@ def prefill(
 
     ``rows`` (optional, (B,) int32) are the *global* batch-row ids this
     sub-batch will occupy in a larger live cache: the strided owner rule is
-    evaluated at those ids so the resulting sub-cache can be spliced in with
-    ``insert_rows`` (continuous-batching admission).  Default: arange(B).
+    evaluated at those ids so the resulting sub-cache can be paged into
+    those rows (continuous-batching admission).  Default: arange(B).
 
     ``model_axis`` names the mesh axis the slot dim is sharded over when the
     call runs inside ``shard_map`` (DESIGN.md §10): the replica-0 weight
@@ -569,8 +570,8 @@ def decode_step(
     single-device path.
 
     ``paged_impl`` selects the paged decode-attention implementation
-    (``kernels.ops.PAGED_DECODE_IMPLS``: native "pallas" kernel, legacy
-    "gather", "jnp" oracle, or "auto" — DESIGN.md §11).  It is *static*
+    (``kernels.ops.PAGED_DECODE_IMPLS``: native "pallas" kernel, "jnp"
+    oracle, or "auto" — DESIGN.md §11).  It is *static*
     configuration (the executors close over ``PagingConfig.decode_impl``),
     so it never affects the StepFn's trace signature.
 
@@ -646,7 +647,8 @@ def _decode_attention(pl, hn, positions, cfg, layer_idx, cache, plan,
                       decode_steps, ccfg, active=None, rows=None,
                       model_axis=None, data_axis=None, paged_impl="auto",
                       kv_kinds=None):
-    """Slot-layout attention for one new token; appends to the cache."""
+    """Slot-layout attention for one new token over the paged cache
+    (DESIGN.md §9); appends the token's K/V to it first."""
     B = hn.shape[0]
     G, Dh = cfg.q_per_kv, cfg.head_dim
     from repro.serving.quant import deq
@@ -666,65 +668,53 @@ def _decode_attention(pl, hn, positions, cfg, layer_idx, cache, plan,
     if active is not None:
         own = own & active[None, :]
     window = M.layer_window(cfg, layer_idx)
-    if isinstance(cache, PagedCache):
-        # paged backend (DESIGN.md §9): block-pool storage, same append
-        # index rule and decode masking through `ops.paged_fairkv_decode`
-        # (native block-table kernel on TPU by default, §11).  Appends
-        # are always scatters into the pool (the onehot trade-off does not
-        # arise: writes touch one block, not a full cache slice).
-        capacity = ccfg.static_capacity()
-        table_l = cache.block_table[layer_idx]  # (S, B, M)
-        if model_axis is not None:
-            # mesh (DESIGN.md §10): the pool shards over (model, data) —
-            # blocks of (slot, row) live on the (slot's model shard, row's
-            # data shard) device — so each device holds an N_part-block
-            # partition while the table stores *global* block ids.  The
-            # partition-aware allocator guarantees locality, so subtracting
-            # the partition offset localizes the ids; anything that falls
-            # outside (the global null block 0 on later partitions,
-            # defensively a foreign id) redirects to local block 0 — every
-            # partition reserves its local block 0 as a null block.
-            n_part = cache.k_pool.shape[1]
-            part_idx = jax.lax.axis_index(model_axis)
-            if data_axis is not None:
-                row_parts = jax.lax.psum(1, data_axis)
-                part_idx = (part_idx * row_parts
-                            + jax.lax.axis_index(data_axis))
-            loc = table_l - part_idx * n_part
-            table_l = jnp.where((loc > 0) & (loc < n_part), loc, 0)
-        kinds = None
-        if cache.k_scale is not None:
-            # per-slot dequant kinds from the *traced* plan: the static
-            # (L, H) kind grid indexed by slot_head, so a replan that moves
-            # heads across slots reuses the same compiled step (§15)
-            grid_l = (jnp.zeros((cfg.n_kv_heads,), jnp.int32)
-                      if kv_kinds is None
-                      else jnp.asarray(kv_kinds[layer_idx], jnp.int32))
-            kinds = jnp.take(grid_l,
-                             jnp.maximum(plan.slot_head[layer_idx], 0))
-        cache = paged_append_token(cache, layer_idx, k_new.swapaxes(0, 1),
-                                   v_new.swapaxes(0, 1), own, decode_steps,
-                                   capacity, ring=max(1, ccfg.decode_margin),
-                                   table_layer=table_l, kinds=kinds)
-        out = K.paged_fairkv_decode(
-            q, cache.k_pool[layer_idx], cache.v_pool[layer_idx],
-            cache.pos_pool[layer_idx], table_l,
-            cache.lengths[layer_idx], capacity, attn_cap=cfg.attn_softcap,
-            q_pos=positions, window=window, impl=paged_impl,
-            k_scale=(None if cache.k_scale is None
-                     else cache.k_scale[layer_idx]),
-            v_scale=(None if cache.v_scale is None
-                     else cache.v_scale[layer_idx]),
-            kinds=kinds)
-        return out, cache
-    cache = append_token(cache, layer_idx, k_new.swapaxes(0, 1),
-                         v_new.swapaxes(0, 1), own, decode_steps,
-                         ring=max(1, ccfg.decode_margin),
-                         mode=ccfg.append_mode)
-    out = K.fairkv_decode(q, cache.k[layer_idx], cache.v[layer_idx],
-                          cache.lengths[layer_idx], attn_cap=cfg.attn_softcap,
-                          k_pos=cache.pos[layer_idx], q_pos=positions,
-                          window=window)
+    # appends scatter one token into each owned row's write block; the
+    # decode reads through `ops.paged_fairkv_decode` (the native
+    # block-table kernel on TPU, §11)
+    capacity = ccfg.static_capacity()
+    table_l = cache.block_table[layer_idx]  # (S, B, M)
+    if model_axis is not None:
+        # mesh (DESIGN.md §10): the pool shards over (model, data) —
+        # blocks of (slot, row) live on the (slot's model shard, row's
+        # data shard) device — so each device holds an N_part-block
+        # partition while the table stores *global* block ids.  The
+        # partition-aware allocator guarantees locality, so subtracting
+        # the partition offset localizes the ids; anything that falls
+        # outside (the global null block 0 on later partitions,
+        # defensively a foreign id) redirects to local block 0 — every
+        # partition reserves its local block 0 as a null block.
+        n_part = cache.k_pool.shape[1]
+        part_idx = jax.lax.axis_index(model_axis)
+        if data_axis is not None:
+            row_parts = jax.lax.psum(1, data_axis)
+            part_idx = (part_idx * row_parts
+                        + jax.lax.axis_index(data_axis))
+        loc = table_l - part_idx * n_part
+        table_l = jnp.where((loc > 0) & (loc < n_part), loc, 0)
+    kinds = None
+    if cache.k_scale is not None:
+        # per-slot dequant kinds from the *traced* plan: the static
+        # (L, H) kind grid indexed by slot_head, so a replan that moves
+        # heads across slots reuses the same compiled step (§15)
+        grid_l = (jnp.zeros((cfg.n_kv_heads,), jnp.int32)
+                  if kv_kinds is None
+                  else jnp.asarray(kv_kinds[layer_idx], jnp.int32))
+        kinds = jnp.take(grid_l,
+                         jnp.maximum(plan.slot_head[layer_idx], 0))
+    cache = paged_append_token(cache, layer_idx, k_new.swapaxes(0, 1),
+                               v_new.swapaxes(0, 1), own, decode_steps,
+                               capacity, ring=max(1, ccfg.decode_margin),
+                               table_layer=table_l, kinds=kinds)
+    out = K.paged_fairkv_decode(
+        q, cache.k_pool[layer_idx], cache.v_pool[layer_idx],
+        cache.pos_pool[layer_idx], table_l,
+        cache.lengths[layer_idx], capacity, attn_cap=cfg.attn_softcap,
+        q_pos=positions, window=window, impl=paged_impl,
+        k_scale=(None if cache.k_scale is None
+                 else cache.k_scale[layer_idx]),
+        v_scale=(None if cache.v_scale is None
+                 else cache.v_scale[layer_idx]),
+        kinds=kinds)
     return out, cache  # (B, S, G, Dh)
 
 
@@ -739,11 +729,11 @@ def _rope_slots(q, positions, cfg):
 def _slot_rms_norm(x, scale_slot, n_channels, eps, model_axis=None):
     """RMS norm over the slot layout (B, S, G, Dh).
 
-    Unowned-slot entries are exactly zero (fairkv_decode guarantees it), and
-    every head contributes through exactly one owned slot per row, so
-    Σx² over (S, G, Dh) equals the original-channel Σx²; the mean divides by
-    the *true* channel count (Hq·Dh), not the padded slot width.  Under
-    ``shard_map`` the Σ over S is a (tiny) cross-shard psum.
+    Unowned-slot entries are exactly zero (the decode attention guarantees
+    it), and every head contributes through exactly one owned slot per row,
+    so Σx² over (S, G, Dh) equals the original-channel Σx²; the mean
+    divides by the *true* channel count (Hq·Dh), not the padded slot width.
+    Under ``shard_map`` the Σ over S is a (tiny) cross-shard psum.
     """
     xf = x.astype(jnp.float32)
     ss = (xf * xf).sum(axis=(1, 2, 3), keepdims=True)
@@ -898,8 +888,6 @@ def verify_step(
     Returns (state, g (B, Q), n_commit (B,), logits (B, Q, V)).
     """
     _spec_supported(cfg)
-    if not isinstance(state.cache, PagedCache):
-        raise ValueError("speculative verify requires the paged cache backend")
     d = draft_layers if draft_layers > 0 else cfg.n_layers
     B, Q = tokens.shape
     active_b = (jnp.ones((B,), bool) if active is None else active)
@@ -983,8 +971,6 @@ def _verify_attention(pl, hn, positions_q, q_lens, cfg, layer_idx, cache,
     """
     B, Q, _ = hn.shape
     from repro.serving.quant import deq
-    if not isinstance(cache, PagedCache):
-        raise ValueError("speculative verify requires the paged cache backend")
     q = jnp.einsum("bqd,sdgx->bsqgx", hn, deq(pl["wq_s"]))  # (B, S, Q, G, Dh)
     k_new = jnp.einsum("bqd,sdx->bsqx", hn, deq(pl["wk_s"]))  # (B, S, Q, Dh)
     v_new = jnp.einsum("bqd,sdx->bsqx", hn, deq(pl["wv_s"]))
@@ -1071,8 +1057,9 @@ def init_serve_state(cfg: ModelConfig, plan: PlanArrays, batch: int,
     """Empty B-row ServeState: every row retired (lengths 0, positions 0).
 
     The continuous-batching scheduler starts from this and splices prefilled
-    requests into rows as they are admitted.  ``cache`` lets a cache backend
-    substitute its own layout (e.g. a `PagedCache`) while reusing the
+    requests into rows as they are admitted.  The default cache is an empty
+    slot-layout one (a chunked prefill's seed); ``cache`` substitutes
+    another (the paged backend's `PagedCache`) while reusing the
     SSM/conv/token initialization.  Encoder-decoder models are not
     supported (their cross-KV shape depends on per-request encoder inputs).
     """
@@ -1100,20 +1087,17 @@ def init_serve_state(cfg: ModelConfig, plan: PlanArrays, batch: int,
 
 
 def splice_state(state: ServeState, sub: ServeState,
-                 rows: jnp.ndarray, cache=_KEEP) -> ServeState:
+                 rows: jnp.ndarray, cache) -> ServeState:
     """Splice a prefilled sub-batch state into ``rows`` of the live state.
 
     ``sub`` must come from ``prefill(..., rows=rows)`` so its slot-cache
     ownership matches the target global rows.  ``decode_steps`` keeps the
     live value — the ring-write phase is global, not per-request.
-    ``cache`` overrides the cache splice (cache backends pass their
-    already-spliced layout; the SSM/conv/token rows still merge here).
+    ``cache`` is the live cache with ``sub``'s rows already paged in (the
+    paged backend's `paginate_rows`; None for attention-free models); the
+    SSM/conv/token rows merge here.
     """
     rows = jnp.asarray(rows, jnp.int32)
-    if cache is _KEEP:
-        cache = state.cache
-        if cache is not None:
-            cache = insert_rows(cache, sub.cache, rows)
     ssm = state.ssm_state
     if ssm is not None:
         ssm = ssm.at[:, rows].set(sub.ssm_state)
@@ -1127,17 +1111,13 @@ def splice_state(state: ServeState, sub: ServeState,
         decode_steps=state.decode_steps)
 
 
-def reset_state_rows(state: ServeState, rows, cache=_KEEP) -> ServeState:
-    """Retire rows: clear their cache/SSM state so their decode output is
-    exactly zero and the rows can be handed back to the freelist.
-    ``cache`` overrides the cache reset (backends pass their own layout)."""
+def reset_state_rows(state: ServeState, rows, cache) -> ServeState:
+    """Retire rows: clear their SSM/conv state and last tokens so the rows
+    can be handed back to the freelist.  ``cache`` is the live cache with
+    the rows already released (the paged backend's `release_rows`, which
+    zeroes their lengths so their decode output is exactly zero; None for
+    attention-free models)."""
     m = rows_to_mask(rows, state.last_tokens.shape[0])
-    if cache is _KEEP:
-        cache = state.cache
-        if cache is not None:
-            cache = (paged_release_rows(cache, rows)
-                     if isinstance(cache, PagedCache)
-                     else reset_rows(cache, rows))
     ssm = state.ssm_state
     if ssm is not None:
         ssm = jnp.where(m[None, :, None, None, None], 0, ssm)

@@ -1,16 +1,18 @@
-"""Continuous-batching scheduler over the slot cache (DESIGN.md §7).
+"""Continuous-batching scheduler over the paged cache (DESIGN.md §7, §9).
 
 The engine's decode step is batch-shaped: every tick runs all ``max_rows``
 batch rows, and a retired row (``lengths == 0`` everywhere) contributes
-exactly zero work inside ``fairkv_decode`` and zero output through the
-o-projection.  Continuous batching therefore reduces to *row bookkeeping*:
+exactly zero work inside ``paged_fairkv_decode`` and zero output through
+the o-projection.  Continuous batching therefore reduces to *row
+bookkeeping*:
 
 - a **freelist** hands out retired rows to queued requests;
 - **admission** prefills the new request alone — with slot-cache ownership
   evaluated at its target global row id (``prefill(..., rows=[row])``) — and
-  splices the resulting sub-state into the live batch (``splice_state``);
-- **retirement** (EOS or max-new-tokens) zeroes the row's cache/SSM state
-  (``reset_state_rows``) and returns the row to the freelist.
+  the paged backend pages the resulting sub-state into the live batch
+  (`PagedBackend.splice`) when its free blocks cover the request;
+- **retirement** (EOS or max-new-tokens) frees the row's blocks, clears its
+  state (`PagedBackend.release_rows`) and returns the row to the freelist.
 
 On top of the lifecycle the scheduler watches the *realized* per-shard KV
 load (Σ ``lengths`` per shard, the paper's Eq. 4 observable) over a sliding
@@ -18,7 +20,8 @@ window; when the max/mean imbalance stays above a threshold for the whole
 window (hysteresis) and a cooldown has elapsed, it rebuilds the
 ``HeadPlacement`` from the realized per-head profile (``build_plan``),
 re-slotifies the weights, and migrates the live cache into the new layout
-(``migrate_cache``) — the online form of ``examples/straggler_replan.py``.
+(`PagedBackend.migrate_cache`) — the online form of
+``examples/straggler_replan.py``.
 """
 from __future__ import annotations
 
@@ -27,7 +30,7 @@ import time
 from collections import deque
 from contextlib import contextmanager
 from dataclasses import dataclass, field
-from typing import Dict, List, Optional, Sequence
+from typing import TYPE_CHECKING, Dict, List, Optional, Sequence
 
 import jax.numpy as jnp
 import numpy as np
@@ -43,11 +46,13 @@ from repro.obs import NULL_OBS, Obs
 from repro.paging.block_pool import PoolExhausted
 from repro.prefix import PrefixConfig, PrefixEntry, PrefixIndex
 from repro.serving import engine as _serve
-from repro.serving.cache_backend import CacheBackend, make_cache_backend
 from repro.serving.engine import slotify_params
 from repro.serving.request import (Request, RequestState,
                                    latency_percentiles)
 from repro.serving.speculation import SpeculationConfig
+
+if TYPE_CHECKING:  # paging.backend imports the serving package itself
+    from repro.paging.backend import PagedBackend
 
 
 # ---------------------------------------------------------------------------
@@ -155,17 +160,10 @@ class ReplanTrigger:
 
 @dataclass(frozen=True)
 class SchedulerConfig:
-    max_rows: int = 4  # fixed decode batch width (row slots)
-    # admission token budget (slot backend): projected Σ lengths over (L, H)
-    # the live cache may hold; None admits on free rows alone.  The paged
-    # backend ignores this — its budget is the free-block pool itself.
-    max_live_tokens: Optional[int] = None
-    # per-model-shard admission budget (slot backend, DESIGN.md §10): the
-    # projected Σ lengths any single shard may hold — the bottleneck shard
-    # gates admission, which is what makes balanced (Fair-Copying) plans
-    # admit more concurrent rows than imbalanced ones (benchmarks/fig8).
-    # The paged backend's analog is its per-partition free-block check.
-    max_live_tokens_per_shard: Optional[int] = None
+    # fixed decode batch width (row slots); the admission budget beyond
+    # free rows is the paged pool's free blocks (per model-shard partition
+    # on a mesh, DESIGN.md §10), sized by `PagingConfig`
+    max_rows: int = 4
     replan_window: int = 8
     replan_threshold: float = 1.25
     replan_cooldown: int = 16
@@ -187,7 +185,7 @@ class Scheduler:
         planner_cfg: Optional[PlannerConfig] = None,
         dtype=jnp.float32,
         serve_params: Optional[dict] = None,
-        backend: Optional[CacheBackend] = None,
+        backend: Optional["PagedBackend"] = None,
         executor: Optional[Executor] = None,
         head_importance: Optional[np.ndarray] = None,
         obs: Optional[Obs] = None,
@@ -208,19 +206,19 @@ class Scheduler:
             mode=plan.mode, slots_per_shard=plan.slots_per_shard,
             r_max=plan.r_max, batch_cap=scfg.max_rows)
         self.dtype = dtype
-        # serve_params: pre-slotified weights for *this plan* (the Engine
-        # facade passes its own copy so the permutation isn't paid twice)
-        # cache backend: storage layout + admission accounting (DESIGN.md §9)
-        self.backend = backend if backend is not None else make_cache_backend(
-            "slot", cfg, ccfg, max_live_tokens=scfg.max_live_tokens,
-            n_shards=plan.n_shards,
-            max_live_tokens_per_shard=scfg.max_live_tokens_per_shard)
+        # the paged cache: block storage + admission accounting (DESIGN.md §9)
+        if backend is None:
+            from repro.paging.backend import PagedBackend
+            backend = PagedBackend(cfg, ccfg)
+        self.backend = backend
         # executor: the compiled StepFns the hot loop runs (DESIGN.md §10);
         # sp/pa are StepFn *arguments*, so replans swap placements through
         # the same executable — no retrace
         self.executor = (executor if executor is not None
                          else make_executor("local", cfg, ccfg,
                                             paging=self.backend.paging))
+        # serve_params: pre-slotified weights for *this plan* (the Engine
+        # facade passes its own copy so the permutation isn't paid twice)
         self.sp = (serve_params if serve_params is not None
                    else self.executor.shard_params(
                        slotify_params(params, plan, cfg)))
@@ -248,9 +246,9 @@ class Scheduler:
 
         # prefix cache + chunked prefill (DESIGN.md §14).  Chunking needs
         # only the dense-attention chunk StepFn; block *sharing* further
-        # needs the paged backend with a single-partition pool (shared
-        # blocks must be valid for any recipient row — a mesh pool pins
-        # blocks to the donor's (shard, row-partition) device).
+        # needs a single-partition pool (shared blocks must be valid for
+        # any recipient row — a mesh pool pins blocks to the donor's
+        # (shard, row-partition) device).
         self.prefix_cfg = prefix_cfg if prefix_cfg is not None \
             else PrefixConfig()
         self.prefilling: Dict[int, _ChunkJob] = {}  # row -> in-flight job
@@ -258,10 +256,9 @@ class Scheduler:
                           and cfg.family == "dense"
                           and not cfg.attention_free)
         self.prefix: Optional[PrefixIndex] = None
-        pool = getattr(self.backend, "pool", None)
+        pool = self.backend.pool
         if (self.prefix_cfg.enabled and self._chunk_ok
-                and self.backend.name == "paged" and pool is not None
-                and pool.n_partitions == 1):
+                and pool is not None and pool.n_partitions == 1):
             self.prefix = PrefixIndex(self.prefix_cfg.chunk_tokens,
                                       self.prefix_cfg.max_entries,
                                       obs=self.obs)
@@ -275,11 +272,6 @@ class Scheduler:
                                  and spec_cfg.enabled) else None
         if self.spec is not None:
             _serve._spec_supported(cfg)  # dense decoder-only models
-            if self.backend.name != "paged":
-                raise ValueError(
-                    "speculative decoding needs the paged backend "
-                    "(provisional blocks + rollback), got "
-                    f"cache_backend={self.backend.name!r}")
             d = self.spec.draft_layers
             if d > cfg.n_layers:
                 raise ValueError(
@@ -558,7 +550,7 @@ class Scheduler:
             pool = self.backend.pool
             extra = int(np.maximum(pool.refcount - 1, 0).sum())
             c = self.state.cache
-            if c is not None and hasattr(c, "k_pool"):
+            if c is not None:
                 blk_bytes = (2 * c.k_pool.shape[2] * c.k_pool.shape[3]
                              * c.k_pool.dtype.itemsize)
                 m.gauge("prefix_bytes_saved",
@@ -609,8 +601,8 @@ class Scheduler:
         self.queue.append(req)
 
     def _estimated_cost(self, req: Request) -> int:
-        """Projected cost in the backend's units (slot: Σ-lengths bound via
-        the per-policy keep bounds; paged: worst-case blocks)."""
+        """Projected worst-case blocks (the per-policy keep bounds plus the
+        request's decode growth)."""
         return self.backend.request_cost(req)
 
     def admissible(self, req: Request) -> bool:
@@ -644,10 +636,9 @@ class Scheduler:
             except PoolExhausted:
                 # admission never preempts (only decode growth does —
                 # evicting older in-flight work to admit newer would invert
-                # FCFS): undo and let the caller requeue.  Unreachable for
-                # the built-in backends, whose admissible() charge dominates
-                # the splice need; this guards plugin backends with looser
-                # admission estimates.
+                # FCFS): undo and let the caller requeue.  Unreachable while
+                # admissible()'s charge dominates the splice need; this
+                # guards a looser admission estimate.
                 self.freelist.release(row)
                 req.state = RequestState.QUEUED
                 req.row = None
@@ -897,10 +888,10 @@ class Scheduler:
         return {} if self.prefix is None else self.prefix.stats()
 
     def _release_row(self, req: Request) -> None:
-        """Free a live request's row and its backing storage (blocks /
-        slot state) — shared by retirement, cancellation, and preemption."""
+        """Free a live request's row and its blocks — shared by
+        retirement, cancellation, and preemption."""
         row = req.row
-        self.state = self.backend.release_rows(self.state, jnp.asarray([row]))
+        self.state = self.backend.release_rows(self.state, np.asarray([row]))
         del self.active[row]
         self.freelist.release(row)
         self._spec_depth.pop(row, None)
@@ -932,12 +923,11 @@ class Scheduler:
         """Retire a request early (client disconnect, deadline shed).
 
         An in-flight row is released exactly like a normal retirement —
-        the paged backend frees its blocks back to the pool (refcounts
-        decremented), the slot backend zeroes the row — so cancellation
-        conserves pool capacity.  A still-queued request is simply removed.
-        The request lands in ``finished`` with state CANCELLED so trace
-        drivers and streams observe a terminal state.  Returns False when
-        the id is unknown or already finished.
+        its blocks go back to the pool (refcounts decremented) — so
+        cancellation conserves pool capacity.  A still-queued request is
+        simply removed.  The request lands in ``finished`` with state
+        CANCELLED so trace drivers and streams observe a terminal state.
+        Returns False when the id is unknown or already finished.
         """
         req = next((r for r in self.active.values()
                     if r.req_id == req_id), None)
@@ -975,7 +965,7 @@ class Scheduler:
         `run` cancels the queue and sheds unsubmitted arrivals itself."""
         self.draining = True
 
-    # ---- preemption (paged backend, DESIGN.md §9) --------------------------
+    # ---- preemption (DESIGN.md §9) -----------------------------------------
 
     def _evict(self, victim: Request) -> None:
         """Preempt one live request back to QUEUED (recompute policy),

@@ -175,6 +175,7 @@ def test_config_replace_revalidates():
 def test_generate_parity_with_handwired_loop():
     from repro.cache.slot_cache import PlanArrays
     from repro.core import build_plan, synthetic_profile
+    from repro.paging.backend import PagedBackend
     from repro.serving import decode_step, prefill, slotify_params
 
     T, B, GEN = 24, 2, 4
@@ -194,9 +195,12 @@ def test_generate_parity_with_handwired_loop():
     batch = {"tokens": jnp.asarray(prompts, jnp.int32)}
     state, logits, lengths = prefill(sp, batch, cfg.model, pa,
                                      cfg.compression)
+    backend = PagedBackend(cfg.model, cfg.compression, paging=cfg.paging)
+    state = backend.from_prefill(state, pa)
     ref_logits = [np.asarray(logits)]
     ref_tokens = [np.asarray(state.last_tokens)]
     for _ in range(GEN):
+        state = backend.prepare_decode(state, None)
         state, logits = decode_step(sp, state, cfg.model, pa,
                                     cfg.compression)
         ref_logits.append(np.asarray(logits))
@@ -208,6 +212,34 @@ def test_generate_parity_with_handwired_loop():
     np.testing.assert_array_equal(res.lengths, np.asarray(lengths))
     assert res.efficiency == pytest.approx(
         plan.efficiency(np.asarray(lengths, np.float64).mean(axis=2)))
+
+
+@pytest.mark.parametrize("arch", ["minitron-8b", "qwen1.5-110b", "gemma2-9b",
+                                  "granite-3-2b"])
+def test_generate_on_paged_cache_matches_train_forward(arch):
+    """`Engine.generate` serves each architecture its one-shot path runs
+    for through the paged cache: uncompressed, the logits at every served
+    position equal the training forward's on the same tokens."""
+    from repro.models import forward_train
+    from repro.paging.paged_cache import PagedCache
+    T, B, GEN = 20, 2, 4
+    cfg = EngineConfig.smoke(
+        arch, n_shards=2, max_seq_len=T + GEN + 8,
+        compression=_ccfg(policy="none", budget=64, alpha_max=1.0,
+                          capacity=T),
+        planner=PlannerConfig(mode="fairkv_dp", extra_copies=2, batch_cap=B))
+    eng = Engine.build(cfg)
+    prompts = np.random.default_rng(0).integers(
+        0, cfg.model.vocab_size, (B, T))
+    res = eng.generate(prompts, GEN)
+    assert isinstance(eng.state.cache, PagedCache)
+    assert eng.executor.decode_traces == 1
+    fed = np.concatenate([prompts, res.tokens[:, :GEN]], axis=1)
+    gold, _ = forward_train(eng.params, {"tokens": jnp.asarray(fed)},
+                            cfg.model, remat=False)
+    gold = np.asarray(gold[:, T - 1:])
+    rel = float(np.abs(res.logits - gold).max() / np.abs(gold).max())
+    assert rel < 2e-3, rel
 
 
 def test_generate_teacher_forcing_feeds_given_tokens():
@@ -224,6 +256,33 @@ def test_generate_teacher_forcing_feeds_given_tokens():
     np.testing.assert_allclose(free.logits[:, 0], forced.logits[:, 0],
                                rtol=1e-5, atol=1e-5)
     assert not np.allclose(free.logits[:, -1], forced.logits[:, -1])
+
+
+def test_prefill_pages_and_measure_profile_leaves_the_pool():
+    """`Engine.prefill` holds its cache in the paged pool; the profiling
+    prefill of `measure_profile` touches neither the held state nor the
+    pool's allocator, and a one-shot replan migrates the paged cache."""
+    from repro.paging.paged_cache import PagedCache
+    T, B = 16, 2
+    cfg = _ecfg(max_seq_len=T + 8)
+    eng = Engine.build(cfg)
+    prompts = np.random.default_rng(0).integers(
+        0, cfg.model.vocab_size, (B, T))
+    eng.prefill(prompts)
+    assert isinstance(eng.state.cache, PagedCache)
+    state, pool = eng.state, eng.backend.pool
+    in_use = pool.blocks_in_use()
+    assert in_use > 0
+    prof = eng.measure_profile(prompts[:, ::-1].copy())
+    assert prof.shape == (cfg.model.n_layers, cfg.model.n_kv_heads)
+    assert eng.state is state and eng.backend.pool is pool
+    assert pool.blocks_in_use() == in_use
+    lens = np.asarray(state.cache.lengths).sum()
+    ev = eng.replan(profile=prof)
+    assert ev["migrated_cache"]
+    assert isinstance(eng.state.cache, PagedCache)
+    assert int(np.asarray(eng.state.cache.lengths).sum()) == lens
+    eng.backend.pool.check_invariants()
 
 
 # ---------------------------------------------------------------------------
@@ -300,7 +359,7 @@ def test_config_dict_roundtrip_defaults():
 
 
 def test_config_dict_roundtrip_property():
-    """Round-trip over the registered option space: every policy, backend,
+    """Round-trip over the registered option space: every policy,
     executor, and planner mode survives ``to_dict -> json -> from_dict``
     unchanged (tuples, nested sub-configs, and dtype-override dicts
     included)."""
@@ -314,18 +373,15 @@ def test_config_dict_roundtrip_property():
            mode=st.sampled_from(sorted(PLANNER_MODES)),
            engine=st.sampled_from(sorted(list_engines())),
            executor=st.sampled_from(sorted(list_executors())),
-           backend=st.sampled_from(["slot", "paged"]),
            kv=st.sampled_from(["fp32", "int8"]),
            spec=st.booleans(), max_k=st.integers(1, 6))
-    def run(policy, mode, engine, executor, backend, kv, spec, max_k):
-        if spec or kv != "fp32":
-            backend = "paged"  # speculation / quantized pools need paged
+    def run(policy, mode, engine, executor, kv, spec, max_k):
         overrides = {(0, 1): "int8"} if kv == "int8" else {}
         cfg = _ecfg(
             compression=_ccfg(policy=policy),
             planner=PlannerConfig(mode=mode, engine=engine, extra_copies=4,
                                   batch_cap=2),
-            cache_backend=backend, executor=executor,
+            executor=executor,
             paging=PagingConfig(block_size=8, kv_dtype=kv,
                                 kv_dtype_overrides=overrides),
             speculation=SpeculationConfig(enabled=spec, max_k=max_k))
@@ -359,8 +415,9 @@ def test_config_from_dict_revalidates():
     with pytest.raises(ValueError, match="not_a_policy"):
         EngineConfig.from_dict(data)
     data = _ecfg().to_dict()
-    data["speculation"]["enabled"] = True  # slot backend + speculation
-    with pytest.raises(ValueError, match="paged"):
+    data["speculation"]["enabled"] = True  # deeper draft than the model
+    data["speculation"]["draft_layers"] = 99
+    with pytest.raises(ValueError, match="draft_layers"):
         EngineConfig.from_dict(data)
 
 
@@ -401,5 +458,5 @@ def test_stats_continuous_matches_legacy_accessors():
     assert st.scheduler.imbalance == pytest.approx(eng.imbalance())
     assert st.scheduler.replan_log == eng.replan_log
     assert st.pool.detail == eng.memory_stats()
-    assert st.pool.backend == "slot"
+    assert st.pool.backend == "paged"
     assert st.to_dict()["scheduler"]["finished"] == 3

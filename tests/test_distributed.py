@@ -96,6 +96,26 @@ print(json.dumps({{"ok": True, "args": ma.argument_size_in_bytes}}))
 """
 
 
+def test_decode_cell_state_is_the_paged_cache():
+    """The decode cells lower the step that serves: their state holds a
+    `PagedCache` of abstract arrays (the worst-case pool, its block axis
+    split by model shard), not a dense slot cache."""
+    from repro.configs import get_smoke_config
+    from repro.configs.base import InputShape
+    from repro.launch.specs import build_cell
+    from repro.paging.paged_cache import PagedCache
+    cfg = get_smoke_config("minitron-8b")
+    cell = build_cell(cfg, InputShape("mini_decode", 64, 4, "decode"),
+                      _mesh11(), quantize=False)
+    cache = cell.args[1].cache
+    assert isinstance(cache, PagedCache)
+    L, N, bs, Dh = cache.k_pool.shape
+    _, S, B, M = cache.block_table.shape
+    assert (L, B, Dh) == (cfg.n_layers, 4, cfg.head_dim)
+    assert M * bs >= cell.meta["ccfg"].static_capacity()
+    assert N == S * B * M + 1  # one partition on a one-device mesh
+
+
 @pytest.mark.parametrize("arch,kind", [
     ("minitron-8b", "decode"),
     ("gemma2-9b", "train"),

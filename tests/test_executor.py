@@ -1,13 +1,13 @@
 """Executor layer (DESIGN.md §10): registry/config wiring, StepFn no-retrace
-guarantees, per-shard admission, partitioned block pool, and local↔mesh
-parity on a multi-device host mesh.
+guarantees, per-model-shard admission, partitioned block pool, and
+local↔mesh parity on a multi-device host mesh.
 
 The parity tests run in a subprocess (the fake-device count must be set
 before the first jax import, like tests/test_distributed.py): one process
 drives `Engine.generate` through the ``local`` and ``mesh`` executors on
-identical weights/plans — imbalanced profiles WITH replicas, both cache
-backends, 2- and 8-device meshes — and asserts identical tokens and cache
-lengths, plus a replan that must not recompile the decode StepFn.
+identical weights/plans — imbalanced profiles WITH replicas, 2- and
+8-device meshes — and asserts identical tokens and cache lengths, plus a
+replan that must not recompile the decode StepFn.
 """
 import json
 import subprocess
@@ -156,8 +156,7 @@ def test_local_step_hlo_audit():
                                         eng.state.last_tokens)
 
 
-@pytest.mark.parametrize("backend,kernel", [("slot", "fairkv_decode"),
-                                            ("paged", "paged_fairkv_decode")])
+@pytest.mark.parametrize("backend,kernel", [("paged", "paged_fairkv_decode")])
 def test_step_hlo_names_the_step_and_its_kernels(monkeypatch, backend,
                                                  kernel):
     """The compiled modules are named for their StepFn kind and carry the
@@ -182,41 +181,73 @@ def test_step_hlo_names_the_step_and_its_kernels(monkeypatch, backend,
 
 
 # ---------------------------------------------------------------------------
-# per-model-shard admission (slot backend)
+# per-model-shard admission (partitioned paged pool)
 # ---------------------------------------------------------------------------
 
 
+def _partitioned_backend(cfg, n_blocks):
+    """A paged cache whose pool splits into one partition per model shard
+    (the mesh executor's layout, DESIGN.md §10)."""
+    from repro.api import PagingConfig
+    from repro.paging.backend import PagedBackend
+    return PagedBackend(cfg.model, cfg.compression,
+                        paging=PagingConfig(block_size=8, n_blocks=n_blocks),
+                        pool_partitions=cfg.n_shards)
+
+
 def test_per_shard_budget_gates_admission():
-    """A per-shard budget must gate on the bottleneck shard: a request that
-    fits the global sum but overloads one shard is not admissible."""
-    from repro.serving.cache_backend import make_cache_backend
+    """The free-block check is per model-shard partition: a request that
+    fits the pool's global free blocks but overflows one partition is not
+    admissible."""
     from repro.serving.request import Request
 
     cfg = _ecfg()
     eng = Engine.build(cfg)  # supplies a live plan geometry (4 shards)
-    backend = make_cache_backend(
-        "slot", cfg.model, cfg.compression, n_shards=cfg.n_shards,
-        max_live_tokens_per_shard=10_000)
-    state = backend.init_state(eng.plan_arrays, 2, jnp.float32)
     req = Request(req_id=0, prompt=np.zeros(16, np.int32), arrival_step=0,
                   max_new_tokens=4)
-    cost = backend.per_shard_cost(req)
-    assert cost.shape == (cfg.n_shards,)
-    assert cost.sum() > 0
+    probe = _partitioned_backend(cfg, 0)
+    probe.init_state(eng.plan_arrays, 2, jnp.float32)
+    need = probe._partition_need(16, 4, worst_case=False)  # (L, P)
+    assert need.shape == (cfg.model.n_layers, cfg.n_shards)
+    worst = probe._partition_need(16, 4, worst_case=True)
+    part = int(max(need.max(), worst.max())) + 8  # every partition fits
+    backend = _partitioned_backend(cfg, cfg.n_shards * part)
+    state = backend.init_state(eng.plan_arrays, 2, jnp.float32)
     assert backend.admissible(state, req)
-    # shrink the per-shard budget below the hottest shard's projected cost
-    backend.max_live_tokens_per_shard = int(cost.max()) - 1
+    assert backend.never_fits(req) is None
+    # drain the hottest shard's partition below its share, every layer
+    hot = int(need.max(axis=0).argmax())
+    for lyr in range(need.shape[0]):
+        free = backend.pool.free_blocks_by_partition()[lyr, hot]
+        backend.pool.alloc(lyr, int(free - need[lyr, hot] + 1),
+                           partition=hot)
+    assert (backend.pool.free_blocks() >= need.sum(axis=1)).all()
     assert not backend.admissible(state, req)
-    assert "per-shard" in backend.never_fits(req)
 
 
 def test_scheduler_rejects_request_never_fitting_per_shard():
-    cfg = _ecfg(scheduler=SchedulerConfig(max_rows=2, enable_replan=False,
-                                          max_live_tokens_per_shard=8),
+    """A request whose worst-case need overflows one model shard's
+    partition fails at submit, and the reason names that partition."""
+    from repro.serving.request import Request
+    from repro.serving.scheduler import Scheduler
+
+    cfg = _ecfg(scheduler=SchedulerConfig(max_rows=2, enable_replan=False),
                 max_seq_len=40)
     eng = Engine.build(cfg)
-    with pytest.raises(ValueError, match="never be admitted"):
-        eng.submit(np.zeros(16, np.int32), max_new_tokens=4)
+    req = Request(req_id=0, prompt=np.zeros(16, np.int32), arrival_step=0,
+                  max_new_tokens=4)
+    probe = _partitioned_backend(cfg, 0)
+    probe.init_state(eng.plan_arrays, 2, jnp.float32)
+    worst = probe._partition_need(16, 4, worst_case=True)  # (L, P)
+    # partitions one block short of the hottest (layer, shard) need
+    part = int(worst.max())
+    sched = Scheduler(cfg.model, eng.params, eng.plan, cfg.compression,
+                      cfg.scheduler,
+                      backend=_partitioned_backend(cfg, cfg.n_shards * part))
+    lyr, shard = np.unravel_index(int(worst.argmax()), worst.shape)
+    with pytest.raises(ValueError, match="never be admitted") as err:
+        sched.submit(req)
+    assert f"(layer {lyr}, model shard {shard}) partition" in str(err.value)
 
 
 # ---------------------------------------------------------------------------
@@ -365,16 +396,15 @@ def _run_subproc(cases):
     return json.loads(out.stdout.strip().splitlines()[-1])
 
 
-@pytest.mark.parametrize("backend", ["slot", "paged"])
+@pytest.mark.parametrize("backend", ["paged"])
 def test_mesh_parity_multidevice_subprocess(backend):
     """local and mesh executors produce identical tokens and cache lengths
     on imbalanced plans with replicas — 2-device (1x2) and 8-device (2x4)
     meshes, profile-seed variation on the 8-device case — and the decode
     StepFn compiles exactly once per engine across generate + replan."""
     cases = [(backend, 1, 2, 2, 2.0, 1),
-             (backend, 2, 4, 4, 2.0, 1)]
-    if backend == "slot":  # property-style variation (kept off the slow arm)
-        cases.append((backend, 2, 4, 4, 3.0, 7))
+             (backend, 2, 4, 4, 2.0, 1),
+             (backend, 2, 4, 4, 3.0, 7)]
     results = _run_subproc(cases)
     gen = [r for r in results if r["case"][0] == backend]
     cont = [r for r in results if r["case"][0] == "continuous"]
@@ -390,7 +420,7 @@ def test_mesh_parity_multidevice_subprocess(backend):
     for r in cont:
         assert r["tokens_equal"], r
         assert r["decode_traces"] == 1, r
-        if backend == "paged":  # every admission spliced in place on the
-            # mesh: donation aliases only where the pools keep their sharding
-            assert r["splices"] == {"inplace": r["admissions"], "copy": 0}, r
-            assert r["admissions"] == 5, r
+        # every admission spliced in place on the mesh: donation aliases
+        # only where the pools keep their sharding
+        assert r["splices"] == {"inplace": r["admissions"], "copy": 0}, r
+        assert r["admissions"] == 5, r

@@ -33,7 +33,7 @@ from tests._hypothesis_compat import given, settings, st
 ARCH = "minitron-8b"
 
 
-def _cfg(backend="slot", rows=2, n_blocks=0, block_size=8, **sched_kw):
+def _cfg(rows=2, n_blocks=0, block_size=8, **sched_kw):
     scfg = dict(max_rows=rows, enable_replan=False)
     scfg.update(sched_kw)
     return EngineConfig.smoke(
@@ -44,7 +44,6 @@ def _cfg(backend="slot", rows=2, n_blocks=0, block_size=8, **sched_kw):
         planner=PlannerConfig(mode="fairkv_dp", extra_copies=4,
                               batch_cap=rows),
         scheduler=SchedulerConfig(**scfg),
-        cache_backend=backend,
         paging=PagingConfig(block_size=block_size, n_blocks=n_blocks))
 
 
@@ -52,7 +51,7 @@ def _cfg(backend="slot", rows=2, n_blocks=0, block_size=8, **sched_kw):
 def shared_params():
     """Build once; every engine in this module reuses the params (and the
     jit cache, since shapes match)."""
-    cfg = _cfg("slot")
+    cfg = _cfg()
     return cfg, Engine.build(cfg).params
 
 
@@ -297,7 +296,7 @@ def test_cancel_returns_blocks_to_pool(shared_params):
     to the pool immediately (admitting a new request proves capacity), the
     allocator invariants hold, and full drain-out ends at zero in-use."""
     _, params = shared_params
-    eng = Engine.build(_cfg("paged", rows=2), params=params)
+    eng = Engine.build(_cfg(rows=2), params=params)
     vocab = eng.cfg.model.vocab_size
     reqs = synthesize_requests(3, 5.0, vocab, min_prompt=12, max_prompt=20,
                                max_new_tokens=6, seed=3)
@@ -501,14 +500,13 @@ def test_backend_admissible_charges_pending(shared_params):
     un-spliced state; the ``pending`` charge must make the joint check
     fail where each individual one passes."""
     cfg, params = shared_params
-    eng = Engine.build(cfg, params=params)
+    req = Request(req_id=0, prompt=np.zeros(16, np.int32), max_new_tokens=4)
+    probe = Engine.build(cfg, params=params)._ensure_scheduler().backend
+    need = probe._layer_blocks(16, 4, worst_case=False)
+    # a pool that holds one request's charge and a half: one fits, not two
+    eng = Engine.build(_cfg(n_blocks=1 + int(need.max() * 1.5)),
+                       params=params)
     sched = eng._ensure_scheduler()
     b = sched.backend
-    req = Request(req_id=0, prompt=np.zeros(16, np.int32), max_new_tokens=4)
-    old = b.max_live_tokens
-    try:
-        b.max_live_tokens = int(b.request_cost(req) * 1.5)  # one fits
-        assert b.admissible(sched.state, req)
-        assert not b.admissible(sched.state, req, pending=[req])
-    finally:
-        b.max_live_tokens = old
+    assert b.admissible(sched.state, req)
+    assert not b.admissible(sched.state, req, pending=[req])

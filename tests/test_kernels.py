@@ -1,70 +1,14 @@
-"""Pallas kernels: shape/dtype sweeps vs the ref.py pure-jnp oracles
-(interpret mode executes the kernel body on CPU)."""
+"""Pallas snapkv scoring kernel: shape/dtype sweeps vs the ref.py pure-jnp
+oracle (interpret mode executes the kernel body on CPU).  The paged decode
+kernel's sweeps are in tests/test_paged_kernel.py."""
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels.fairkv_decode import fairkv_decode_pallas
-from repro.kernels.ref import fairkv_decode_ref, snapkv_scores_ref
+from repro.kernels.ref import snapkv_scores_ref
 from repro.kernels.snapkv_select import snapkv_scores_pallas
 
 RNG = np.random.default_rng(0)
-
-
-def _decode_case(B, S, G, Dh, C, block_c, window=0, cap=0.0,
-                 dtype=jnp.float32, empty_rows=False):
-    q = jnp.asarray(RNG.normal(size=(B, S, G, Dh)), dtype)
-    k = jnp.asarray(RNG.normal(size=(S, B, C, Dh)), dtype)
-    v = jnp.asarray(RNG.normal(size=(S, B, C, Dh)), dtype)
-    lo = 0 if empty_rows else 1
-    lengths = jnp.asarray(RNG.integers(lo, C + 1, size=(S, B)), jnp.int32)
-    if empty_rows:
-        lengths = lengths.at[0].set(0)  # a fully-empty slot
-    kpos = jnp.broadcast_to(jnp.arange(C, dtype=jnp.int32), (S, B, C))
-    qpos = jnp.full((B,), C + 7, jnp.int32)
-    ref = fairkv_decode_ref(q, k, v, lengths, cap, k_pos=kpos, q_pos=qpos,
-                            window=window)
-    out = fairkv_decode_pallas(q, k, v, lengths, attn_cap=cap, k_pos=kpos,
-                               q_pos=qpos, window=window, block_c=block_c,
-                               interpret=True)
-    return float(jnp.abs(out.astype(jnp.float32)
-                         - ref.astype(jnp.float32)).max())
-
-
-@pytest.mark.parametrize("B,S,G,Dh,C,block", [
-    (4, 8, 8, 64, 256, 128),   # GQA 8:1, qwen-like
-    (2, 16, 1, 128, 200, 64),  # MHA, ragged capacity
-    (3, 5, 4, 32, 96, 32),     # hymba-ish odd slots
-    (1, 16, 8, 128, 1600, 256),  # decode_32k operating point, B=1
-    (2, 4, 2, 16, 64, 64),     # single block
-])
-def test_fairkv_decode_shapes(B, S, G, Dh, C, block):
-    assert _decode_case(B, S, G, Dh, C, block) < 1e-5
-
-
-@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 1e-5), (jnp.bfloat16, 0.03)])
-def test_fairkv_decode_dtypes(dtype, tol):
-    assert _decode_case(4, 8, 8, 64, 256, 128, dtype=dtype) < tol
-
-
-def test_fairkv_decode_window():
-    assert _decode_case(3, 5, 4, 32, 96, 32, window=40) < 1e-5
-
-
-def test_fairkv_decode_softcap():
-    assert _decode_case(2, 4, 8, 64, 256, 128, cap=50.0) < 1e-5
-
-
-def test_fairkv_decode_empty_rows_zero_output():
-    """Unowned rows (len==0) must give exactly 0 — the psum-reassembly
-    contract (DESIGN.md §2)."""
-    B, S, G, Dh, C = 2, 4, 4, 32, 64
-    q = jnp.asarray(RNG.normal(size=(B, S, G, Dh)), jnp.float32)
-    k = jnp.asarray(RNG.normal(size=(S, B, C, Dh)), jnp.float32)
-    v = jnp.asarray(RNG.normal(size=(S, B, C, Dh)), jnp.float32)
-    lengths = jnp.zeros((S, B), jnp.int32)
-    out = fairkv_decode_pallas(q, k, v, lengths, interpret=True)
-    assert float(jnp.abs(out).max()) == 0.0
 
 
 def _scores_case(B, W, Hq, Hkv, Dh, T, block_t, cap=0.0, dtype=jnp.float32):

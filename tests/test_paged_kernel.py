@@ -1,10 +1,12 @@
-"""Native paged decode kernel (`kernels/paged_fairkv_decode.py`): interpret
-mode vs the ``ref.paged_fairkv_decode_ref`` oracle over ragged lengths,
-null-block tables, partial last blocks, window + softcap, and dtypes; the
-``ops.paged_fairkv_decode`` impl dispatch; and gather↔native↔slot three-way
-token parity through `Engine.generate` on the local and 2x4-mesh executors
-(the mesh case runs in a subprocess so the fake-device count is set before
-the first jax import, mirroring tests/test_executor.py).
+"""Native paged decode kernel (`kernels/paged_fairkv_decode.py`), the
+serving path's one decode attention: interpret mode vs the
+``ref.paged_fairkv_decode_ref`` oracle over ragged lengths, null-block
+tables, partial last blocks, window + softcap, and dtypes; the
+``ops.paged_fairkv_decode`` impl dispatch; and token parity of the kernel,
+the oracle and the continuous scheduler through `Engine.generate` on the
+local and 2x4-mesh executors (the mesh case runs in a subprocess so the
+fake-device count is set before the first jax import, mirroring
+tests/test_executor.py).
 """
 import json
 import subprocess
@@ -59,6 +61,11 @@ def test_paged_kernel_ragged_lengths(S, B, G, C, bs, seed):
     (2, 2, 8, 64, 256, 32),   # GQA 8:1
     (3, 2, 1, 128, 200, 64),  # MHA, capacity not a block multiple
     (2, 2, 2, 32, 64, 64),    # single block per row
+    (8, 4, 8, 64, 256, 128),  # GQA 8:1, qwen-like, two blocks per row
+    (16, 2, 1, 128, 200, 64),  # MHA over 16 slots, ragged capacity
+    (5, 3, 4, 32, 96, 32),    # hymba-ish odd slot count
+    (16, 1, 8, 128, 1600, 256),  # decode_32k operating point, B=1
+    (4, 2, 2, 16, 64, 64),    # head_dim 16, single block
 ])
 def test_paged_kernel_shapes(S, B, G, Dh, C, bs):
     rng = np.random.default_rng(0)
@@ -79,6 +86,24 @@ def test_paged_kernel_null_block_tables():
     out = paged_fairkv_decode_pallas(q, kp, vp, pp, tbl, lens, C,
                                      interpret=True)
     assert float(jnp.abs(out).max()) == 0.0
+
+
+def test_paged_kernel_empty_slot_among_ragged_rows():
+    """One fully-empty slot (all-null tables) among ragged ones: its output
+    is exactly 0 and every other (slot, row) matches the oracle."""
+    rng = np.random.default_rng(1)
+    S, B, G, Dh, C, bs = 3, 2, 4, 32, 96, 16
+    lengths = rng.integers(1, C + 1, size=(S, B)).astype(np.int32)
+    lengths[0] = 0  # a fully-empty slot
+    kp, vp, pp, tbl, lens = make_paged_layer(rng, S, B, C, bs, Dh,
+                                             lengths=lengths)
+    assert int(np.asarray(tbl[0]).max()) == 0  # nothing allocated
+    q = jnp.asarray(rng.normal(size=(B, S, G, Dh)), jnp.float32)
+    out = paged_fairkv_decode_pallas(q, kp, vp, pp, tbl, lens, C,
+                                     interpret=True)
+    assert float(jnp.abs(out[:, 0]).max()) == 0.0
+    ref = paged_fairkv_decode_ref(q, kp, vp, pp, tbl, lens, C)
+    assert float(jnp.abs(out - ref).max()) < 1e-5
 
 
 def test_paged_kernel_mixed_null_rows():
@@ -145,8 +170,8 @@ needs_fp8 = pytest.mark.skipif(not fp8_supported(),
 
 def _compare_quant(rng, S, B, G, Dh, C, bs, kinds, window=0, cap=0.0,
                    lengths=None):
-    """(pallas-vs-ref, gather-vs-ref, quantized-ref-vs-fp32-ref) max errors
-    for one random quantized layer; ``kinds`` is the (S,) per-slot grid."""
+    """(pallas-vs-ref, quantized-ref-vs-fp32-ref) max errors for one
+    random quantized layer; ``kinds`` is the (S,) per-slot grid."""
     kp, vp, pp, tbl, lens = make_paged_layer(rng, S, B, C, bs, Dh,
                                              lengths=lengths)
     kinds = jnp.asarray(np.broadcast_to(kinds, (S,)), jnp.int32)
@@ -161,14 +186,11 @@ def _compare_quant(rng, S, B, G, Dh, C, bs, kinds, window=0, cap=0.0,
     out = paged_fairkv_decode_pallas(q, kq, vq, pp, tbl, lens, C,
                                      attn_cap=cap, q_pos=qpos, window=window,
                                      interpret=True, **quant_kw)
-    gat = K.paged_fairkv_decode(q, kq, vq, pp, tbl, lens, C, attn_cap=cap,
-                                q_pos=qpos, window=window, impl="gather",
-                                **quant_kw)
 
     def err(a, b):
         return float(jnp.abs(a - b).max())
 
-    return err(out, ref), err(gat, ref), err(ref, fp32)
+    return err(out, ref), err(ref, fp32)
 
 
 @settings(max_examples=8)
@@ -178,15 +200,13 @@ def _compare_quant(rng, S, B, G, Dh, C, bs, kinds, window=0, cap=0.0,
 def test_paged_kernel_quantized_ragged(S, B, G, C, bs, kind, seed):
     """Quantized kernel parity over the same adversarial space as the fp32
     sweep: ragged lengths, shuffled blocks, null rows, partial last blocks.
-    All three impls dequantize identically (tight bound vs the quantized
+    Kernel and oracle dequantize identically (tight bound vs the quantized
     oracle) and the codec error vs fp32 stays inside the per-dtype bound."""
     if kind == KIND_FP8 and not fp8_supported():
         return
     rng = np.random.default_rng(seed)
-    pallas_err, gather_err, quant_err = _compare_quant(
-        rng, S, B, G, 32, C, bs, kind)
+    pallas_err, quant_err = _compare_quant(rng, S, B, G, 32, C, bs, kind)
     assert pallas_err < 1e-5
-    assert gather_err < 1e-5
     assert quant_err < QUANT_TOL[kind]
 
 
@@ -194,9 +214,9 @@ def test_paged_kernel_quantized_ragged(S, B, G, C, bs, kind, seed):
                                   pytest.param(KIND_FP8, marks=needs_fp8)])
 def test_paged_kernel_quantized_window_softcap(kind):
     rng = np.random.default_rng(21)
-    pallas_err, gather_err, quant_err = _compare_quant(
+    pallas_err, quant_err = _compare_quant(
         rng, 3, 2, 4, 32, 96, 16, kind, window=40, cap=30.0)
-    assert pallas_err < 1e-5 and gather_err < 1e-5
+    assert pallas_err < 1e-5
     assert quant_err < QUANT_TOL[kind]
 
 
@@ -206,9 +226,8 @@ def test_paged_kernel_quantized_mixed_kinds():
     must select the right dequant interpretation per program."""
     rng = np.random.default_rng(22)
     kinds = np.arange(4) % 2  # alternating int8 / fp8
-    pallas_err, gather_err, quant_err = _compare_quant(
-        rng, 4, 3, 4, 32, 96, 16, kinds)
-    assert pallas_err < 1e-5 and gather_err < 1e-5
+    pallas_err, quant_err = _compare_quant(rng, 4, 3, 4, 32, 96, 16, kinds)
+    assert pallas_err < 1e-5
     assert quant_err < QUANT_TOL[KIND_FP8]
 
 
@@ -238,10 +257,10 @@ def test_paged_kernel_quantized_null_block_tables():
 
 def _compare_mq(rng, S, B, Q, G, Dh, C, bs, window=0, cap=0.0,
                 dtype=jnp.float32, q_lens=None, kinds=None):
-    """(pallas-vs-ref, gather-vs-ref) max errors for a 5-D multi-query
-    layer.  ``lengths`` count the cache AFTER the speculative appends, so
-    they are drawn ≥ Q per (slot, row); ``q_lens`` defaults to a random
-    ragged draw in [1, Q]."""
+    """Pallas-vs-ref max error for a 5-D multi-query layer.  ``lengths``
+    count the cache AFTER the speculative appends, so they are drawn ≥ Q
+    per (slot, row); ``q_lens`` defaults to a random ragged draw in
+    [1, Q]."""
     lengths = rng.integers(Q, C + 1, size=(S, B)).astype(np.int32)
     kp, vp, pp, tbl, lens = make_paged_layer(rng, S, B, C, bs, Dh,
                                              dtype=np.dtype(dtype),
@@ -264,15 +283,8 @@ def _compare_mq(rng, S, B, Q, G, Dh, C, bs, window=0, cap=0.0,
                                      attn_cap=cap, q_pos=qpos,
                                      q_lens=q_lens, window=window,
                                      interpret=True, **quant_kw)
-    gat = K.paged_fairkv_decode(q, kp, vp, pp, tbl, lens, C, attn_cap=cap,
-                                q_pos=qpos, q_lens=q_lens, window=window,
-                                impl="gather", **quant_kw)
-
-    def err(a, b):
-        return float(jnp.abs(a.astype(jnp.float32)
-                             - b.astype(jnp.float32)).max())
-
-    return err(out, ref), err(gat, ref)
+    return float(jnp.abs(out.astype(jnp.float32)
+                         - ref.astype(jnp.float32)).max())
 
 
 @settings(max_examples=10)
@@ -281,12 +293,10 @@ def _compare_mq(rng, S, B, Q, G, Dh, C, bs, window=0, cap=0.0,
        bs=st.sampled_from([2, 8, 16, 32]), seed=st.integers(0, 10))
 def test_paged_kernel_mq_ragged(S, B, Q, G, C, bs, seed):
     """Random speculative windows (ragged ``q_lens``) over ragged cache
-    lengths: the in-window causal mask must match the mq oracle in both
-    the pallas and gather impls."""
+    lengths: the kernel's in-window causal mask must match the mq
+    oracle."""
     rng = np.random.default_rng(seed)
-    pallas_err, gather_err = _compare_mq(rng, S, B, Q, G, 32, C, bs)
-    assert pallas_err < 1e-5
-    assert gather_err < 1e-5
+    assert _compare_mq(rng, S, B, Q, G, 32, C, bs) < 1e-5
 
 
 def test_paged_kernel_mq_q1_matches_4d():
@@ -315,9 +325,7 @@ def test_paged_kernel_mq_causal_window():
     rng = np.random.default_rng(31)
     S, B, Q, G, Dh, C, bs = 2, 2, 3, 2, 32, 64, 16
     q_lens = np.array([3, 2], np.int32)
-    pallas_err, gather_err = _compare_mq(rng, S, B, Q, G, Dh, C, bs,
-                                         q_lens=q_lens)
-    assert pallas_err < 1e-5 and gather_err < 1e-5
+    assert _compare_mq(rng, S, B, Q, G, Dh, C, bs, q_lens=q_lens) < 1e-5
 
 
 def test_paged_kernel_mq_garbage_lanes_do_not_leak():
@@ -346,20 +354,17 @@ def test_paged_kernel_mq_garbage_lanes_do_not_leak():
 
 def test_paged_kernel_mq_window_softcap():
     rng = np.random.default_rng(33)
-    pallas_err, gather_err = _compare_mq(rng, 2, 2, 3, 4, 32, 96, 16,
-                                         window=40, cap=30.0)
-    assert pallas_err < 1e-5 and gather_err < 1e-5
+    assert _compare_mq(rng, 2, 2, 3, 4, 32, 96, 16, window=40,
+                       cap=30.0) < 1e-5
 
 
 @pytest.mark.parametrize("kind", [KIND_INT8,
                                   pytest.param(KIND_FP8, marks=needs_fp8)])
 def test_paged_kernel_mq_quantized(kind):
-    """Quantized pools through the multi-query path: all impls dequantize
-    identically under the speculative causal mask."""
+    """Quantized pools through the multi-query path: kernel and oracle
+    dequantize identically under the speculative causal mask."""
     rng = np.random.default_rng(34)
-    pallas_err, gather_err = _compare_mq(rng, 3, 2, 3, 4, 32, 96, 16,
-                                         kinds=kind)
-    assert pallas_err < 1e-5 and gather_err < 1e-5
+    assert _compare_mq(rng, 3, 2, 3, 4, 32, 96, 16, kinds=kind) < 1e-5
 
 
 # ---------------------------------------------------------------------------
@@ -374,14 +379,10 @@ def test_ops_dispatch_impls_agree():
     qpos = jnp.full((2,), 99, jnp.int32)
     outs = {impl: K.paged_fairkv_decode(q, kp, vp, pp, tbl, lens, 96,
                                         q_pos=qpos, impl=impl)
-            for impl in ("jnp", "gather", "pallas")}
-    if K._force_interpret():
-        # the gather's inner slot kernel is pallas-interpret here (the CI
-        # kernels-interpret gate) — reduction order differs from the ref
-        assert float(jnp.abs(outs["gather"] - outs["jnp"]).max()) < 1e-5
-    else:
-        # jnp and gather are the same math in the same order -> exact
-        assert bool((outs["jnp"] == outs["gather"]).all())
+            for impl in ("jnp", "pallas")}
+    # the oracle is the same math as the ref in the same order -> exact
+    ref = paged_fairkv_decode_ref(q, kp, vp, pp, tbl, lens, 96, q_pos=qpos)
+    assert bool((outs["jnp"] == ref).all())
     assert float(jnp.abs(outs["pallas"] - outs["jnp"]).max()) < 1e-5
 
 
@@ -390,6 +391,27 @@ def test_ops_dispatch_rejects_unknown_impl():
     with pytest.raises(ValueError, match="bogus"):
         K.paged_fairkv_decode(q, q, q, q[..., 0], q[..., 0, 0], None, 8,
                               impl="bogus")
+
+
+def test_decode_impls_are_the_kernel_and_its_oracle(monkeypatch):
+    """The kernel and the jnp oracle are the only decode attentions: the
+    retired "gather" impl is refused at dispatch and in `PagingConfig`, and
+    `pallas_in_decode` reports a Pallas kernel exactly when dispatch picks
+    the kernel (the mesh executor's check_vma switch)."""
+    from repro.api import PagingConfig
+    assert K.PAGED_DECODE_IMPLS == ("auto", "pallas", "jnp")
+    q = jnp.zeros((1, 1, 1, 8), jnp.float32)
+    with pytest.raises(ValueError, match="gather"):
+        K.paged_fairkv_decode(q, q, q, q[..., 0], q[..., 0, 0], None, 8,
+                              impl="gather")
+    with pytest.raises(ValueError, match="gather"):
+        PagingConfig(decode_impl="gather")
+    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "1")
+    assert K.pallas_in_decode("auto") and K.pallas_in_decode("pallas")
+    assert not K.pallas_in_decode("jnp")
+    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "0")
+    assert not K.pallas_in_decode("auto")
+    assert K.pallas_in_decode("pallas")
 
 
 def test_force_interpret_env_routes_auto_to_pallas(monkeypatch):
@@ -428,11 +450,11 @@ def test_paging_config_validates_decode_impl():
 
 
 # ---------------------------------------------------------------------------
-# three-way token parity through Engine.generate (local executor)
+# three-way token parity: oracle, kernel, continuous scheduler (local)
 # ---------------------------------------------------------------------------
 
 
-def _engine_cfg(backend, impl="auto", rows=2, T=16, gen=3, kv_dtype="fp32"):
+def _engine_cfg(impl="auto", rows=2, T=16, gen=3, kv_dtype="fp32"):
     from repro.api import (CompressionConfig, EngineConfig, PagingConfig,
                            PlannerConfig, SchedulerConfig)
     return EngineConfig.smoke(
@@ -443,55 +465,63 @@ def _engine_cfg(backend, impl="auto", rows=2, T=16, gen=3, kv_dtype="fp32"):
         planner=PlannerConfig(mode="fairkv_dp", extra_copies=4,
                               batch_cap=rows),
         scheduler=SchedulerConfig(max_rows=rows, enable_replan=False),
-        cache_backend=backend,
         paging=PagingConfig(block_size=8, decode_impl=impl,
                             kv_dtype=kv_dtype))
 
 
 def test_engine_generate_three_way_token_parity_local():
-    """gather, native-pallas (interpret), and jnp paged decode — and the
-    slot backend — produce identical tokens through `Engine.generate`."""
+    """The jnp oracle and the native kernel (interpret) produce identical
+    tokens and lengths through `Engine.generate`, and the continuous
+    scheduler — each prompt prefilled alone and paged into its row —
+    generates the one-shot path's tokens."""
     from repro.api import Engine
+    from repro.serving.request import Request
     B, T, GEN = 2, 16, 3
     prompts = np.random.default_rng(0).integers(0, 256, (B, T))
-    slot_eng = Engine.build(_engine_cfg("slot"))
-    base = slot_eng.generate(prompts, GEN)
-    for impl in ("jnp", "gather", "pallas"):
-        eng = Engine.build(_engine_cfg("paged", impl), params=slot_eng.params)
-        res = eng.generate(prompts, GEN)
-        assert np.array_equal(base.tokens, res.tokens), impl
-        assert np.array_equal(base.lengths, res.lengths), impl
-        # one decode trace per engine: the impl knob is static config
-        assert eng.executor.decode_traces == 1, impl
+    ref_eng = Engine.build(_engine_cfg("jnp"))
+    base = ref_eng.generate(prompts, GEN)
+    assert ref_eng.executor.decode_traces == 1
+    eng = Engine.build(_engine_cfg("pallas"), params=ref_eng.params)
+    res = eng.generate(prompts, GEN)
+    assert np.array_equal(base.tokens, res.tokens)
+    assert np.array_equal(base.lengths, res.lengths)
+    # one decode trace per engine: the impl knob is static config
+    assert eng.executor.decode_traces == 1
+    reqs = [Request(req_id=b, prompt=prompts[b].astype(np.int32),
+                    max_new_tokens=GEN + 1) for b in range(B)]
+    out = ref_eng.run_trace(reqs, max_steps=100)
+    assert out["finished"] == out["total"] == B
+    for b, r in enumerate(reqs):
+        assert r.generated == base.tokens[b].tolist(), b
 
 
 @pytest.mark.parametrize("kv_dtype", ["int8",
                                       pytest.param("fp8", marks=needs_fp8)])
 def test_engine_generate_quantized_impl_agreement(kv_dtype):
-    """Quantized end-to-end: all three paged decode impls see the identical
-    codes/scales, so their tokens must agree with each other; lengths match
-    the fp32 slot baseline; and the kv_dtype knob is static StepFn config —
-    exactly one decode trace per engine (compile-once per dtype)."""
+    """Quantized end-to-end: kernel and oracle see the identical
+    codes/scales, so their tokens must agree; lengths match the fp32
+    baseline; and the kv_dtype knob is static StepFn config — exactly one
+    decode trace per engine (compile-once per dtype)."""
     from repro.api import Engine
     B, T, GEN = 2, 16, 3
     prompts = np.random.default_rng(0).integers(0, 256, (B, T))
-    slot_eng = Engine.build(_engine_cfg("slot"))
-    base = slot_eng.generate(prompts, GEN)
+    fp32_eng = Engine.build(_engine_cfg("jnp"))
+    base = fp32_eng.generate(prompts, GEN)
     results = {}
-    for impl in ("jnp", "gather", "pallas"):
-        eng = Engine.build(_engine_cfg("paged", impl, kv_dtype=kv_dtype),
-                           params=slot_eng.params)
+    for impl in ("jnp", "pallas"):
+        eng = Engine.build(_engine_cfg(impl, kv_dtype=kv_dtype),
+                           params=fp32_eng.params)
         res = eng.generate(prompts, GEN)
         assert np.array_equal(base.lengths, res.lengths), impl
         assert eng.executor.decode_traces == 1, impl
         results[impl] = res.tokens
-    assert np.array_equal(results["jnp"], results["gather"])
     assert np.array_equal(results["jnp"], results["pallas"])
 
 
 # ---------------------------------------------------------------------------
-# three-way token parity on the 2x4 mesh executor (subprocess: the fake
-# device count must be set before the first jax import)
+# three-way token parity: local oracle, mesh oracle, mesh kernel on the 2x4
+# mesh executor (subprocess: the fake device count must be set before the
+# first jax import)
 # ---------------------------------------------------------------------------
 
 
@@ -508,7 +538,7 @@ from repro.launch.mesh import make_host_mesh
 
 B, T, GEN = 4, 16, 3
 
-def cfg_for(backend, impl, executor):
+def cfg_for(impl, executor):
     return EngineConfig.smoke(
         "minitron-8b", n_shards=4, max_seq_len=T + GEN + 8,
         compression=CompressionConfig(policy="ada_snapkv", budget=16,
@@ -516,16 +546,16 @@ def cfg_for(backend, impl, executor):
                                       decode_margin=8),
         planner=PlannerConfig(mode="fairkv_dp", extra_copies=4, batch_cap=B),
         scheduler=SchedulerConfig(max_rows=B, enable_replan=False),
-        cache_backend=backend, executor=executor,
+        executor=executor,
         paging=PagingConfig(block_size=8, decode_impl=impl))
 
 prompts = np.random.default_rng(0).integers(0, 256, (B, T))
-loc = Engine.build(cfg_for("slot", "auto", "local"))
+loc = Engine.build(cfg_for("jnp", "local"))
 base = loc.generate(prompts, GEN)
 out = {}
-for impl in ("jnp", "gather", "pallas"):
+for impl in ("jnp", "pallas"):
     mesh = make_host_mesh(model=4, data=2)
-    eng = Engine.build(cfg_for("paged", impl, "mesh"), mesh=mesh,
+    eng = Engine.build(cfg_for(impl, "mesh"), mesh=mesh,
                        params=loc.params)
     res = eng.generate(prompts, GEN)
     out[impl] = {
@@ -538,8 +568,8 @@ print(json.dumps(out))
 
 
 def test_engine_generate_three_way_token_parity_mesh_2x4():
-    """All three paged decode impls on the (data=2, model=4) mesh executor
-    match the local slot baseline token-for-token, one decode trace each."""
+    """The oracle and the kernel on the (data=2, model=4) mesh executor
+    match the local oracle token-for-token, one decode trace each."""
     import repro
     src = list(repro.__path__)[0].rsplit("/repro", 1)[0]
     code = SUBPROC.replace("__SRC__", repr(src))

@@ -10,6 +10,8 @@ from repro.configs import get_smoke_config
 from repro.core import PlannerConfig, build_plan, synthetic_profile
 from repro.kernels import ops as K
 from repro.models import init_params
+from repro.paging.backend import PagedBackend
+from repro.paging.block_pool import PagingConfig
 from repro.serving import (
     Request,
     RequestState,
@@ -95,7 +97,10 @@ def test_trigger_cooldown_blocks_refire():
 # ---------------------------------------------------------------------------
 
 
-def _setup(max_rows=2, mode="fairkv_dp", ch=4, **scfg_kw):
+def _setup(max_rows=2, mode="fairkv_dp", ch=4, n_blocks=0, block_size=16,
+           **scfg_kw):
+    """A scheduler over a paged pool of ``n_blocks`` per layer (0: the
+    dense worst case, never short) in blocks of ``block_size`` tokens."""
     cfg = get_smoke_config(ARCH)
     params = init_params(cfg, jax.random.PRNGKey(0), dtype=jnp.float32,
                          max_seq_len=64)
@@ -106,7 +111,10 @@ def _setup(max_rows=2, mode="fairkv_dp", ch=4, **scfg_kw):
     pcfg = PlannerConfig(mode=mode, extra_copies=ch, batch_cap=max_rows)
     plan = build_plan(prof, 4, pcfg)
     scfg = SchedulerConfig(max_rows=max_rows, collect_logits=True, **scfg_kw)
-    sched = Scheduler(cfg, params, plan, ccfg, scfg, planner_cfg=pcfg)
+    backend = PagedBackend(cfg, ccfg, paging=PagingConfig(
+        block_size=block_size, n_blocks=n_blocks))
+    sched = Scheduler(cfg, params, plan, ccfg, scfg, planner_cfg=pcfg,
+                      backend=backend)
     return cfg, sched
 
 
@@ -142,7 +150,7 @@ def test_admission_blocks_on_full_batch_then_reuses_freed_row():
 
 def test_admission_rejects_impossible_token_budget():
     cfg, sched = _setup(max_rows=2, enable_replan=False,
-                        max_live_tokens=1)  # absurdly small budget
+                        n_blocks=2)  # absurdly small pool: 1 block/layer
     r = _req(0, 14, vocab=cfg.vocab_size)
     # the request could never fit -> fail fast instead of head-of-line block
     with pytest.raises(ValueError, match="never be admitted"):
@@ -153,33 +161,39 @@ def test_admission_respects_token_budget():
     cfg, probe = _setup(max_rows=2, enable_replan=False)
     a = _req(0, 14, gen=5, vocab=cfg.vocab_size)
     b = _req(1, 14, gen=8, vocab=cfg.vocab_size)
-    # budget fits one request (the larger of the two) but not both at once
-    budget = probe._estimated_cost(b) + 1
+    # a pool that holds b's admission charge (the larger of the two) but
+    # not b's next to a live request
+    need_b = probe.backend._layer_blocks(b.prompt_len, b.max_new_tokens,
+                                         worst_case=False)
     _, sched = _setup(max_rows=2, enable_replan=False,
-                      max_live_tokens=budget)
+                      n_blocks=int(need_b.max()) + 1)
     sched.submit(a)
     sched.submit(b)
     sched.step()
-    # free rows exist, but the projected total exceeds the budget -> b waits
+    # free rows exist, but the pool's free blocks do not cover b -> b waits
     assert a.state is RequestState.DECODING
     assert b.state is RequestState.QUEUED
     assert len(sched.freelist) == 1
     while not b.is_finished:
         sched.step()
-    assert b.admit_step >= a.finish_step  # admitted only after a freed tokens
+    assert b.admit_step >= a.finish_step  # admitted only after a freed blocks
+    assert sched.n_preemptions == 0
 
 
 def test_admission_projection_uses_policy_pool_bound():
-    """Audit regression (PR-3): the projected live-token cost must come
-    from the per-policy keep bounds (pool conservation), not the static
-    capacity C — the old ``L·H·min(prompt+gen, C)`` charge blocked
-    admissions the cache could easily hold.  The tighter bound must remain
-    a true upper bound on the realized footprint."""
-    cfg, probe = _setup(max_rows=2, enable_replan=False)
+    """Audit regression (PR-3): the projected cost must come from the
+    per-policy keep bounds (pool conservation), not the static capacity
+    C — the old ``L·H·min(prompt+gen, C)`` charge (here in blocks)
+    blocked admissions the cache could easily hold.  The tighter bound
+    must remain a true upper bound on the realized footprint."""
+    bs = 2  # fine blocks: the charge is the bound, not the rounding slack
+    cfg, probe = _setup(max_rows=2, enable_replan=False, block_size=bs)
     prompt, gen = 30, 4
     a = _req(0, prompt, gen=gen, vocab=cfg.vocab_size)
     cap = probe.ccfg.static_capacity()
-    old_cost = cfg.n_layers * cfg.n_kv_heads * min(prompt + gen, cap)
+    L, H = cfg.n_layers, cfg.n_kv_heads
+    old_layer = H * -(-min(prompt + gen, cap) // bs)
+    old_cost = L * old_layer
     new_cost = probe._estimated_cost(a)
     assert new_cost < old_cost, (new_cost, old_cost)
 
@@ -189,28 +203,28 @@ def test_admission_projection_uses_policy_pool_bound():
     live_max = 0
     while not a.is_finished:
         probe.step()
-        live_max = max(live_max, probe.live_tokens())
-    live_a_prefill = None  # prefill-only footprint for the budget below
+        live_max = max(live_max, probe.backend.pool.blocks_in_use())
     assert live_max <= new_cost, (live_max, new_cost)
 
-    # behavior: a budget the old projection would refuse now admits two
+    # behavior: a pool the old projection would refuse now admits two
     # requests concurrently
-    _, m = _setup(max_rows=2, enable_replan=False)
+    _, m = _setup(max_rows=2, enable_replan=False, block_size=bs)
     a1 = _req(0, prompt, gen=gen, vocab=cfg.vocab_size)
     m.submit(a1)
     m.step()
-    live_a_prefill = m.live_tokens()
-    budget = live_a_prefill + new_cost
-    assert budget < live_a_prefill + old_cost  # old rule: b would wait
-    _, sched = _setup(max_rows=2, enable_replan=False,
-                      max_live_tokens=budget)
+    held = int((m.backend.table > 0).sum(axis=(1, 2, 3)).max())
+    need = m.backend._layer_blocks(prompt, gen, worst_case=False)
+    usable = held + int(need.max())
+    assert usable < held + old_layer  # old rule: b would wait
+    _, sched = _setup(max_rows=2, enable_replan=False, block_size=bs,
+                      n_blocks=usable + 1)
     a2 = _req(0, prompt, gen=gen, vocab=cfg.vocab_size)
     b2 = _req(1, prompt, gen=gen, vocab=cfg.vocab_size)
     sched.submit(a2)
     sched.submit(b2)
     sched.step()
     assert a2.state is RequestState.DECODING
-    assert b2.state is RequestState.DECODING  # co-admitted under the budget
+    assert b2.state is RequestState.DECODING  # co-admitted in the pool
 
 
 # ---------------------------------------------------------------------------
@@ -233,14 +247,16 @@ def test_retired_row_is_zero_and_decode_output_exactly_zero():
     lens = np.asarray(cache.lengths)
     assert lens[:, :, row].sum() == 0
     assert (np.asarray(cache.positions)[row] == 0)
+    assert np.asarray(cache.block_table)[:, :, row].max() == 0  # no blocks
     # the decode kernel's output for the retired row is exactly zero
-    S = cache.k.shape[1]
+    S = cache.block_table.shape[1]
     rng = np.random.default_rng(0)
     q = jnp.asarray(rng.normal(size=(2, S, cfg.q_per_kv, cfg.head_dim)),
                     jnp.float32)
-    out = K.fairkv_decode(q, cache.k[0], cache.v[0], cache.lengths[0],
-                          k_pos=cache.pos[0],
-                          q_pos=jnp.zeros((2,), jnp.int32))
+    out = K.paged_fairkv_decode(
+        q, cache.k_pool[0], cache.v_pool[0], cache.pos_pool[0],
+        cache.block_table[0], cache.lengths[0], sched.backend.capacity,
+        q_pos=jnp.zeros((2,), jnp.int32))
     assert float(jnp.abs(out[row]).max()) == 0.0
     assert sched.freelist.in_use == 1  # the row went back to the freelist
 
@@ -292,6 +308,30 @@ def test_co_scheduled_logits_match_solo_run():
         assert solo.generated == shared.generated, shared.req_id
         for lg_solo, lg_shared in zip(solo.logits, shared.logits):
             np.testing.assert_allclose(lg_solo, lg_shared, atol=2e-4)
+
+
+def test_default_scheduler_runs_on_the_paged_pool():
+    """A scheduler built without a backend stores its live cache in a
+    paged pool sized to the dense worst case, and streams on it."""
+    from repro.paging.paged_cache import PagedCache
+    cfg = get_smoke_config(ARCH)
+    params = init_params(cfg, jax.random.PRNGKey(0), dtype=jnp.float32,
+                         max_seq_len=64)
+    ccfg = CompressionConfig(policy="ada_snapkv", budget=12, alpha_max=2.0,
+                             obs_window=8, sink=2, decode_margin=8)
+    plan = build_plan(synthetic_profile(cfg.n_layers, cfg.n_kv_heads,
+                                        budget=12, skew=1.0, seed=1), 4,
+                      PlannerConfig(mode="fairkv_dp", extra_copies=4,
+                                    batch_cap=2))
+    sched = Scheduler(cfg, params, plan, ccfg,
+                      SchedulerConfig(max_rows=2, enable_replan=False))
+    assert isinstance(sched.backend, PagedBackend)
+    assert isinstance(sched.state.cache, PagedCache)
+    reqs = [_req(0, 14, gen=3, vocab=cfg.vocab_size),
+            _req(1, 12, arrival=1, gen=3, vocab=cfg.vocab_size)]
+    out = _run_stream(sched, reqs)
+    assert out["finished"] == 2 and out["preemptions"] == 0
+    assert sched.backend.pool.blocks_in_use() == 0
 
 
 def test_attention_free_arch_streams():

@@ -8,12 +8,13 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.cache.slot_cache import PlanArrays, init_cache, append_token, ring_write_index
+from repro.cache.slot_cache import PlanArrays, ring_write_index
 from repro.compression.base import CompressionConfig
 from repro.compression.policies import BALANCED, IMBALANCED, POLICIES, select
 from repro.configs import get_smoke_config
 from repro.core import PlannerConfig, build_plan, synthetic_profile
 from repro.models import forward_train, init_params
+from repro.paging.backend import PagedBackend
 from repro.serving import decode_step, prefill, slotify_params
 
 FAST_ARCHS = ["minitron-8b", "gemma2-9b", "granite-moe-1b-a400m",
@@ -59,8 +60,12 @@ def _run(cfg, params, batch, tokens, ccfg, mode, ch, n_shards=4, steps=5):
     pa = PlanArrays.from_plan(plan)
     sp = slotify_params(params, plan, cfg)
     state, logits0, lens = prefill(sp, batch, cfg, pa, ccfg)
+    # the prefill's slot-layout cache is paged into the pool decode reads
+    backend = PagedBackend(cfg, ccfg)
+    state = backend.from_prefill(state, pa)
     out = [logits0]
     for t in range(steps):
+        state = backend.prepare_decode(state, None)
         state, lg = decode_step(sp, state, cfg, pa, ccfg,
                                 tokens=tokens[:, T + t])
         out.append(lg)
@@ -193,13 +198,22 @@ def test_ring_write_index_cycles_in_tail():
 
 
 def test_append_token_ownership():
-    cache = init_cache(n_layers=1, n_slots=4, batch=4, capacity=8,
-                       head_dim=4, dtype=jnp.float32)
+    """A decode append grows exactly the (slot, row) pairs the owner rule
+    gives it; the others stay empty (their writes hit the null block)."""
+    from repro.paging.block_pool import PagingConfig
+    from repro.paging.paged_cache import (build_table, init_paged_cache,
+                                          paged_append_token)
+    cache, pool = init_paged_cache(1, 4, 4, 8, 4, PagingConfig(block_size=4),
+                                   dtype=jnp.float32)
     prof = np.ones((1, 2))
     plan = build_plan(prof, 4, PlannerConfig(mode="sha", slots_per_shard=1))
     pa = PlanArrays.from_plan(plan)
     own = pa.owner_mask(0, 4)
+    table = build_table(np.zeros((1, 4, 4), np.int64), pool, 4, 2,
+                        own=np.asarray(own)[None])
+    cache = dataclasses.replace(cache, block_table=jnp.asarray(table))
     k_new = jnp.ones((4, 4, 4))
-    cache = append_token(cache, 0, k_new, k_new, own, jnp.int32(0), ring=2)
+    cache = paged_append_token(cache, 0, k_new, k_new, own, jnp.int32(0), 8,
+                               ring=2)
     lens = np.asarray(cache.lengths[0])
     assert (lens == np.asarray(own).astype(np.int32)).all()

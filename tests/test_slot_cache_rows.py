@@ -1,5 +1,7 @@
-"""Row-level slot-cache ops: reset_rows / insert_rows / migrate_cache and
-their interaction with the strided owner mask and ring-buffer appends."""
+"""Slot-layout ops around the live paged cache: the strided owner mask,
+row masks, and the head-layout gather / migrate a replan runs through.
+The paged cache's own row ops (release, splice, append) are tested in
+tests/test_paging.py."""
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -7,12 +9,9 @@ import pytest
 from repro.cache.slot_cache import (
     PlanArrays,
     SlotCache,
-    append_token,
     gather_head_layout,
     init_cache,
-    insert_rows,
     migrate_cache,
-    reset_rows,
     rows_to_mask,
 )
 from repro.core import PlannerConfig, build_plan, synthetic_profile
@@ -66,36 +65,8 @@ def test_owner_mask_all_matches_per_layer():
 
 
 # ---------------------------------------------------------------------------
-# reset_rows
+# row masks
 # ---------------------------------------------------------------------------
-
-
-def test_reset_rows_clears_only_target_rows():
-    pa = PlanArrays.from_plan(_plan())
-    cache = _filled_cache(pa)
-    before = np.asarray(cache.lengths)
-    out = reset_rows(cache, jnp.asarray([1]))
-    # row 1 fully cleared
-    assert np.asarray(out.lengths)[:, :, 1].sum() == 0
-    assert np.abs(np.asarray(out.k)[:, :, 1]).sum() == 0
-    assert (np.asarray(out.pos)[:, :, 1] == -1).all()
-    assert int(np.asarray(out.positions)[1]) == 0
-    # other rows untouched
-    keep = [0, 2, 3]
-    np.testing.assert_array_equal(np.asarray(out.lengths)[:, :, keep],
-                                  before[:, :, keep])
-    np.testing.assert_array_equal(np.asarray(out.k)[:, :, keep],
-                                  np.asarray(cache.k)[:, :, keep])
-
-
-def test_reset_rows_accepts_bool_mask():
-    pa = PlanArrays.from_plan(_plan())
-    cache = _filled_cache(pa)
-    m = jnp.asarray([True, False, True, False])
-    out = reset_rows(cache, m)
-    lens = np.asarray(out.lengths)
-    assert lens[:, :, [0, 2]].sum() == 0
-    assert lens[:, :, [1, 3]].sum() > 0
 
 
 def test_rows_to_mask_roundtrip():
@@ -103,79 +74,6 @@ def test_rows_to_mask_roundtrip():
     np.testing.assert_array_equal(m, [True, False, False, True])
     passthrough = rows_to_mask(jnp.asarray(m), B)
     np.testing.assert_array_equal(np.asarray(passthrough), m)
-
-
-# ---------------------------------------------------------------------------
-# insert_rows
-# ---------------------------------------------------------------------------
-
-
-def test_insert_rows_splices_with_target_row_ownership():
-    """A sub-cache built at global row 3 lands on the slots that own row 3."""
-    plan = _plan()  # 2 heads, 4 shards, rc == 2
-    pa = PlanArrays.from_plan(plan)
-    S = int(pa.slot_head.shape[1])
-    live = _filled_cache(pa)
-    live = reset_rows(live, jnp.asarray([3]))
-
-    # build a 1-row sub-cache with ownership evaluated at global row 3
-    sub = init_cache(L, S, 1, CAP, DH, dtype=jnp.float32)
-    own3 = np.asarray(pa.owner_mask_all(B))[:, :, 3]  # (L, S)
-    sub_len = (2 * own3).astype(np.int32)[:, :, None]
-    sub = SlotCache(
-        k=jnp.asarray(np.ones((L, S, 1, CAP, DH), np.float32)
-                      * own3[:, :, None, None, None]),
-        v=sub.v, lengths=jnp.asarray(sub_len), pos=sub.pos,
-        positions=jnp.asarray([7], jnp.int32))
-
-    out = insert_rows(live, sub, jnp.asarray([3]))
-    lens = np.asarray(out.lengths)
-    np.testing.assert_array_equal(lens[:, :, 3], 2 * own3)
-    assert int(np.asarray(out.positions)[3]) == 7
-    # rows 0-2 untouched
-    np.testing.assert_array_equal(lens[:, :, :3],
-                                  np.asarray(live.lengths)[:, :, :3])
-    # the spliced row only has nonzero lengths on slots owning row 3
-    assert (lens[:, :, 3][~own3.astype(bool)] == 0).all()
-
-
-def test_insert_rows_rejects_layout_mismatch():
-    pa = PlanArrays.from_plan(_plan())
-    S = int(pa.slot_head.shape[1])
-    live = init_cache(L, S, B, CAP, DH, dtype=jnp.float32)
-    bad = init_cache(L, S, 1, CAP + 1, DH, dtype=jnp.float32)
-    with pytest.raises(ValueError):
-        insert_rows(live, bad, jnp.asarray([0]))
-
-
-def test_insert_then_append_continues_at_correct_index():
-    """Ring-buffer appends pick up at the spliced row's lengths."""
-    plan = _plan()
-    pa = PlanArrays.from_plan(plan)
-    S = int(pa.slot_head.shape[1])
-    live = init_cache(L, S, B, CAP, DH, dtype=jnp.float32)
-    own3 = np.asarray(pa.owner_mask_all(B))[:, :, 3]
-    sub_len = (3 * own3).astype(np.int32)[:, :, None]
-    sub = init_cache(L, S, 1, CAP, DH, dtype=jnp.float32)
-    sub = SlotCache(k=sub.k, v=sub.v, lengths=jnp.asarray(sub_len),
-                    pos=sub.pos, positions=jnp.asarray([10], jnp.int32))
-    live = insert_rows(live, sub, jnp.asarray([3]))
-
-    own = pa.owner_mask(0, B)
-    k_new = jnp.full((S, B, DH), 5.0, jnp.float32)
-    out = append_token(live, 0, k_new, k_new, own, jnp.int32(0), ring=2)
-    lens = np.asarray(out.lengths[0])
-    # spliced row grew 3 -> 4 on owning slots; empty owned rows grew 0 -> 1
-    np.testing.assert_array_equal(lens[:, 3], (3 * own3[0] + 1)
-                                  * np.asarray(own)[:, 3])
-    np.testing.assert_array_equal(
-        lens[:, 0], np.asarray(own)[:, 0].astype(np.int32))
-    # the new entry landed at index == old length for the spliced row
-    k_np = np.asarray(out.k[0])
-    for s in range(S):
-        if own3[0, s] and np.asarray(own)[s, 3]:
-            assert k_np[s, 3, 3, 0] == 5.0  # written at position 3
-            assert k_np[s, 3, 4, 0] == 0.0
 
 
 # ---------------------------------------------------------------------------

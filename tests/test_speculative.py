@@ -74,8 +74,7 @@ _PROMPTS = np.random.default_rng(0).integers(0, 256, (B, T))
 
 
 def _fresh(eng):
-    eng.prefill(_PROMPTS)
-    eng.state = eng.backend.from_prefill(eng.state, eng.pa)
+    eng.prefill(_PROMPTS)  # paged into a fresh pool
     return eng.state
 
 
@@ -221,7 +220,6 @@ prompts = np.random.default_rng(0).integers(0, 256, (B, T))
 
 def fresh(eng):
     eng.prefill(prompts)
-    eng.state = eng.backend.from_prefill(eng.state, eng.pa)
     return eng.state
 
 def run_ref(eng):

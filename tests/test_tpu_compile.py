@@ -16,7 +16,6 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from repro.kernels.fairkv_decode import fairkv_decode_pallas
 from repro.kernels.paged_fairkv_decode import paged_fairkv_decode_pallas
 from repro.kernels.prefill_flash import prefill_flash
 from repro.kernels.snapkv_select import snapkv_scores_pallas
@@ -59,16 +58,16 @@ def _compiled_text(fn, *shapes):
     return jax.jit(fn).lower(*shapes).compile().as_text()
 
 
-def _paged_shapes(one_chip, q_shape, pool_dtype):
+def _paged_shapes(one_chip, q_shape, pool_dtype, rows=ROWS):
     def spec(shape, dt):
         return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
     return (spec(q_shape, jnp.bfloat16),
             spec((N_BLOCKS, BS, DH), pool_dtype),
             spec((N_BLOCKS, BS, DH), pool_dtype),
             spec((N_BLOCKS, BS), jnp.int32),
-            spec((SLOTS, ROWS, M), jnp.int32),
-            spec((SLOTS, ROWS), jnp.int32),
-            spec((ROWS,), jnp.int32))
+            spec((SLOTS, rows, M), jnp.int32),
+            spec((SLOTS, rows), jnp.int32),
+            spec((rows,), jnp.int32))
 
 
 def test_paged_decode_bf16_compiles_for_v5e(one_chip):
@@ -104,20 +103,16 @@ def test_paged_verify_q5_compiles_for_v5e(one_chip):
     assert "tpu_custom_call" in hlo
 
 
-def test_slot_decode_b8_compiles_for_v5e(one_chip):
-    B, C = 8, 544
+def test_paged_decode_b8_compiles_for_v5e(one_chip):
+    """Eight rows under a 256-token sliding window: the kernel's grid and
+    window mask at the batch width of a full decode tick."""
+    B = 8
 
-    def spec(shape, dt):
-        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
-
-    def fn(q, k, v, lens, k_pos, q_pos):
-        return fairkv_decode_pallas(q, k, v, lens, k_pos=k_pos, q_pos=q_pos,
-                                    window=256)
-    hlo = _compiled_text(
-        fn, spec((B, SLOTS, G, DH), jnp.bfloat16),
-        spec((SLOTS, B, C, DH), jnp.bfloat16),
-        spec((SLOTS, B, C, DH), jnp.bfloat16), spec((SLOTS, B), jnp.int32),
-        spec((SLOTS, B, C), jnp.int32), spec((B,), jnp.int32))
+    def fn(q, k, v, pos, table, lens, q_pos):
+        return paged_fairkv_decode_pallas(q, k, v, pos, table, lens, CAPACITY,
+                                          q_pos=q_pos, window=256)
+    hlo = _compiled_text(fn, *_paged_shapes(
+        one_chip, (B, SLOTS, G, DH), jnp.bfloat16, rows=B))
     assert "tpu_custom_call" in hlo
 
 
