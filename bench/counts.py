@@ -6,7 +6,8 @@ same numbers in the same way; what depends on the layer's mathematics is
 its family's (``families/<family>.py``):
 
 - ``headkv_keep``: the per-(layer, head) prompt tokens HeadKV retains, the
-  rule a planner profile and the reference both follow;
+  rule a planner profile and the reference both follow, and
+  ``decoded_lengths``: each decoded token's retained lengths under it;
 - ``decode_flops`` / ``prefill_flops``: model FLOPs of one token / prompt;
 - ``paged_decode_bytes``: HBM bytes the paged decode kernel must read for
   the live blocks of one decode step (the fig9 byte model of the repo's
@@ -74,6 +75,20 @@ def live_lengths(keep: np.ndarray, appended: int, capacity: int) -> np.ndarray:
     """(L, H) cache lengths after ``appended`` decode appends (the recency
     ring keeps a full head at capacity)."""
     return np.minimum(keep + appended, capacity)
+
+
+def decoded_lengths(ticks, reqs: dict, imp: np.ndarray, comp: dict):
+    """(L, H) cache lengths of every token decoded in ``ticks`` (the
+    harness's ``[(host time, [(request index, decode appends so far)])]``),
+    after its own append; ``reqs`` maps a request index to its request."""
+    cap = static_capacity(comp)
+    keep = {}
+    for _, decoded in ticks:
+        for idx, appended in decoded:
+            T = len(reqs[idx].prompt)
+            if T not in keep:
+                keep[T] = headkv_keep(imp, comp, T)
+            yield live_lengths(keep[T], appended, cap)
 
 
 # ---- FLOPs -----------------------------------------------------------------

@@ -9,17 +9,9 @@ def read(ctx):
     ticks = ctx.get("trace_ticks")
     if not ticks:
         return None
-    m, comp = ctx["model"], ctx["cell"].compression
-    cap = counts.static_capacity(comp)
-    keep = {}
-    flops = 0.0
-    for _, decoded in ticks:
-        for idx, appended in decoded:
-            T = len(ctx["reqs"][idx].prompt)
-            if T not in keep:
-                keep[T] = counts.headkv_keep(ctx["imp"], comp, T)
-            flops += counts.decode_flops(
-                m, counts.live_lengths(keep[T], appended, cap))
+    flops = sum(counts.decode_flops(ctx["model"], lens)
+                for lens in counts.decoded_lengths(
+                    ticks, ctx["reqs"], ctx["imp"], ctx["cell"].compression))
     if flops == 0:
         return None
     peak = ctx["peaks"]["bf16_flops"] * ctx["chips"]

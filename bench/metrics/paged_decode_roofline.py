@@ -25,22 +25,16 @@ def read(ctx):
     ns = red["kernel_ns"].get("paged_decode_roofline", 0.0)
     if ns <= 0:
         return None
-    m, comp = ctx["model"], ctx["cell"].compression
+    m = ctx["model"]
     eng = ctx["cell"].config["engine"]
     bs = int(eng["paging"]["block_size"])
     item = {"bfloat16": 2, "float16": 2, "float32": 4}[eng["dtype"]]
-    cap = counts.static_capacity(comp)
     G = m["n_heads"] // m["n_kv_heads"]
-    keep = {}
     nbytes = flops = 0.0
-    for _, decoded in ctx["trace_ticks"]:
-        for idx, appended in decoded:
-            T = len(ctx["reqs"][idx].prompt)
-            if T not in keep:
-                keep[T] = counts.headkv_keep(ctx["imp"], comp, T)
-            lens = counts.live_lengths(keep[T], appended, cap)
-            nbytes += counts.paged_decode_bytes(lens, bs, m["head_dim"], item)
-            flops += 4.0 * G * m["head_dim"] * float(lens.sum())
+    for lens in counts.decoded_lengths(ctx["trace_ticks"], ctx["reqs"],
+                                       ctx["imp"], ctx["cell"].compression):
+        nbytes += counts.paged_decode_bytes(lens, bs, m["head_dim"], item)
+        flops += 4.0 * G * m["head_dim"] * float(lens.sum())
     pk = ctx["peaks"]
     t_min = max(nbytes / pk["hbm_bytes_per_s"], flops / pk["bf16_flops"])
     return 100.0 * t_min / (ns / 1e9)

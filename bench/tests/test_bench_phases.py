@@ -59,6 +59,19 @@ def test_scheduler_readers_on_the_smoke_cell(smoke_ctx):
     assert _read("host_phase_idle_share", smoke_ctx) is None
 
 
+def test_decode_readers_on_the_smoke_cell(smoke_ctx):
+    assert _read("decode_step_ms", smoke_ctx) > 0
+    ctx = dict(smoke_ctx, peaks={"bf16_flops": 1e12})
+    mfu = _read("decode_step_mfu", ctx)
+    # decode_mfu's FLOPs of the same ticks, over the decode StepFn's time
+    # in place of a traced stretch's
+    s, _ = ctx["stepfn"]["decode"]
+    same = dict(ctx, trace_ticks=ctx["ticks"], trace_host_s=s)
+    assert mfu > 0 and mfu == pytest.approx(_read("decode_mfu", same))
+    # the run captured no trace: the kernel's roofline reads nothing
+    assert _read("paged_decode_roofline", smoke_ctx) is None
+
+
 def test_window_change_reads_the_splices_of_the_window(smoke_ctx):
     """The registry's snapshots at the window's ends: every admission of the
     window spliced its request into the live pools once, in place."""
